@@ -219,6 +219,9 @@ def write_bench_json(
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     filters = sys.argv[1:]
     print("name,us_per_call,derived")
     failed = []

@@ -51,7 +51,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.batch import bucket_slices, gather_sublists
 from repro.core.build import build_from_sorted
 from repro.core.config import _UNSET, ExecConfig, resolve_config
@@ -67,6 +66,7 @@ from repro.core.ops import (
     OpBatch,
     _compact_by_mask,
     apply_ops,
+    resolve_impl,
 )
 from repro.core.query import _suffix_min_with_index, flat_rank, range_offsets
 from repro.core.state import (
@@ -250,7 +250,7 @@ def shard_live_counts(idx: ShardedFliX, mesh) -> jax.Array:
         return jax.lax.all_gather(jnp.sum(node_count).reshape(1), axis).reshape(-1)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis, None),),
@@ -626,7 +626,7 @@ def _build_replicated(
         in_specs += (P(),)
     if has_now:
         in_specs += (P(),)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -866,7 +866,7 @@ def _build_a2a(
         in_specs += (P(axis),)
     if has_now:
         in_specs += (P(),)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -973,22 +973,9 @@ def shard_apply_ops(
         capacity=capacity,
     )
     routing = cfg.routing
-    impl = cfg.impl
+    impl = resolve_impl(cfg.impl, ops, has_updates)
     max_results = cfg.max_results
     capacity = cfg.capacity
-    if impl == "auto":
-        if jax.default_backend() != "tpu":
-            impl = "reference"
-        else:
-            if has_updates is None:
-                has_updates = bool(
-                    jnp.any(
-                        (ops.tag == OP_INSERT)
-                        | (ops.tag == OP_DELETE)
-                        | (ops.tag == OP_EXPIRE)
-                    )
-                )
-            impl = "fused" if has_updates else "reference"
     if has_ranges is None:
         has_ranges = bool(jnp.any(ops.tag == OP_RANGE))
     donate_r = cfg.donate and jax.default_backend() != "cpu"

@@ -414,10 +414,30 @@ def _apply_ops_reference(
     return s2, results, stats
 
 
-def _apply_ops_plain(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConfig):
-    """Dispatch one TTL-free batch to the chosen executor (impl resolved)."""
+def resolve_impl(impl: str, ops: OpBatch, has_updates: bool | None = None) -> str:
+    """The executor ``impl="auto"`` stands for: ``"fused"`` on TPU for a
+    batch with updates, ``"reference"`` otherwise (see :func:`apply_ops`).
+    ``has_updates`` answers the batch-composition check without a device
+    sync when the caller already knows it."""
+    if impl != "auto":
+        return impl
+    if jax.default_backend() != "tpu":
+        return "reference"
+    if has_updates is None:
+        has_updates = bool(
+            jnp.any(
+                (ops.tag == OP_INSERT) | (ops.tag == OP_DELETE) | (ops.tag == OP_EXPIRE)
+            )
+        )
+    return "fused" if has_updates else "reference"
+
+
+def plain_executor(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConfig):
+    """The jitted executor for one TTL-free batch (``impl`` resolved) and
+    its call: ``(fn, args, kwargs)``.  ``fn.lower(*args, **kwargs)``
+    compiles exactly what :func:`apply_ops` runs."""
     if impl == "reference":
-        return _apply_ops_reference(state, ops, max_results=cfg.max_results)
+        return _apply_ops_reference, (state, ops), {"max_results": cfg.max_results}
     if impl != "fused":
         raise ValueError(f"unknown apply_ops impl: {impl!r}")
 
@@ -442,17 +462,19 @@ def _apply_ops_plain(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConf
     # forces it anywhere (interpret mode included — how the differential
     # suite proves byte-identity on CPU); "off" forces the fallback.
     pipeline = (backend == "tpu") if cfg.pipeline == "auto" else (cfg.pipeline == "on")
-    return fn(
-        state,
-        ops.tag,
-        ops.key,
-        ops.val,
-        block_q=block_q or DEFAULT_BLOCK_Q,
-        block_b=block_b or DEFAULT_BLOCK_B,
-        max_results=cfg.max_results,
-        interpret=backend != "tpu",
-        pipeline=pipeline,
-    )
+    return fn, (state, ops.tag, ops.key, ops.val), {
+        "block_q": block_q or DEFAULT_BLOCK_Q,
+        "block_b": block_b or DEFAULT_BLOCK_B,
+        "max_results": cfg.max_results,
+        "interpret": backend != "tpu",
+        "pipeline": pipeline,
+    }
+
+
+def _apply_ops_plain(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConfig):
+    """Run one TTL-free batch on the chosen executor (impl resolved)."""
+    fn, args, kwargs = plain_executor(state, ops, impl=impl, cfg=cfg)
+    return fn(*args, **kwargs)
 
 
 def _apply_ops_ttl(
@@ -630,20 +652,7 @@ def apply_ops(
         block_b=block_b,
         max_results=max_results,
     )
-    impl_r = cfg.impl
-    if impl_r == "auto":
-        if jax.default_backend() != "tpu":
-            impl_r = "reference"
-        else:
-            if has_updates is None:
-                has_updates = bool(
-                    jnp.any(
-                        (ops.tag == OP_INSERT)
-                        | (ops.tag == OP_DELETE)
-                        | (ops.tag == OP_EXPIRE)
-                    )
-                )
-            impl_r = "fused" if has_updates else "reference"
+    impl_r = resolve_impl(cfg.impl, ops, has_updates)
     # TTL activation is structural (does an expiry column exist on the state
     # or the batch?), so it is host-decidable even inside shard_map traces.
     if state.exps is not None or ops.exp is not None:

@@ -6,10 +6,9 @@ The fused kernel's two tile knobs trade off against each other:
     over the bucket blocks, but each window revisits every bucket block its
     op span touches, so an oversized window drags cold stripes through VMEM
     for a handful of ops.
-  * ``block_b`` — bucket stripes per block.  The merge/delete masks are
-    O(block_b · S²), and the double-buffered variant holds **two** stripe
-    blocks in VMEM at once, so ``block_b`` is bounded by VMEM long before
-    it stops helping amortize grid overhead.
+  * ``block_b`` — bucket stripes per block.  The kernel walks a block's
+    buckets one at a time, so ``block_b`` only sizes the DMA'd blocks (two
+    of each, double-buffered) and amortizes per-step grid overhead.
 
 The right point depends on (build_size, batch_size), which is exactly the
 :class:`~repro.core.config.TileTable` key.  This module sweeps the
@@ -36,37 +35,44 @@ from __future__ import annotations
 import math
 
 from repro.core.config import TileTable, _pow2_bucket
+from repro.kernels.flix_apply import N_COLS, VMEM_LIMIT_BYTES, _chunk_rows
 
 # candidate grid — DEFAULT_BLOCK_Q (flix_query) and DEFAULT_BLOCK_B
 # (flix_apply) are both members, so the tuned table can only match or beat
-# the static defaults under the model
+# the static defaults under the model.  Every candidate compiles for a v5e at
+# both served geometries (node_size × nodes_per_bucket = 16×8 and 32×16).
 CANDIDATE_BLOCK_Q = (128, 256, 512)
 CANDIDATE_BLOCK_B = (1, 2, 4, 8)
 
-# VMEM budget the model holds a candidate to.  Real TPU cores have ~16 MiB;
-# the margin leaves room for the compiler's own temporaries.
-VMEM_BUDGET_BYTES = 12 * 2**20
+# the scoped-VMEM limit the kernel is compiled with — one number for both
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 _I32 = 4  # bytes
+
+
+def _tile(rows: int, lanes: int) -> int:
+    """int32 elements a [rows, lanes] block occupies in (8, 128) VMEM tiles."""
+    return -(-rows // 8) * 8 * (-(-lanes // 128) * 128)
 
 
 def vmem_bytes(block_q: int, block_b: int, *, node_size: int, nodes_per_bucket: int,
                max_results: int = 128) -> int:
     """Model of the kernel's VMEM residency for one grid step.
 
-    Counts the double-buffered worst case (two stripe blocks live at once)
-    plus the O(block_b · S²) merge one-hots, which dominate everything else
-    for realistic S.
+    Every blocked operand is double-buffered by the pipeline (the explicit
+    two-slot stripe scratch of the pipelined variant is the same size);
+    the column scratch holds N_COLS [S, 1] vectors, each one lane of an
+    (8, 128) tile; the loop bodies keep a few [CHUNK, max(S, QB, MR)] tiles
+    live.
     """
     S = node_size * nodes_per_bucket
-    cap = S  # bucket_capacity == npb * ns
-    stripes = 2 * 2 * block_b * S            # two planes × two slots
-    merge = 2 * block_b * S * S              # ohA/mask temporaries [BB, S, S]
-    tiles = 3 * block_b * cap                # ik / iv / dk
-    meta = 2 * block_b * nodes_per_bucket    # node_max + counts
-    window = 4 * block_q                     # tags, keys, resv, resk
-    fences = 8 * block_b                     # mkba/lf/nxk/nxv/ps/pe rows
-    rng = 3 * max_results
-    return _I32 * (stripes + merge + tiles + meta + window + fences + rng)
+    npb = nodes_per_bucket
+    stripes = 7 * _tile(block_b, S)          # keys/vals in + out, ik/iv/dk
+    meta = 4 * _tile(block_b, npb) + 2 * _tile(block_b, 128)
+    window = 4 * _tile(1, block_q)           # tags, keys, resv, resk
+    rng = 3 * _tile(1, max_results)
+    cols = N_COLS * _tile(S, 1)
+    temps = 8 * _tile(_chunk_rows(S), max(S, block_q, max_results))
+    return _I32 * (2 * (stripes + meta + window + rng) + cols + temps)
 
 
 def model_cost(

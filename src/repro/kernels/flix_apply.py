@@ -12,8 +12,8 @@ collapses them: while a bucket stripe is VMEM-resident it
   2. physically DELETEs its DELETE slice with in-node and chain compaction
      (identical formulas to ``flix_delete`` / ``core.delete``),
   3. answers the batch's POINT and SUCCESSOR ops that fall in the bucket
-     against the *post-update* stripe (compare-count votes + one-hot MXU
-     gathers, as in ``flix_query`` / ``flix_successor``),
+     against the *post-update* stripe (compare-count votes + one-hot
+     masked gathers, the formulas of ``flix_query`` / ``flix_successor``),
   4. fills the output slots of the batch's RANGE ops whose global key rank
      lands in the bucket — the dense count/scatter contract of
      ``kernels/flix_range`` (DESIGN.md §10), read straight from the
@@ -72,300 +72,369 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-from repro.kernels.flix_query import DEFAULT_BLOCK_Q, _exact_gather_i32
+from repro.kernels.flix_query import DEFAULT_BLOCK_Q
 from repro.core.batch import bucket_slices, gather_kv_sublists, gather_sublists
 from repro.core.state import EMPTY, KEY_DTYPE, NOT_FOUND, FliXState
 
-DEFAULT_BLOCK_B = 2     # bucket stripes per block (merge masks are O(BB·S²))
+DEFAULT_BLOCK_B = 2     # bucket stripes per block (walked one bucket at a time)
+# Scoped VMEM the kernel is compiled with, set explicitly rather than left
+# to the compiler's default; kernels.autotune holds its tile candidates to
+# the same number.  The kernel needs a few MiB at S = 512 (column scratch +
+# (CHUNK, S) tiles + double-buffered blocks) — a v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 32 * 2**20
 _EMPTY = int(jnp.iinfo(jnp.int32).max)
+_MIN = int(jnp.iinfo(jnp.int32).min)
 _MISS = -1
 _OP_POINT = 2           # mirror core.ops tags as Python literals (kernels
 _OP_SUCCESSOR = 3       # must not capture traced constants)
 _OP_RANGE = 5
 
+# rows of the per-bucket fence array streamed with every stripe block
+_FENCE_MKBA, _FENCE_LF, _FENCE_NXK, _FENCE_NXV, _FENCE_PS, _FENCE_PE = range(6)
+N_FENCE_ROWS = 6
 
-def _stripe_body(
-    A,           # [BB, S] stripe keys (VMEM-resident, chain order)
-    Av,          # [BB, S] stripe vals
+
+# column scratch slots: per-bucket vectors turned on their side, so a loop
+# over (CHUNK, S) tiles can stream them by sublane offset
+(_C_A, _C_AV, _C_B, _C_BV, _C_D, _C_RANK_A, _C_REG_A, _C_KEEP_A, _C_RANK_B,
+ _C_REG_B, _C_MK, _C_MV, _C_FK, _C_FV) = range(14)
+N_COLS = 14
+
+
+def _chunk_rows(S: int) -> int:
+    """Rows per (CHUNK, S) tile in the in-kernel loops.  128 keeps each tile
+    64 vregs at S = 512, so the unrolled loop body — and the compile — stay
+    small; smaller stripes (interpret-mode tests) run as one chunk."""
+    return 128 if S % 128 == 0 else S
+
+
+def _col(row):
+    """[1, W] row → [W, 1] column."""
+    return row[0, :][:, None]
+
+
+def _row(col):
+    """[W, 1] column → [1, W] row."""
+    return col[:, 0][None, :]
+
+
+def _sum_rows(x):
+    return jnp.sum(x, axis=0, keepdims=True)
+
+
+def _sum_lanes(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _bucket_body(
+    b,           # bucket index within the block (traced loop index)
+    A_ref,       # [BB, S] stripe keys (VMEM-resident, chain order)
+    Av_ref,      # [BB, S] stripe vals
     t_ref,       # [1, QB] op tags for window j
     q_ref,       # [1, QB] sorted op keys for window j
     nmax_ref,    # [BB, npb] per-node max keys (EMPTY when inactive)
     ik_ref,      # [BB, cap] sorted per-bucket INSERT keys (EMPTY-padded)
     iv_ref,      # [BB, cap]
     dk_ref,      # [BB, cap] sorted per-bucket DELETE keys (present only)
-    mkba_ref,    # [1, BB] bucket fences for the block
-    lf_ref,      # [1, BB] lower fences
-    nxk_ref,     # [1, BB] post-update "first key after bucket b" rows
-    nxv_ref,     # [1, BB]
+    fence_ref,   # [BB, N_FENCE_ROWS] per-bucket fences (see _FENCE_*)
     g_ref,       # [1, MR] per-RANGE-slot post-update global rank (-1 unused)
-    ps_ref,      # [1, BB] post-update rank fences pref[b]
-    pe_ref,      # [1, BB] post-update rank fences pref[b+1]
-    okeys_ref,   # [BB, npb*ns] post-update stripes
-    ovals_ref,   # [BB, npb*ns]
+    okeys_ref,   # [BB, S] post-update stripes
+    ovals_ref,   # [BB, S]
     ocnt_ref,    # [BB, npb]
     omax_ref,    # [BB, npb]
-    onn_ref,     # [BB, 1]
-    oflow_ref,   # [BB, 1] bucket overflow flag
-    odel_ref,    # [BB, 1] keys physically deleted in this bucket
+    bmeta_ref,   # [BB, 3]: node count, overflow flag, keys deleted
     resv_ref,    # [1, QB] POINT/SUCCESSOR values / NOT_FOUND
     resk_ref,    # [1, QB] SUCCESSOR keys / EMPTY
     rngk_ref,    # [1, MR] dense RANGE keys / EMPTY (shared across windows)
     rngv_ref,    # [1, MR] dense RANGE vals / NOT_FOUND
+    cols,        # [N_COLS, S, 1] column scratch
     *,
-    block_b: int,
     npb: int,
     ns: int,
-    cap: int,
 ):
-    """One active stripe block: merge + delete + reads + range gather.
+    """Merge + delete + reads + range gather for one bucket stripe.
+
+    Vectors live as [1, S] rows.  Every all-pairs compare-count runs as a
+    loop over (CHUNK, S) tiles whose sublane axis streams one vector from
+    the column scratch, so no [S, S] temporary exists and no reshape splits
+    the lane axis; a lane's node is ``lane // ns``.  The formulas are those
+    of ``core.insert`` / ``core.delete`` / ``core.query``, term for term.
+    """
+    S = npb * ns
+    C = _chunk_rows(S)
+    nch = S // C
+    row = pl.ds(b, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+    node_of_lane = lane // ns                                  # [1, S]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    node_col = jax.lax.broadcasted_iota(jnp.int32, (npb, 1), 0)
+    node_row = jax.lax.broadcasted_iota(jnp.int32, (1, npb), 1)
+
+    def chunk(c):
+        return pl.ds(pl.multiple_of(c * C, C), C)
+
+    def loop(body, init):
+        return jax.lax.fori_loop(0, nch, body, init)
+
+    A = A_ref[row, :]                          # [1, S]
+    Av = Av_ref[row, :]
+    B = ik_ref[row, :]                         # [1, cap] incoming
+    Bv = iv_ref[row, :]
+    D = dk_ref[row, :]
+    nmax = nmax_ref[row, :]                    # [1, npb]
+    for slot, vec in ((_C_A, A), (_C_AV, Av), (_C_B, B), (_C_BV, Bv), (_C_D, D)):
+        cols[slot] = _col(vec)
+
+    # ---- phase 1: upsert merge of the INSERT slice --------------------
+    validA = A != _EMPTY
+    validB_i = (B != _EMPTY).astype(jnp.int32)
+
+    def dup_tile(c, acc):                      # is A[l] re-inserted by B?
+        return acc + _sum_rows(jnp.where(cols[_C_B, chunk(c), :] == A, 1, 0))
+
+    dupA = loop(dup_tile, jnp.zeros((1, S), jnp.int32)) > 0
+    keepA_i = (validA & ~dupA).astype(jnp.int32)   # incoming value wins
+    cols[_C_KEEP_A] = _col(keepA_i)
+
+    # original node regions (fixed boundaries; last region open-ended)
+    onn0 = _sum_lanes((nmax != _EMPTY).astype(jnp.int32))     # [1, 1]
+    onn_c = jnp.maximum(onn0 - 1, 0)
+
+    def rank_pass(z_slot, keep_slot, rank_slot, reg_slot):
+        # merged ranks by compare-count (both sides sorted & unique), the
+        # region of each key, and the live-key count of every region
+        def tile(c, m):
+            z = cols[z_slot, chunk(c), :]      # [C, 1]
+            keep = (
+                cols[keep_slot, chunk(c), :]
+                if keep_slot is not None
+                else (z != _EMPTY).astype(jnp.int32)
+            )
+            rank = _sum_lanes(jnp.where(A < z, keepA_i, 0)) + _sum_lanes(
+                jnp.where(B < z, validB_i, 0)
+            )
+            reg = jnp.minimum(
+                _sum_lanes((nmax < z).astype(jnp.int32)), onn_c
+            )
+            cols[rank_slot, chunk(c), :] = rank
+            cols[reg_slot, chunk(c), :] = reg
+            return m + _sum_rows(jnp.where(reg == node_row, keep, 0))
+
+        return tile
+
+    m_j = loop(
+        rank_pass(_C_A, _C_KEEP_A, _C_RANK_A, _C_REG_A),
+        jnp.zeros((1, npb), jnp.int32),
+    )
+    m_j = loop(rank_pass(_C_B, None, _C_RANK_B, _C_REG_B), m_j)   # [1, npb]
+
+    def excl_prefix(x_row):
+        # exclusive prefix sum of a [1, npb] row (Mosaic has no cumsum)
+        return _sum_rows(jnp.where(node_col < node_row, _col(x_row), 0))
+
+    s_j = (m_j + ns - 1) // ns                 # pieces per region
+    f_j = excl_prefix(m_j)                     # first rank of region
+    base_j = excl_prefix(s_j)                  # first output slot
+    total_new = _sum_lanes(s_j)                # [1, 1]
+
+    def scatter_pass(z_slot, v_slot, keep_slot, rank_slot, reg_slot):
+        # balanced split within each region (same formulas as core/insert),
+        # then a one-hot scatter of each key to its merged lane
+        def tile(c, acc):
+            mk, mv, filled = acc
+            z = cols[z_slot, chunk(c), :]
+            keep = (
+                cols[keep_slot, chunk(c), :] > 0
+                if keep_slot is not None
+                else z != _EMPTY
+            )
+            oh = cols[reg_slot, chunk(c), :] == node_row      # [C, npb]
+            m_r = jnp.maximum(_sum_lanes(jnp.where(oh, m_j, 0)), 1)
+            s_r = jnp.maximum(_sum_lanes(jnp.where(oh, s_j, 0)), 1)
+            f_r = _sum_lanes(jnp.where(oh, f_j, 0))
+            b_r = _sum_lanes(jnp.where(oh, base_j, 0))
+            rr = cols[rank_slot, chunk(c), :] - f_r
+            piece = (rr * s_r) // m_r
+            start = (piece * m_r + s_r - 1) // s_r
+            slot = b_r + piece
+            dest = jnp.where(keep & (slot < npb), slot * ns + rr - start, S)
+            hit = dest == lane                                # [C, S]
+            return (
+                mk + _sum_rows(jnp.where(hit, z, 0)),
+                mv + _sum_rows(jnp.where(hit, cols[v_slot, chunk(c), :], 0)),
+                filled + _sum_rows(hit.astype(jnp.int32)),
+            )
+
+        return tile
+
+    zero_row = jnp.zeros((1, S), jnp.int32)
+    acc = loop(
+        scatter_pass(_C_A, _C_AV, _C_KEEP_A, _C_RANK_A, _C_REG_A),
+        (zero_row, zero_row, zero_row),
+    )
+    mk, mv, filled = loop(
+        scatter_pass(_C_B, _C_BV, None, _C_RANK_B, _C_REG_B), acc
+    )
+    mk = jnp.where(filled > 0, mk, _EMPTY)     # [1, S] merged stripe
+    mv = jnp.where(filled > 0, mv, 0)
+
+    # ---- phase 2: physical delete on the merged stripe ---------------
+    def hit_tile(c, acc):
+        return acc + _sum_rows(jnp.where(cols[_C_D, chunk(c), :] == mk, 1, 0))
+
+    hit = (loop(hit_tile, zero_row) > 0) & (mk != _EMPTY)
+    del_cnt = _sum_lanes(hit.astype(jnp.int32))               # [1, 1]
+    keep_i = ((~hit) & (mk != _EMPTY)).astype(jnp.int32)      # [1, S]
+    cols[_C_MK] = _col(mk)
+    cols[_C_MV] = _col(mv)
+
+    # chain compaction: surviving nodes shift into the lowest slots
+    cnt = _sum_lanes(jnp.where(node_of_lane == node_col, keep_i, 0))  # [npb, 1]
+    nonempty = (cnt > 0).astype(jnp.int32)
+    slot_dest = _sum_rows(jnp.where(node_col < node_row, nonempty, 0))  # [1, npb]
+
+    def compact_tile(c, acc):
+        # a kept lane moves to (its node's new slot, the count of kept
+        # lanes before it in its own node)
+        fk, fv, filled = acc
+        z = cols[_C_MK, chunk(c), :]
+        l = c * C + sub                                       # [C, 1]
+        kept = (z != _EMPTY) & (_sum_lanes(jnp.where(z == D, 1, 0)) == 0)
+        before = (lane < l) & (node_of_lane == l // ns)
+        dest = _sum_lanes(jnp.where(before, keep_i, 0))
+        slot = _sum_lanes(jnp.where(node_row == l // ns, slot_dest, 0))
+        final = jnp.where(kept, slot * ns + dest, S)
+        hit = final == lane
+        return (
+            fk + _sum_rows(jnp.where(hit, z, 0)),
+            fv + _sum_rows(jnp.where(hit, cols[_C_MV, chunk(c), :], 0)),
+            filled + _sum_rows(hit.astype(jnp.int32)),
+        )
+
+    fk, fv, filled = loop(compact_tile, (zero_row, zero_row, zero_row))
+    fk = jnp.where(filled > 0, fk, _EMPTY)                    # [1, S]
+    fv = jnp.where(filled > 0, fv, 0)
+
+    # metadata (keys ascend within a node, so its max is its last key)
+    live = fk != _EMPTY
+    mine_lane = (node_of_lane == node_col) & live            # [npb, S]
+    ocnt = _sum_lanes(mine_lane.astype(jnp.int32))            # [npb, 1]
+    omax = jnp.max(jnp.where(mine_lane, fk, _MIN), axis=1, keepdims=True)
+    omax = jnp.where(ocnt > 0, omax, _EMPTY)
+    onn = _sum_rows((ocnt > 0).astype(jnp.int32))             # [1, 1]
+
+    okeys_ref[row, :] = fk
+    ovals_ref[row, :] = fv
+    ocnt_ref[row, :] = _row(ocnt)
+    omax_ref[row, :] = _row(omax)
+    meta_lane = jax.lax.broadcasted_iota(jnp.int32, (1, 3), 1)
+    oflow = (total_new > npb).astype(jnp.int32)
+    bmeta_ref[row, :] = jnp.where(
+        meta_lane == 0, onn, jnp.where(meta_lane == 1, oflow, del_cnt)
+    )
+    cols[_C_FK] = _col(fk)
+    cols[_C_FV] = _col(fv)
+
+    fences = fence_ref[row, :]                 # [1, N_FENCE_ROWS]
+
+    def fence(k):
+        return fences[:, k : k + 1]            # [1, 1]
+
+    # ---- phase 3: reads against the post-update stripe ---------------
+    t = t_ref[...]                             # [1, QB] op tags
+    q = q_ref[...]                             # [1, QB] op keys
+    is_p = t == _OP_POINT
+    is_s = t == _OP_SUCCESSOR
+    # the bucket owns the ops in (lower fence, mkba]
+    mine = (is_p | is_s) & (q > fence(_FENCE_LF)) & (q <= fence(_FENCE_MKBA))
+
+    @pl.when(jnp.sum(mine.astype(jnp.int32)) > 0)
+    def _reads():
+        # node by post-update node-max votes, position by key votes
+        nidx = _sum_rows((omax < q).astype(jnp.int32))        # [1, QB]
+        in_bucket = nidx < onn
+        nidx_c = jnp.minimum(nidx, npb - 1)
+
+        def pos_tile(c, pos):
+            k = cols[_C_FK, chunk(c), :]
+            l = c * C + sub
+            return pos + _sum_rows(
+                jnp.where((l // ns == nidx_c) & (k < q), 1, 0)
+            )
+
+        pos = loop(pos_tile, jnp.zeros_like(q))
+        target = nidx_c * ns + jnp.minimum(pos, ns - 1)
+
+        def at_tile(c, acc):
+            hit = (c * C + sub) == target                     # [C, QB]
+            return (
+                acc[0] + _sum_rows(jnp.where(hit, cols[_C_FK, chunk(c), :], 0)),
+                acc[1] + _sum_rows(jnp.where(hit, cols[_C_FV, chunk(c), :], 0)),
+            )
+
+        key_at, val_at = loop(at_tile, (jnp.zeros_like(q), jnp.zeros_like(q)))
+
+        # POINT: hit iff the key is stored post-update
+        use_in = in_bucket & (pos < ns)
+        point_res = jnp.where(use_in & (key_at == q), val_at, _MISS)
+
+        # SUCCESSOR: in-bucket candidate, else the post-update fence rows
+        succ_key = jnp.where(use_in, key_at, fence(_FENCE_NXK))
+        succ_val = jnp.where(use_in, val_at, fence(_FENCE_NXV))
+        succ_val = jnp.where(succ_key != _EMPTY, succ_val, _MISS)
+
+        resv_ref[...] = jnp.where(
+            mine & is_p,
+            point_res,
+            jnp.where(mine & is_s, succ_val, resv_ref[...]),
+        )
+        resk_ref[...] = jnp.where(mine & is_s, succ_key, resk_ref[...])
+
+    # ---- phase 4: dense RANGE slots owned by this bucket --------------
+    # slot p carries the post-update global rank of its key; the bucket
+    # claims p iff the rank falls in its [pref[b], pref[b+1]) span, then
+    # maps the in-bucket rank to a (node, pos) of the stripe just rebuilt
+    # above (ocnt prefix sums = node boundaries).  Valid slots are a
+    # prefix, so g[0] < 0 ⇔ nothing to emit — batches with no RANGE
+    # output skip the gather compute entirely.
+    @pl.when(g_ref[0, 0] >= 0)
+    def _range_gather():
+        g = g_ref[...]                         # [1, MR]
+        ps = fence(_FENCE_PS)
+        mine_r = (g >= ps) & (g < fence(_FENCE_PE))
+        r = g - ps                             # rank within the bucket
+
+        cum = _sum_lanes(jnp.where(node_row <= node_col, _row(ocnt), 0))  # [npb, 1]
+        node_r = jnp.minimum(_sum_rows((cum <= r).astype(jnp.int32)), npb - 1)
+        base = _sum_rows(jnp.where(node_col == node_r, cum - ocnt, 0))
+        target = node_r * ns + jnp.clip(r - base, 0, ns - 1)  # [1, MR]
+
+        def gather_tile(c, acc):
+            hit = (c * C + sub) == target                     # [C, MR]
+            return (
+                acc[0] + _sum_rows(jnp.where(hit, cols[_C_FK, chunk(c), :], 0)),
+                acc[1] + _sum_rows(jnp.where(hit, cols[_C_FV, chunk(c), :], 0)),
+            )
+
+        kk, vv = loop(gather_tile, (jnp.zeros_like(g), jnp.zeros_like(g)))
+        rngk_ref[...] = jnp.where(mine_r, kk, rngk_ref[...])
+        rngv_ref[...] = jnp.where(mine_r, vv, rngv_ref[...])
+
+
+def _stripe_body(A_ref, Av_ref, *refs, block_b: int, npb: int, ns: int):
+    """One active stripe block: every bucket of it, one at a time.
 
     Shared verbatim by the single-buffer kernel (stripes arrive through the
     automatic BlockSpec pipeline) and the double-buffered kernel (stripes
-    arrive via explicit DMA into two-slot scratch) — only where ``A``/``Av``
-    come *from* differs, so the two variants cannot diverge numerically.
+    arrive via explicit DMA into two-slot scratch) — only where ``A_ref``/
+    ``Av_ref`` point differs, so the two variants cannot diverge.
     """
-    S = npb * ns
-    bb = block_b
-    # ---- phase 1: upsert merge of the INSERT slice (per stripe) ------
-    B = ik_ref[...]                            # [BB, cap] incoming
-    Bv = iv_ref[...]
-    nmax = nmax_ref[...]                       # [BB, npb]
 
-    validA = A != _EMPTY
-    validB = B != _EMPTY
-    dupA = jnp.any(A[:, :, None] == B[:, None, :], axis=2) & validA
-    keepA = validA & ~dupA                     # incoming value wins
+    def one(b, carry):
+        _bucket_body(b, A_ref, Av_ref, *refs, npb=npb, ns=ns)
+        return carry
 
-    # merged ranks by compare-count (both sides sorted & unique)
-    lessA_A = jnp.sum((A[:, None, :] < A[:, :, None]) & keepA[:, None, :], axis=2)
-    lessB_A = jnp.sum(
-        (B[:, None, :] < A[:, :, None]) & validB[:, None, :], axis=2
-    )
-    rankA = lessA_A + lessB_A                  # [BB, S]
-    lessA_B = jnp.sum((A[:, None, :] < B[:, :, None]) & keepA[:, None, :], axis=2)
-    lessB_B = jnp.sum(
-        (B[:, None, :] < B[:, :, None]) & validB[:, None, :], axis=2
-    )
-    rankB = lessA_B + lessB_B                  # [BB, cap]
-
-    # original node regions (fixed boundaries; last region open-ended)
-    onn0 = jnp.sum((nmax != _EMPTY).astype(jnp.int32), axis=1)   # [BB]
-    onn_c = jnp.maximum(onn0 - 1, 0)
-
-    def region_of(z):
-        r = jnp.sum((nmax[:, None, :] < z[:, :, None]).astype(jnp.int32), axis=2)
-        return jnp.minimum(r, onn_c[:, None])
-
-    regA = region_of(A)
-    regB = region_of(B)
-
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (bb, npb), 1)
-    mA = jnp.sum(
-        (regA[:, :, None] == iota_r[:, None, :]) & keepA[:, :, None],
-        axis=1,
-    )
-    mB = jnp.sum(
-        (regB[:, :, None] == iota_r[:, None, :]) & validB[:, :, None],
-        axis=1,
-    )
-    m_j = (mA + mB).astype(jnp.int32)          # [BB, npb]
-    s_j = (m_j + ns - 1) // ns                 # pieces per region
-    f_j = jnp.cumsum(m_j, axis=1) - m_j        # first rank of region
-    base_j = jnp.cumsum(s_j, axis=1) - s_j     # first output slot
-    total_new = jnp.sum(s_j, axis=1)           # [BB]
-
-    def dest_of(rank, reg, keep):
-        # balanced split within each region (same formulas as core/insert)
-        oh = reg[:, :, None] == iota_r[:, None, :]
-        m_r = jnp.maximum(jnp.sum(jnp.where(oh, m_j[:, None, :], 0), axis=2), 1)
-        s_r = jnp.maximum(jnp.sum(jnp.where(oh, s_j[:, None, :], 0), axis=2), 1)
-        f_r = jnp.sum(jnp.where(oh, f_j[:, None, :], 0), axis=2)
-        b_r = jnp.sum(jnp.where(oh, base_j[:, None, :], 0), axis=2)
-        rr = rank - f_r
-        piece = (rr * s_r) // m_r
-        start = (piece * m_r + s_r - 1) // s_r
-        pos = rr - start
-        slot = b_r + piece
-        return jnp.where(keep & (slot < npb), slot * ns + pos, S)
-
-    destA = dest_of(rankA, regA, keepA)        # [BB, S]
-    destB = dest_of(rankB, regB, validB)       # [BB, cap]
-
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, 1, S), 2)
-    ohA = destA[:, :, None] == lane            # [BB, S, S]
-    ohB = destB[:, :, None] == lane            # [BB, cap, S]
-    mk = jnp.sum(jnp.where(ohA, A[:, :, None], 0), axis=1) + jnp.sum(
-        jnp.where(ohB, B[:, :, None], 0), axis=1
-    )
-    mv = jnp.sum(jnp.where(ohA, Av[:, :, None], 0), axis=1) + jnp.sum(
-        jnp.where(ohB, Bv[:, :, None], 0), axis=1
-    )
-    filled = jnp.any(ohA, axis=1) | jnp.any(ohB, axis=1)
-    mk = jnp.where(filled, mk, _EMPTY)         # [BB, S] merged stripe
-    mv = jnp.where(filled, mv, 0)
-
-    # ---- phase 2: physical delete on the merged stripe ---------------
-    D = dk_ref[...]                            # [BB, cap]
-    hit = jnp.any(mk[:, :, None] == D[:, None, :], axis=2)
-    hit &= mk != _EMPTY
-    del_cnt = jnp.sum(hit.astype(jnp.int32), axis=1)          # [BB]
-
-    rows = mk.reshape(bb, npb, ns)
-    vrows = mv.reshape(bb, npb, ns)
-    hitr = hit.reshape(bb, npb, ns)
-    keep = (~hitr) & (rows != _EMPTY)
-    dest = jnp.cumsum(keep.astype(jnp.int32), axis=2) - 1
-    lane_n = jax.lax.broadcasted_iota(jnp.int32, (bb, npb, ns, ns), 3)
-    ohc = (dest[..., None] == lane_n) & keep[..., None]
-    nk = jnp.sum(jnp.where(ohc, rows[..., None], 0), axis=2)
-    nfill = jnp.any(ohc, axis=2)
-    nk = jnp.where(nfill, nk, _EMPTY)
-    nv = jnp.where(
-        nk == _EMPTY, 0, jnp.sum(jnp.where(ohc, vrows[..., None], 0), axis=2)
-    )
-    cnt = jnp.sum(keep.astype(jnp.int32), axis=2)             # [BB, npb]
-
-    # chain compaction: surviving nodes shift into the lowest slots
-    nonempty = cnt > 0
-    slot_dest = jnp.cumsum(nonempty.astype(jnp.int32), axis=1) - 1
-    slot_lane = jax.lax.broadcasted_iota(jnp.int32, (bb, npb, npb), 2)
-    ohs = (slot_dest[:, :, None] == slot_lane) & nonempty[:, :, None]
-    fk = jnp.sum(jnp.where(ohs[..., None], nk[:, :, None, :], 0), axis=1)
-    fv = jnp.sum(jnp.where(ohs[..., None], nv[:, :, None, :], 0), axis=1)
-    row_filled = jnp.any(ohs, axis=1)                         # [BB, npb]
-    fk = jnp.where(row_filled[..., None], fk, _EMPTY)
-    fv = jnp.where(row_filled[..., None], fv, 0)
-
-    # metadata
-    ocnt = jnp.sum((fk != _EMPTY).astype(jnp.int32), axis=2)
-    last = jnp.maximum(ocnt - 1, 0)
-    lane3 = jax.lax.broadcasted_iota(jnp.int32, (bb, npb, ns), 2)
-    omax = jnp.sum(jnp.where(lane3 == last[..., None], fk, 0), axis=2)
-    omax = jnp.where(ocnt > 0, omax, _EMPTY)
-    onn_new = jnp.sum((ocnt > 0).astype(jnp.int32), axis=1)   # [BB]
-
-    okeys_ref[...] = fk.reshape(bb, S)
-    ovals_ref[...] = fv.reshape(bb, S)
-    ocnt_ref[...] = ocnt
-    omax_ref[...] = omax
-    onn_ref[...] = onn_new[:, None]
-    oflow_ref[...] = (total_new > npb).astype(jnp.int32)[:, None]
-    odel_ref[...] = del_cnt[:, None]
-
-    # ---- phase 3: reads against the post-update stripe ---------------
-    t = t_ref[0, :]                            # [QB] op tags
-    q = q_ref[0, :]                            # [QB] op keys
-    qcol = q[:, None]
-
-    mkba = mkba_ref[0, :][None, :]             # [1, BB]
-    b_local = jnp.sum(mkba < qcol, axis=1)     # [QB]
-    lf = lf_ref[0, :][None, :]
-    b_sel = jnp.minimum(b_local, bb - 1)
-    oh_b = (
-        jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], bb), 1)
-        == b_sel[:, None]
-    )
-    lf_q = jnp.sum(jnp.where(oh_b, lf, 0), axis=1)
-    is_read = (t == _OP_POINT) | (t == _OP_SUCCESSOR)
-    mine = (b_local < bb) & (qcol[:, 0] > lf_q) & is_read
-
-    # node by post-update node-max votes, position by key votes
-    nmax_rows = _exact_gather_i32(oh_b.astype(jnp.float32), omax)
-    nn_q = jnp.sum(jnp.where(oh_b, onn_new[None, :], 0), axis=1)
-    nidx = jnp.sum(nmax_rows < qcol, axis=1)
-    in_bucket = nidx < nn_q
-    nidx_c = jnp.minimum(nidx, npb - 1)
-
-    flat = b_sel * npb + nidx_c
-    oh_n = (
-        jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], bb * npb), 1)
-        == flat[:, None]
-    ).astype(jnp.float32)
-    krow = _exact_gather_i32(oh_n, fk.reshape(bb * npb, ns))
-    vrow = _exact_gather_i32(oh_n, fv.reshape(bb * npb, ns))
-
-    pos = jnp.sum(krow < qcol, axis=1)
-    pos_c = jnp.minimum(pos, ns - 1)
-    oh_p = (
-        jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], ns), 1)
-        == pos_c[:, None]
-    )
-    key_at = jnp.sum(jnp.where(oh_p, krow, 0), axis=1)
-    val_at = jnp.sum(jnp.where(oh_p, vrow, 0), axis=1)
-
-    # POINT: hit iff the key is stored post-update
-    hit_q = in_bucket & (pos < ns) & (key_at == qcol[:, 0])
-    point_res = jnp.where(hit_q, val_at, _MISS)
-
-    # SUCCESSOR: in-bucket candidate, else the post-update fence rows
-    nxk = jnp.sum(jnp.where(oh_b, nxk_ref[0, :][None, :], 0), axis=1)
-    nxv = jnp.sum(jnp.where(oh_b, nxv_ref[0, :][None, :], 0), axis=1)
-    use_in = in_bucket & (pos < ns)
-    succ_key = jnp.where(use_in, key_at, nxk)
-    succ_val = jnp.where(use_in, val_at, nxv)
-    found = succ_key != _EMPTY
-    succ_val = jnp.where(found, succ_val, _MISS)
-
-    is_p = t == _OP_POINT
-    is_s = t == _OP_SUCCESSOR
-    resv_ref[0, :] = jnp.where(
-        mine & is_p,
-        point_res,
-        jnp.where(mine & is_s, succ_val, resv_ref[0, :]),
-    )
-    resk_ref[0, :] = jnp.where(mine & is_s, succ_key, resk_ref[0, :])
-
-    # ---- phase 4: dense RANGE slots owned by this block's buckets ----
-    # slot p carries the post-update global rank of its key; the block
-    # claims p iff the rank falls in one of its buckets' [pref[b],
-    # pref[b+1]) spans, then maps the in-bucket rank to a (node, pos) of
-    # the stripe just rebuilt above (ocnt cumsum = node boundaries).
-    # Valid slots are a prefix, so g[0] < 0 ⇔ nothing to emit — batches
-    # with no RANGE output skip the gather compute entirely and keep the
-    # PR-2 update-only cost (the init above already wrote EMPTY).
-    @pl.when(g_ref[0, 0] >= 0)
-    def _range_gather():
-        g = g_ref[0, :]                        # [MR]
-        gcol = g[:, None]
-        ps = ps_ref[0, :][None, :]             # [1, BB]
-        pe = pe_ref[0, :][None, :]
-        bloc = jnp.sum((pe <= gcol).astype(jnp.int32), axis=1)
-        bloc_c = jnp.minimum(bloc, bb - 1)
-        oh_rb = (
-            jax.lax.broadcasted_iota(jnp.int32, (g.shape[0], bb), 1)
-            == bloc_c[:, None]
-        )
-        ps_g = jnp.sum(jnp.where(oh_rb, ps, 0), axis=1)
-        mine_r = (g >= 0) & (bloc < bb) & (g >= ps_g)
-        r = g - ps_g                           # rank within the bucket
-
-        cnt_rows = _exact_gather_i32(oh_rb.astype(jnp.float32), ocnt)
-        cum = jnp.cumsum(cnt_rows, axis=1)     # [MR, npb]
-        node_r = jnp.sum((cum <= r[:, None]).astype(jnp.int32), axis=1)
-        node_rc = jnp.minimum(node_r, npb - 1)
-        oh_nd = (
-            jax.lax.broadcasted_iota(jnp.int32, (g.shape[0], npb), 1)
-            == node_rc[:, None]
-        )
-        base = jnp.sum(jnp.where(oh_nd, cum - cnt_rows, 0), axis=1)
-        pos_r = jnp.clip(r - base, 0, ns - 1)
-
-        flat_r = bloc_c * npb + node_rc
-        oh_fr = (
-            jax.lax.broadcasted_iota(jnp.int32, (g.shape[0], bb * npb), 1)
-            == flat_r[:, None]
-        ).astype(jnp.float32)
-        krow_r = _exact_gather_i32(oh_fr, fk.reshape(bb * npb, ns))
-        vrow_r = _exact_gather_i32(oh_fr, fv.reshape(bb * npb, ns))
-        oh_pr = (
-            jax.lax.broadcasted_iota(jnp.int32, (g.shape[0], ns), 1)
-            == pos_r[:, None]
-        )
-        kk = jnp.sum(jnp.where(oh_pr, krow_r, 0), axis=1)
-        vv = jnp.sum(jnp.where(oh_pr, vrow_r, 0), axis=1)
-        rngk_ref[0, :] = jnp.where(mine_r, kk, rngk_ref[0, :])
-        rngv_ref[0, :] = jnp.where(mine_r, vv, rngv_ref[0, :])
+    jax.lax.fori_loop(0, block_b, one, 0)
 
 
 def _init_outputs(j, i, resv_ref, resk_ref, rngk_ref, rngv_ref):
@@ -389,25 +458,24 @@ def _apply_kernel(
     hi_ref,      # scalar prefetch: [n_windows] last  bucket block of window
     t_ref,
     q_ref,
-    keys_ref,    # [BB, npb*ns] bucket-block key stripes (auto-pipelined)
-    vals_ref,    # [BB, npb*ns]
-    *rest,
+    keys_ref,    # [BB, S] bucket-block key stripes (auto-pipelined)
+    vals_ref,    # [BB, S]
+    *rest,       # the remaining blocked inputs/outputs, the column scratch
     block_b: int,
     npb: int,
     ns: int,
-    cap: int,
 ):
     """Single-buffer variant: stripes stream through the BlockSpec pipeline."""
     j = pl.program_id(0)
     i = pl.program_id(1)
-    _init_outputs(j, i, *rest[-4:])
+    _init_outputs(j, i, *rest[-5:-1])
     active = (i >= lo_ref[j]) & (i <= hi_ref[j])
 
     @pl.when(active)
     def _process():
         _stripe_body(
-            keys_ref[...], vals_ref[...], t_ref, q_ref, *rest,
-            block_b=block_b, npb=npb, ns=ns, cap=cap,
+            keys_ref, vals_ref, t_ref, q_ref, *rest,
+            block_b=block_b, npb=npb, ns=ns,
         )
 
 
@@ -416,15 +484,14 @@ def _apply_kernel_pipelined(
     hi_ref,      # scalar prefetch: [n_windows] last  bucket block of window
     t_ref,
     q_ref,
-    keys_hbm,    # [nb_p, npb*ns] FULL key stripes, HBM-resident (ANY space)
-    vals_hbm,    # [nb_p, npb*ns]
+    keys_hbm,    # [nb_blocks, BB, S] FULL key stripes, HBM-resident (ANY)
+    vals_hbm,    # [nb_blocks, BB, S]
     *rest,       # the remaining blocked inputs/outputs, then the scratch:
     #              kscr/vscr [2, BB, S] two-slot VMEM stripes, ksem/vsem
-    #              DMA semaphores [2]
+    #              DMA semaphores [2], the column scratch
     block_b: int,
     npb: int,
     ns: int,
-    cap: int,
     nb_blocks: int,
     n_windows: int,
 ):
@@ -447,8 +514,8 @@ def _apply_kernel_pipelined(
     """
     j = pl.program_id(0)
     i = pl.program_id(1)
-    kscr, vscr, ksem, vsem = rest[-4:]
-    rest = rest[:-4]
+    kscr, vscr, ksem, vsem, cols = rest[-5:]
+    rest = (*rest[:-5], cols)
     step = j * nb_blocks + i
     slot = jax.lax.rem(step, 2)
 
@@ -456,10 +523,9 @@ def _apply_kernel_pipelined(
         return jnp.clip(ii, lo_ref[jj], hi_ref[jj])
 
     def copies(b, sl):
-        row = pl.ds(b * block_b, block_b)
         return (
-            pltpu.make_async_copy(keys_hbm.at[row, :], kscr.at[sl], ksem.at[sl]),
-            pltpu.make_async_copy(vals_hbm.at[row, :], vscr.at[sl], vsem.at[sl]),
+            pltpu.make_async_copy(keys_hbm.at[b], kscr.at[sl], ksem.at[sl]),
+            pltpu.make_async_copy(vals_hbm.at[b], vscr.at[sl], vsem.at[sl]),
         )
 
     @pl.when(step == 0)
@@ -477,15 +543,140 @@ def _apply_kernel_pipelined(
     for c in copies(block_of(j, i), slot):
         c.wait()
 
-    _init_outputs(j, i, *rest[-4:])
+    _init_outputs(j, i, *rest[-5:-1])
     active = (i >= lo_ref[j]) & (i <= hi_ref[j])
 
     @pl.when(active)
     def _process():
         _stripe_body(
-            kscr[slot], vscr[slot], t_ref, q_ref, *rest,
-            block_b=block_b, npb=npb, ns=ns, cap=cap,
+            kscr.at[slot], vscr.at[slot], t_ref, q_ref, *rest,
+            block_b=block_b, npb=npb, ns=ns,
         )
+
+
+def apply_call(
+    lo,          # [n_windows] first bucket block of each window (scalar prefetch)
+    hi,          # [n_windows] last bucket block of each window
+    tags,        # [n_windows, 1, QB] op tags
+    keys,        # [n_windows, 1, QB] sorted op keys
+    stripe_k,    # [nb_blocks, BB, S] bucket key stripes
+    stripe_v,    # [nb_blocks, BB, S]
+    node_max,    # [nb_blocks, BB, npb]
+    ik,          # [nb_blocks, BB, S] per-bucket INSERT keys
+    iv,          # [nb_blocks, BB, S]
+    dk,          # [nb_blocks, BB, S] per-bucket DELETE keys
+    fences,      # [nb_blocks, BB, N_FENCE_ROWS]
+    g_row,       # [1, MR] per-RANGE-slot global ranks
+    *,
+    ns: int,
+    interpret: bool,
+    pipeline: bool,
+):
+    """The fused kernel's ``pallas_call`` on pre-blocked operands.
+
+    Returns ``(keys, vals, node_count, node_max, meta, value, succ_key,
+    range_key, range_val)`` in the same blocked layouts.  Split out of the
+    wrapper so the kernel can be compiled for a described chip from shapes
+    alone (``tests/test_chip_compile.py``).
+    """
+    n_windows, _, block_q = tags.shape
+    nb_blocks, block_b, S = stripe_k.shape
+    npb = S // ns
+    cap = ik.shape[2]
+    mrp = g_row.shape[1]
+
+    def bucket_map(j, i, lo_ref, hi_ref):
+        return (jnp.clip(i, lo_ref[j], hi_ref[j]), 0, 0)
+
+    def window_map(j, i, lo_ref, hi_ref):
+        return (j, 0, 0)
+
+    def per_bucket(width):
+        return pl.BlockSpec((None, block_b, width), bucket_map)
+
+    window_spec = pl.BlockSpec((None, 1, block_q), window_map)
+    range_spec = pl.BlockSpec((1, mrp), lambda j, i, lo, hi: (0, 0))
+
+    cols = pltpu.VMEM((N_COLS, S, 1), jnp.int32)
+
+    # the pipelined variant stages the big stripe planes itself: keys/vals
+    # stay HBM-resident (ANY memory space) and a two-slot VMEM scratch +
+    # DMA semaphore pair per plane double-buffers them across grid steps;
+    # everything else keeps the automatic BlockSpec pipeline either way
+    if pipeline:
+        stripe_specs = [
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ]
+        scratch_shapes = [
+            pltpu.VMEM((2, block_b, S), jnp.int32),
+            pltpu.VMEM((2, block_b, S), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            cols,
+        ]
+        kernel = functools.partial(
+            _apply_kernel_pipelined,
+            block_b=block_b,
+            npb=npb,
+            ns=ns,
+            nb_blocks=nb_blocks,
+            n_windows=n_windows,
+        )
+    else:
+        stripe_specs = [per_bucket(S), per_bucket(S)]
+        scratch_shapes = [cols]
+        kernel = functools.partial(_apply_kernel, block_b=block_b, npb=npb, ns=ns)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_windows, nb_blocks),
+        in_specs=[
+            window_spec,
+            window_spec,
+            *stripe_specs,
+            per_bucket(npb),
+            per_bucket(cap),
+            per_bucket(cap),
+            per_bucket(cap),
+            per_bucket(N_FENCE_ROWS),
+            range_spec,
+        ],
+        out_specs=[
+            per_bucket(S),
+            per_bucket(S),
+            per_bucket(npb),
+            per_bucket(npb),
+            per_bucket(3),
+            window_spec,
+            window_spec,
+            range_spec,
+            range_spec,
+        ],
+        scratch_shapes=scratch_shapes,
+    )
+
+    i32 = jnp.int32
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((nb_blocks, block_b, S), i32),
+            jax.ShapeDtypeStruct((nb_blocks, block_b, S), i32),
+            jax.ShapeDtypeStruct((nb_blocks, block_b, npb), i32),
+            jax.ShapeDtypeStruct((nb_blocks, block_b, npb), i32),
+            jax.ShapeDtypeStruct((nb_blocks, block_b, 3), i32),
+            jax.ShapeDtypeStruct((n_windows, 1, block_q), i32),
+            jax.ShapeDtypeStruct((n_windows, 1, block_q), i32),
+            jax.ShapeDtypeStruct((1, mrp), i32),
+            jax.ShapeDtypeStruct((1, mrp), i32),
+        ],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+    )(lo, hi, tags, keys, stripe_k, stripe_v, node_max, ik, iv, dk, fences, g_row)
 
 
 def _fused_apply(
@@ -628,11 +819,10 @@ def _fused_apply(
     tpad = jnp.pad(tag, (0, qp - n), constant_values=OP_NOP)
     qpad = jnp.pad(key.astype(KEY_DTYPE), (0, qp - n), constant_values=EMPTY)
     n_windows = qp // block_q
-    t2 = tpad.reshape(n_windows, block_q)
-    q2 = qpad.reshape(n_windows, block_q)
 
     # per-window bucket-block bounds; window 0 widens to the full sweep —
     # that is where every stripe's update pass is guaranteed to happen.
+    q2 = qpad.reshape(n_windows, block_q)
     first_b = jnp.searchsorted(mkba, q2[:, 0], side="left")
     last_b = jnp.searchsorted(mkba, q2[:, -1], side="left")
     nb_blocks = nb_p // block_b
@@ -641,151 +831,46 @@ def _fused_apply(
     lo = lo.at[0].set(0)
     hi = hi.at[0].set(nb_blocks - 1)
 
-    mkba_row = mkba.reshape(1, nb_p)
-    lf_row = lfence.reshape(1, nb_p)
-    nxk_row = next_key.reshape(1, nb_p)
-    nxv_row = next_val.reshape(1, nb_p)
-    ps_row = ps_row_post.reshape(1, nb_p)
-    pe_row = pe_row_post.reshape(1, nb_p)
+    # Mosaic takes a block whose last two dims are (8, 128)-aligned or span
+    # the whole array, so every blocked array gets a leading grid axis and
+    # full trailing dims: per-bucket planes [nb_blocks, BB, W] (fences and
+    # the node-count/overflow/deleted triple included), op windows
+    # [n_windows, 1, QB].
+    def by_block(x):
+        return x.reshape(nb_blocks, block_b, x.shape[1])
 
-    def bucket_map(j, i, lo_ref, hi_ref):
-        return (jnp.clip(i, lo_ref[j], hi_ref[j]), 0)
+    fences = jnp.stack(
+        [mkba, lfence, next_key, next_val, ps_row_post, pe_row_post], axis=1
+    ).astype(jnp.int32)                        # [nb_p, N_FENCE_ROWS]
 
-    def fence_map(j, i, lo_ref, hi_ref):
-        return (0, jnp.clip(i, lo_ref[j], hi_ref[j]))
-
-    def window_map(j, i, lo_ref, hi_ref):
-        return (j, 0)
-
-    # the pipelined variant stages the big stripe planes itself: keys/vals
-    # stay HBM-resident (ANY memory space) and a two-slot VMEM scratch +
-    # DMA semaphore pair per plane double-buffers them across grid steps;
-    # everything else keeps the automatic BlockSpec pipeline either way
-    if pipeline:
-        stripe_specs = [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ]
-        scratch_shapes = [
-            pltpu.VMEM((2, block_b, S), jnp.int32),
-            pltpu.VMEM((2, block_b, S), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-        kernel = functools.partial(
-            _apply_kernel_pipelined,
-            block_b=block_b,
-            npb=npb,
-            ns=ns,
-            cap=cap,
-            nb_blocks=nb_blocks,
-            n_windows=n_windows,
-        )
-    else:
-        stripe_specs = [
-            pl.BlockSpec((block_b, S), bucket_map),
-            pl.BlockSpec((block_b, S), bucket_map),
-        ]
-        scratch_shapes = []
-        kernel = functools.partial(
-            _apply_kernel, block_b=block_b, npb=npb, ns=ns, cap=cap
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_windows, nb_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q), window_map),
-            pl.BlockSpec((1, block_q), window_map),
-            *stripe_specs,
-            pl.BlockSpec((block_b, npb), bucket_map),
-            pl.BlockSpec((block_b, cap), bucket_map),
-            pl.BlockSpec((block_b, cap), bucket_map),
-            pl.BlockSpec((block_b, cap), bucket_map),
-            pl.BlockSpec((1, block_b), fence_map),
-            pl.BlockSpec((1, block_b), fence_map),
-            pl.BlockSpec((1, block_b), fence_map),
-            pl.BlockSpec((1, block_b), fence_map),
-            pl.BlockSpec((1, mrp), lambda j, i, lo, hi: (0, 0)),
-            pl.BlockSpec((1, block_b), fence_map),
-            pl.BlockSpec((1, block_b), fence_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, S), bucket_map),
-            pl.BlockSpec((block_b, S), bucket_map),
-            pl.BlockSpec((block_b, npb), bucket_map),
-            pl.BlockSpec((block_b, npb), bucket_map),
-            pl.BlockSpec((block_b, 1), bucket_map),
-            pl.BlockSpec((block_b, 1), bucket_map),
-            pl.BlockSpec((block_b, 1), bucket_map),
-            pl.BlockSpec((1, block_q), window_map),
-            pl.BlockSpec((1, block_q), window_map),
-            pl.BlockSpec((1, mrp), lambda j, i, lo, hi: (0, 0)),
-            pl.BlockSpec((1, mrp), lambda j, i, lo, hi: (0, 0)),
-        ],
-        scratch_shapes=scratch_shapes,
-    )
-
-    (
-        okeys,
-        ovals,
-        ocnt,
-        omax,
-        onn,
-        oflow,
-        odel,
-        resv,
-        resk,
-        rngk,
-        rngv,
-    ) = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nb_p, S), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, S), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, npb), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, npb), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nb_p, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_windows, block_q), jnp.int32),
-            jax.ShapeDtypeStruct((n_windows, block_q), jnp.int32),
-            jax.ShapeDtypeStruct((1, mrp), jnp.int32),
-            jax.ShapeDtypeStruct((1, mrp), jnp.int32),
-        ],
-        interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
-        ),
-    )(
+    okeys, ovals, ocnt, omax, bmeta, resv, resk, rngk, rngv = apply_call(
         lo,
         hi,
-        t2,
-        q2,
-        keys2d,
-        vals2d,
-        node_max,
-        ik,
-        iv,
-        dk_tile,
-        mkba_row,
-        lf_row,
-        nxk_row,
-        nxv_row,
+        tpad.reshape(n_windows, 1, block_q),
+        qpad.reshape(n_windows, 1, block_q),
+        by_block(keys2d),
+        by_block(vals2d),
+        by_block(node_max),
+        by_block(ik),
+        by_block(iv),
+        by_block(dk_tile),
+        by_block(fences),
         g_row,
-        ps_row,
-        pe_row,
+        ns=ns,
+        interpret=interpret,
+        pipeline=pipeline,
     )
+    bmeta = bmeta.reshape(nb_p, 3)[:nb]
+    onn, oflow, odel = bmeta[:, 0], bmeta[:, 1], bmeta[:, 2]
 
     slice_overflow = true_counts > cap
-    any_overflow = (jnp.sum(oflow[:nb]) > 0) | jnp.any(slice_overflow)
+    any_overflow = (jnp.sum(oflow) > 0) | jnp.any(slice_overflow)
     new_state = FliXState(
-        keys=okeys[:nb].reshape(nb, npb, ns),
-        vals=ovals[:nb].reshape(nb, npb, ns),
-        node_count=ocnt[:nb],
-        node_max=omax[:nb],
-        num_nodes=onn[:nb, 0],
+        keys=okeys.reshape(nb_p, npb, ns)[:nb],
+        vals=ovals.reshape(nb_p, npb, ns)[:nb],
+        node_count=ocnt.reshape(nb_p, npb)[:nb],
+        node_max=omax.reshape(nb_p, npb)[:nb],
+        num_nodes=onn,
         mkba=state.mkba,
         needs_restructure=state.needs_restructure | any_overflow,
     )
@@ -799,10 +884,8 @@ def _fused_apply(
     }
     stats = {
         "inserted": jnp.sum(jnp.minimum(true_counts, cap)),
-        "deleted": jnp.sum(odel[:nb]),
-        "overflowed_buckets": jnp.sum(
-            (oflow[:nb, 0] > 0) | slice_overflow
-        ),
+        "deleted": jnp.sum(odel),
+        "overflowed_buckets": jnp.sum((oflow > 0) | slice_overflow),
         "range_truncated": rtrunc,
     }
     return new_state, results, stats

@@ -22,8 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.batch import bucket_slices, gather_sublists
 from repro.core.state import EMPTY, KEY_DTYPE, FliXState
@@ -160,7 +159,7 @@ def flix_delete_pallas(
             jax.ShapeDtypeStruct((nb_p, 1), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(keys, vals, del_tile)
 
     return FliXState(

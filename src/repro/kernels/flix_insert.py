@@ -27,8 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.batch import bucket_slices, gather_kv_sublists
 from repro.core.state import KEY_DTYPE, VAL_DTYPE, FliXState
@@ -193,7 +192,7 @@ def flix_insert_pallas(
             jax.ShapeDtypeStruct((nb, 1), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(
         state.keys.reshape(nb, npb * ns),
         state.vals.reshape(nb, npb * ns),

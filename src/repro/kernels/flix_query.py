@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.state import EMPTY, KEY_DTYPE
 
 DEFAULT_BLOCK_Q = 128   # queries per window
@@ -41,14 +39,21 @@ _MISS = -1              # NOT_FOUND as a Python literal (kernels must not
 
 
 def _exact_gather_i32(onehot_f32: jax.Array, table_i32: jax.Array) -> jax.Array:
-    """Exact int32 row gather as two f32 MXU matmuls (hi/lo 16-bit split)."""
-    u = table_i32.astype(jnp.uint32)
-    lo = (u & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    hi = (u >> jnp.uint32(16)).astype(jnp.float32)
-    glo = jax.lax.dot(onehot_f32, lo, preferred_element_type=jnp.float32)
-    ghi = jax.lax.dot(onehot_f32, hi, preferred_element_type=jnp.float32)
-    out = ghi.astype(jnp.uint32) * jnp.uint32(65536) + glo.astype(jnp.uint32)
-    return out.astype(jnp.int32)
+    """Exact int32 row gather as two f32 MXU matmuls (hi/lo 16-bit split).
+
+    The halves are split with an int32 mask and a logical shift — Mosaic
+    has no uint32 ↔ f32 cast — and each is < 2^16, so the one-hot products
+    are exact in f32 at full matmul precision."""
+    lo = (table_i32 & 0xFFFF).astype(jnp.float32)
+    hi = jax.lax.shift_right_logical(table_i32, 16).astype(jnp.float32)
+    dot = functools.partial(
+        jax.lax.dot,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    glo = dot(onehot_f32, lo).astype(jnp.int32)
+    ghi = dot(onehot_f32, hi).astype(jnp.int32)
+    return jax.lax.shift_left(ghi, 16) | glo
 
 
 def _query_kernel(
@@ -199,7 +204,7 @@ def flix_point_query_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_windows, block_q), jnp.int32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
     )(lo, hi, q2, keys2d, vals2d, node_max, mkba_row, lf_row)

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.flix_query import DEFAULT_BLOCK_Q, _exact_gather_i32
 from repro.core.state import EMPTY, KEY_DTYPE
 
@@ -221,7 +220,7 @@ def flix_range_pallas(
         grid_spec=count_spec,
         out_shape=jax.ShapeDtypeStruct((n_windows, block_q), jnp.int32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
     )(lo_blk, hi_blk, l2, h2, flat_kp)
@@ -281,7 +280,7 @@ def flix_range_pallas(
             jax.ShapeDtypeStruct((1, mrp), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(
         lo2,
         hi2,

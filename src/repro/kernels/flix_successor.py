@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.flix_query import (
     DEFAULT_BLOCK_B,
     DEFAULT_BLOCK_Q,
@@ -226,7 +225,7 @@ def flix_successor_pallas(
             jax.ShapeDtypeStruct((n_windows, block_q), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
     )(lo, hi, q2, keys2d, vals2d, node_max, mkba_row, lf_row, nxk_row, nxv_row)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 DEFAULT_BLOCK_T = 128
 DEFAULT_BLOCK_F = 128
 
@@ -116,7 +114,7 @@ def grouped_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
     )(offs, elo, ehi, x, w)

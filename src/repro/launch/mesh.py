@@ -11,15 +11,15 @@ import jax
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the jax version has them.
+    """``jax.make_mesh`` with Auto axis types.
 
-    ``jax.sharding.AxisType`` only exists in newer jax; older releases treat
-    every axis as Auto already, so omitting the argument is equivalent.
+    ``jax.make_mesh`` without ``axis_types`` builds Explicit axes, under
+    which jitted code must carry sharding-typed values; every caller here
+    shards through ``NamedSharding`` and ``with mesh:`` instead.
     """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,4 +34,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh_auto((data, model), ("data", "model"))

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
-
 
 def _local_capacity(t_loc: int, k: int, n_shards: int, factor: float) -> int:
     c = math.ceil(t_loc * k / n_shards * factor)
@@ -135,7 +133,7 @@ def moe_ffn_a2a(x: jax.Array, p: dict, cfg, mesh) -> jax.Array:
         out = jnp.zeros((tl + 1, D), contrib.dtype).at[tok].add(contrib)[:tl]
         return out.astype(x_loc.dtype)
 
-    y = _shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
