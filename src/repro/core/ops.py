@@ -64,6 +64,7 @@ from repro.core.config import (
     resolve_config,
 )
 from repro.core.state import EMPTY, KEY_DTYPE, NOT_FOUND, VAL_DTYPE, FliXState
+from repro.core.trace import span
 
 OP_INSERT = 0
 OP_DELETE = 1
@@ -142,32 +143,33 @@ def make_ops(tags, keys, vals=None, *, exps=None, pad_to: int | None = None):
     """
     from repro.core.expiry import NO_EXPIRY
 
-    tags = jnp.asarray(tags, OP_DTYPE)
-    keys = jnp.asarray(keys, KEY_DTYPE)
-    if vals is None:
-        vals = jnp.zeros(keys.shape, VAL_DTYPE)
-    vals = jnp.asarray(vals, VAL_DTYPE)
-    if exps is not None:
-        exps = jnp.asarray(exps, KEY_DTYPE)
-    if pad_to is not None and pad_to > keys.shape[0]:
-        extra = pad_to - keys.shape[0]
-        tags = jnp.concatenate([tags, jnp.full((extra,), OP_NOP, OP_DTYPE)])
-        keys = jnp.concatenate([keys, jnp.full((extra,), EMPTY, KEY_DTYPE)])
-        vals = jnp.concatenate([vals, jnp.zeros((extra,), VAL_DTYPE)])
+    with span("make_ops"):
+        tags = jnp.asarray(tags, OP_DTYPE)
+        keys = jnp.asarray(keys, KEY_DTYPE)
+        if vals is None:
+            vals = jnp.zeros(keys.shape, VAL_DTYPE)
+        vals = jnp.asarray(vals, VAL_DTYPE)
         if exps is not None:
-            exps = jnp.concatenate([exps, jnp.full((extra,), NO_EXPIRY, KEY_DTYPE)])
-    order = jnp.argsort(keys, stable=True)
-    # inverse permutation (input position -> sorted position) by O(N) scatter
-    perm = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
-    return (
-        OpBatch(
-            tag=tags[order],
-            key=keys[order],
-            val=vals[order],
-            exp=None if exps is None else exps[order],
-        ),
-        perm,
-    )
+            exps = jnp.asarray(exps, KEY_DTYPE)
+        if pad_to is not None and pad_to > keys.shape[0]:
+            extra = pad_to - keys.shape[0]
+            tags = jnp.concatenate([tags, jnp.full((extra,), OP_NOP, OP_DTYPE)])
+            keys = jnp.concatenate([keys, jnp.full((extra,), EMPTY, KEY_DTYPE)])
+            vals = jnp.concatenate([vals, jnp.zeros((extra,), VAL_DTYPE)])
+            if exps is not None:
+                exps = jnp.concatenate([exps, jnp.full((extra,), NO_EXPIRY, KEY_DTYPE)])
+        order = jnp.argsort(keys, stable=True)
+        # inverse permutation (input position -> sorted position) by O(N) scatter
+        perm = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+        return (
+            OpBatch(
+                tag=tags[order],
+                key=keys[order],
+                val=vals[order],
+                exp=None if exps is None else exps[order],
+            ),
+            perm,
+        )
 
 
 def unsort(sorted_result: jax.Array, perm: jax.Array) -> jax.Array:
@@ -332,70 +334,76 @@ def _apply_ops_reference(
     n = key.shape[0]
 
     # --- the single routing + derived per-type views (no second sort) -----
-    (
-        is_ins,
-        is_del,
-        ins_keys,
-        ins_vals,
-        del_keys,
-        ins_starts,
-        ins_ends,
-    ) = derive_type_views(state, tag, key, val)
+    with jax.named_scope("flix.reference.route"):
+        (
+            is_ins,
+            is_del,
+            ins_keys,
+            ins_vals,
+            del_keys,
+            ins_starts,
+            ins_ends,
+        ) = derive_type_views(state, tag, key, val)
 
     # --- update phase: merge inserts, then physical deletes ---------------
     # an absent op class skips its phase entirely (lax.cond executes one
     # branch), so read-heavy batches don't pay the merge machinery; the
     # differential contract is correspondingly "apply the present types".
-    s1, ins_stats = jax.lax.cond(
-        jnp.any(is_ins),
-        lambda: insert_with_slices(state, ins_keys, ins_vals, ins_starts, ins_ends),
-        lambda: (
-            state,
-            {
-                "inserted": jnp.int32(0),
-                "nodes_after": jnp.sum(state.num_nodes),
-                "splits": jnp.int32(0),
-                "overflowed_buckets": jnp.int32(0),
-            },
-        ),
-    )
-    s2, del_stats = jax.lax.cond(
-        jnp.any(is_del),
-        lambda: delete(s1, del_keys),
-        lambda: (s1, {"deleted": jnp.int32(0), "nodes_freed": jnp.int32(0)}),
-    )
+    with jax.named_scope("flix.reference.insert"):
+        s1, ins_stats = jax.lax.cond(
+            jnp.any(is_ins),
+            lambda: insert_with_slices(state, ins_keys, ins_vals, ins_starts, ins_ends),
+            lambda: (
+                state,
+                {
+                    "inserted": jnp.int32(0),
+                    "nodes_after": jnp.sum(state.num_nodes),
+                    "splits": jnp.int32(0),
+                    "overflowed_buckets": jnp.int32(0),
+                },
+            ),
+        )
+    with jax.named_scope("flix.reference.delete"):
+        s2, del_stats = jax.lax.cond(
+            jnp.any(is_del),
+            lambda: delete(s1, del_keys),
+            lambda: (s1, {"deleted": jnp.int32(0), "nodes_freed": jnp.int32(0)}),
+        )
 
     # --- read phase: flipped compare-count against the updated state ------
     is_point = tag == OP_POINT
     is_succ = tag == OP_SUCCESSOR
-    pv = jax.lax.cond(
-        jnp.any(is_point),
-        lambda: point_query(s2, key),
-        lambda: jnp.full((n,), NOT_FOUND, VAL_DTYPE),
-    )
-    sk, sv = jax.lax.cond(
-        jnp.any(is_succ),
-        lambda: successor_query(s2, key),
-        lambda: (
-            jnp.full((n,), EMPTY, KEY_DTYPE),
-            jnp.full((n,), NOT_FOUND, VAL_DTYPE),
-        ),
-    )
+    with jax.named_scope("flix.reference.point"):
+        pv = jax.lax.cond(
+            jnp.any(is_point),
+            lambda: point_query(s2, key),
+            lambda: jnp.full((n,), NOT_FOUND, VAL_DTYPE),
+        )
+    with jax.named_scope("flix.reference.successor"):
+        sk, sv = jax.lax.cond(
+            jnp.any(is_succ),
+            lambda: successor_query(s2, key),
+            lambda: (
+                jnp.full((n,), EMPTY, KEY_DTYPE),
+                jnp.full((n,), NOT_FOUND, VAL_DTYPE),
+            ),
+        )
     # --- range phase: dense [lo, hi) scans against the updated state ------
     is_range = tag == OP_RANGE
-    rk, rv, rstart, rcnt, rtrunc = jax.lax.cond(
-        jnp.any(is_range),
-        lambda: dense_range_scan(
-            s2, is_range, key, val.astype(KEY_DTYPE), max_results=max_results
-        ),
-        lambda: (
-            jnp.full((max_results,), EMPTY, KEY_DTYPE),
-            jnp.full((max_results,), NOT_FOUND, VAL_DTYPE),
-            jnp.zeros((n,), jnp.int32),
-            jnp.zeros((n,), jnp.int32),
-            jnp.int32(0),
-        ),
-    )
+    with jax.named_scope("flix.reference.range"):
+        rk, rv, rstart, rcnt, rtrunc = jax.lax.cond(
+            jnp.any(is_range),
+            lambda: dense_range_scan(
+                s2, is_range, key, val.astype(KEY_DTYPE), max_results=max_results
+            ),
+            lambda: (
+                jnp.full((max_results,), EMPTY, KEY_DTYPE),
+                jnp.full((max_results,), NOT_FOUND, VAL_DTYPE),
+                jnp.zeros((n,), jnp.int32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.int32(0),
+            ),
+        )
 
     results = {
         "value": jnp.where(is_point, pv, jnp.where(is_succ, sv, NOT_FOUND)),
@@ -424,11 +432,11 @@ def resolve_impl(impl: str, ops: OpBatch, has_updates: bool | None = None) -> st
     if jax.default_backend() != "tpu":
         return "reference"
     if has_updates is None:
-        has_updates = bool(
-            jnp.any(
+        with span("sync.has_updates"):
+            is_update = (
                 (ops.tag == OP_INSERT) | (ops.tag == OP_DELETE) | (ops.tag == OP_EXPIRE)
             )
-        )
+            has_updates = bool(jnp.any(is_update))
     return "fused" if has_updates else "reference"
 
 
@@ -474,7 +482,8 @@ def plain_executor(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConfig
 def _apply_ops_plain(state: FliXState, ops: OpBatch, *, impl: str, cfg: ExecConfig):
     """Run one TTL-free batch on the chosen executor (impl resolved)."""
     fn, args, kwargs = plain_executor(state, ops, impl=impl, cfg=cfg)
-    return fn(*args, **kwargs)
+    with span("dispatch.fused" if impl == "fused" else "dispatch.reference"):
+        return fn(*args, **kwargs)
 
 
 def _apply_ops_ttl(
@@ -694,45 +703,54 @@ def apply_ops_safe(
     """
     from repro.core.restructure import restructure_grow
 
-    cfg = resolve_config(
-        "apply_ops_safe",
-        config,
-        impl=impl,
-        max_results=max_results,
-        validate_ranges=validate_ranges,
-        validate=validate,
-    )
-    # a retry replays the batch on the pre-batch state — never donate here
-    run_cfg = cfg.replace(donate=False, validate=False, validate_ranges=False)
-    restructure_retries = 0
-    new_state, results, stats = apply_ops(
-        state, ops, config=run_cfg, has_updates=has_updates, now=now
-    )
-    if bool(new_state.needs_restructure) and not bool(state.needs_restructure):
-        n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-        grown = restructure_grow(state, extra_keys=max(n_ins, 1))
-        new_state, results, stats = apply_ops(
-            grown, ops, config=run_cfg, has_updates=has_updates, now=now
+    with span("apply_ops_safe"):
+        cfg = resolve_config(
+            "apply_ops_safe",
+            config,
+            impl=impl,
+            max_results=max_results,
+            validate_ranges=validate_ranges,
+            validate=validate,
         )
-        assert not bool(new_state.needs_restructure), "post-restructure overflow"
-        restructure_retries = 1
-    stats = dict(stats)
-    stats["restructure_retries"] = restructure_retries
-    if cfg.validate_ranges:
-        from repro.core.invariants import check_range_results
+        # a retry replays the batch on the pre-batch state — never donate here
+        run_cfg = cfg.replace(donate=False, validate=False, validate_ranges=False)
+        restructure_retries = 0
+        new_state, results, stats = apply_ops(
+            state, ops, config=run_cfg, has_updates=has_updates, now=now
+        )
+        # waits for the executor: the device's tail of the batch ends here
+        with span("sync.needs_restructure"):
+            overflowed = bool(new_state.needs_restructure) and not bool(
+                state.needs_restructure
+            )
+        if overflowed:
+            with span("restructure"):
+                n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
+                grown = restructure_grow(state, extra_keys=max(n_ins, 1))
+                new_state, results, stats = apply_ops(
+                    grown, ops, config=run_cfg, has_updates=has_updates, now=now
+                )
+                assert not bool(new_state.needs_restructure), (
+                    "post-restructure overflow"
+                )
+            restructure_retries = 1
+        stats = dict(stats)
+        stats["restructure_retries"] = restructure_retries
+        if cfg.validate_ranges:
+            from repro.core.invariants import check_range_results
 
-        check_range_results(ops, results, max_results=cfg.max_results)
-    if cfg.validate:
-        from repro.core.invariants import check_invariants
+            check_range_results(ops, results, max_results=cfg.max_results)
+        if cfg.validate:
+            from repro.core.invariants import check_invariants
 
-        check_now = now
-        if now is not None and ops.exp is not None:
-            # the §14 same-batch edge: a row THIS batch wrote with
-            # ``exp <= now`` is legitimately live until the next batch's
-            # expiry pre-pass, so liveness-at-now cannot be asserted on
-            # the post-state of a batch carrying dead-on-arrival writes
-            wrote = (ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)
-            if bool(jnp.any(wrote & (ops.exp <= jnp.asarray(now, KEY_DTYPE)))):
-                check_now = None
-        check_invariants(new_state, now=check_now)
-    return new_state, results, stats
+            check_now = now
+            if now is not None and ops.exp is not None:
+                # the §14 same-batch edge: a row THIS batch wrote with
+                # ``exp <= now`` is legitimately live until the next batch's
+                # expiry pre-pass, so liveness-at-now cannot be asserted on
+                # the post-state of a batch carrying dead-on-arrival writes
+                wrote = (ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)
+                if bool(jnp.any(wrote & (ops.exp <= jnp.asarray(now, KEY_DTYPE)))):
+                    check_now = None
+            check_invariants(new_state, now=check_now)
+        return new_state, results, stats

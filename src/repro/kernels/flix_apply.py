@@ -699,43 +699,46 @@ def _fused_apply(
 
     # --- the single routing + derived per-type views (shared with the
     # reference engine, so the routing contract cannot diverge) ------------
-    _, _, ins_keys, ins_vals, del_keys, ins_starts, ins_ends = (
-        derive_type_views(state, tag, key, val)
-    )
-    true_counts = (ins_ends - ins_starts).astype(jnp.int32)
+    with jax.named_scope("flix.fused.route"):
+        _, _, ins_keys, ins_vals, del_keys, ins_starts, ins_ends = (
+            derive_type_views(state, tag, key, val)
+        )
+        true_counts = (ins_ends - ins_starts).astype(jnp.int32)
 
-    # per-bucket INSERT tiles (keys + aligned vals)
-    ik, iv, _, _ = gather_kv_sublists(ins_keys, ins_vals, ins_starts, ins_ends, cap)
+        # per-bucket INSERT tiles (keys + aligned vals)
+        ik, iv, _, _ = gather_kv_sublists(ins_keys, ins_vals, ins_starts, ins_ends, cap)
 
-    # per-bucket DELETE tiles, pre-filtered to PRESENT keys so each bucket's
-    # sublist fits its capacity tile (same trick as flix_delete; filtering
-    # against the pre-insert state is exact because one batch never inserts
-    # and deletes the same key).
-    present = point_query(state, del_keys) != NOT_FOUND
-    dk_sorted = jnp.sort(jnp.where(present, del_keys, EMPTY))
-    dstarts, dends = bucket_slices(state, dk_sorted)
-    dk_tile, _, _ = gather_sublists(dk_sorted, dstarts, dends, cap)
+    with jax.named_scope("flix.fused.delete_tiles"):
+        # per-bucket DELETE tiles, pre-filtered to PRESENT keys so each bucket's
+        # sublist fits its capacity tile (same trick as flix_delete; filtering
+        # against the pre-insert state is exact because one batch never inserts
+        # and deletes the same key).
+        present = point_query(state, del_keys) != NOT_FOUND
+        dk_sorted = jnp.sort(jnp.where(present, del_keys, EMPTY))
+        dstarts, dends = bucket_slices(state, dk_sorted)
+        dk_tile, _, _ = gather_sublists(dk_sorted, dstarts, dends, cap)
 
     # --- post-update successor fence rows (one O(nb) suffix scan) ---------
-    # surviving stripe minimum: smallest stored key not in the delete batch
-    flat_k = state.keys.reshape(nb, S)
-    flat_v = state.vals.reshape(nb, S)
-    dpos = jnp.searchsorted(del_keys, flat_k.reshape(-1), side="left")
-    dpos = jnp.minimum(dpos, jnp.maximum(del_keys.shape[0] - 1, 0))
-    dhit = (del_keys[dpos] == flat_k.reshape(-1)) & (flat_k.reshape(-1) != EMPTY)
-    masked = jnp.where(dhit.reshape(nb, S), EMPTY, flat_k)
-    surv_min = jnp.min(masked, axis=1)
-    amin = jnp.argmin(masked, axis=1)
-    surv_val = flat_v[jnp.arange(nb), amin]
-    ins_min = ik[:, 0]                       # tiles are sorted, EMPTY-padded
-    ins_val = iv[:, 0]
-    bucket_min = jnp.minimum(surv_min, ins_min)
-    # tie (same key upserted) → the incoming value wins
-    min_val = jnp.where(ins_min <= surv_min, ins_val, surv_val)
-    smin, sidx = _suffix_min_with_index(bucket_min)
-    next_key = jnp.concatenate([smin[1:], jnp.array([EMPTY], KEY_DTYPE)])
-    next_idx = jnp.concatenate([sidx[1:], jnp.array([0], jnp.int32)])
-    next_val = min_val[next_idx]
+    with jax.named_scope("flix.fused.fence_rows"):
+        # surviving stripe minimum: smallest stored key not in the delete batch
+        flat_k = state.keys.reshape(nb, S)
+        flat_v = state.vals.reshape(nb, S)
+        dpos = jnp.searchsorted(del_keys, flat_k.reshape(-1), side="left")
+        dpos = jnp.minimum(dpos, jnp.maximum(del_keys.shape[0] - 1, 0))
+        dhit = (del_keys[dpos] == flat_k.reshape(-1)) & (flat_k.reshape(-1) != EMPTY)
+        masked = jnp.where(dhit.reshape(nb, S), EMPTY, flat_k)
+        surv_min = jnp.min(masked, axis=1)
+        amin = jnp.argmin(masked, axis=1)
+        surv_val = flat_v[jnp.arange(nb), amin]
+        ins_min = ik[:, 0]                       # tiles are sorted, EMPTY-padded
+        ins_val = iv[:, 0]
+        bucket_min = jnp.minimum(surv_min, ins_min)
+        # tie (same key upserted) → the incoming value wins
+        min_val = jnp.where(ins_min <= surv_min, ins_val, surv_val)
+        smin, sidx = _suffix_min_with_index(bucket_min)
+        next_key = jnp.concatenate([smin[1:], jnp.array([EMPTY], KEY_DTYPE)])
+        next_idx = jnp.concatenate([sidx[1:], jnp.array([0], jnp.int32)])
+        next_val = min_val[next_idx]
 
     # --- post-update RANGE rank fences + per-slot ranks -------------------
     # same predict-without-running-the-update argument as the fence rows,
@@ -746,148 +749,155 @@ def _fused_apply(
     # shared core.query formulas then fix the dense output layout.
     is_range = tag == _OP_RANGE
 
-    def _range_plumbing():
-        mflat = masked.reshape(-1)
-        ipos = jnp.clip(
-            jnp.searchsorted(ins_keys, mflat, side="left"), 0, max(n - 1, 0)
-        )
-        upserted = (ins_keys[ipos] == mflat) & (mflat != EMPTY)
-        post_rows = jnp.concatenate(
-            [jnp.where(upserted.reshape(nb, S), EMPTY, masked), ik], axis=1
-        )
-        post_sorted = jnp.sort(post_rows, axis=1)
-        live_post = jnp.sum(post_sorted != EMPTY, axis=1).astype(jnp.int32)
-        pref_post = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32), jnp.cumsum(live_post).astype(jnp.int32)]
-        )
-        rank_lo = flat_rank(post_sorted, pref_post, state.mkba, key)
-        rank_hi = flat_rank(post_sorted, pref_post, state.mkba, val.astype(KEY_DTYPE))
-        full = jnp.maximum(rank_hi - rank_lo, 0)
-        rstart, remit, total_emit, rtrunc = range_offsets(full, is_range, max_results)
-        g = range_slot_ranks(rank_lo, rstart, total_emit, max_results)
-        return g, pref_post[:-1], pref_post[1:], rstart, remit, rtrunc
+    with jax.named_scope("flix.fused.range_plumbing"):
+        def _range_plumbing():
+            mflat = masked.reshape(-1)
+            ipos = jnp.clip(
+                jnp.searchsorted(ins_keys, mflat, side="left"), 0, max(n - 1, 0)
+            )
+            upserted = (ins_keys[ipos] == mflat) & (mflat != EMPTY)
+            post_rows = jnp.concatenate(
+                [jnp.where(upserted.reshape(nb, S), EMPTY, masked), ik], axis=1
+            )
+            post_sorted = jnp.sort(post_rows, axis=1)
+            live_post = jnp.sum(post_sorted != EMPTY, axis=1).astype(jnp.int32)
+            pref_post = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32), jnp.cumsum(live_post).astype(jnp.int32)]
+            )
+            rank_lo = flat_rank(post_sorted, pref_post, state.mkba, key)
+            rank_hi = flat_rank(
+                post_sorted, pref_post, state.mkba, val.astype(KEY_DTYPE)
+            )
+            full = jnp.maximum(rank_hi - rank_lo, 0)
+            rstart, remit, total_emit, rtrunc = range_offsets(
+                full, is_range, max_results
+            )
+            g = range_slot_ranks(rank_lo, rstart, total_emit, max_results)
+            return g, pref_post[:-1], pref_post[1:], rstart, remit, rtrunc
 
-    # a batch with no RANGE ops skips the per-bucket post-state sort and
-    # rank scans entirely (lax.cond executes one branch — no host sync, and
-    # update-only fused steps keep their PR-2 cost); all slots dead (-1)
-    # makes the kernel's pl.when skip the phase-4 gather compute too
-    g, ps_row_post, pe_row_post, rstart, remit, rtrunc = jax.lax.cond(
-        jnp.any(is_range),
-        _range_plumbing,
-        lambda: (
-            jnp.full((max_results,), -1, jnp.int32),
-            jnp.zeros((nb,), jnp.int32),
-            jnp.zeros((nb,), jnp.int32),
-            jnp.zeros((n,), jnp.int32),
-            jnp.zeros((n,), jnp.int32),
-            jnp.int32(0),
-        ),
-    )
+        # a batch with no RANGE ops skips the per-bucket post-state sort and
+        # rank scans entirely (lax.cond executes one branch — no host sync, and
+        # update-only fused steps keep their PR-2 cost); all slots dead (-1)
+        # makes the kernel's pl.when skip the phase-4 gather compute too
+        g, ps_row_post, pe_row_post, rstart, remit, rtrunc = jax.lax.cond(
+            jnp.any(is_range),
+            _range_plumbing,
+            lambda: (
+                jnp.full((max_results,), -1, jnp.int32),
+                jnp.zeros((nb,), jnp.int32),
+                jnp.zeros((nb,), jnp.int32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.int32(0),
+            ),
+        )
 
     # --- pad buckets to a block multiple (EMPTY stripes merge to EMPTY) ---
-    nb_p = pl.cdiv(nb, block_b) * block_b
-    keys2d, vals2d, node_max, mkba = flat_k, flat_v, state.node_max, state.mkba
-    if nb_p != nb:
-        pad = nb_p - nb
-        keys2d = jnp.pad(keys2d, ((0, pad), (0, 0)), constant_values=EMPTY)
-        vals2d = jnp.pad(vals2d, ((0, pad), (0, 0)))
-        node_max = jnp.pad(node_max, ((0, pad), (0, 0)), constant_values=EMPTY)
-        mkba = jnp.pad(mkba, (0, pad), constant_values=EMPTY - 1)
-        ik = jnp.pad(ik, ((0, pad), (0, 0)), constant_values=EMPTY)
-        iv = jnp.pad(iv, ((0, pad), (0, 0)))
-        dk_tile = jnp.pad(dk_tile, ((0, pad), (0, 0)), constant_values=EMPTY)
-        next_key = jnp.pad(next_key, (0, pad), constant_values=EMPTY)
-        next_val = jnp.pad(next_val, (0, pad))
-        # padded buckets own no ranks: empty [total, total) spans
-        total_post = pe_row_post[-1]
-        ps_row_post = jnp.concatenate(
-            [ps_row_post, jnp.full((pad,), total_post, jnp.int32)]
+    with jax.named_scope("flix.fused.layout"):
+        nb_p = pl.cdiv(nb, block_b) * block_b
+        keys2d, vals2d, node_max, mkba = flat_k, flat_v, state.node_max, state.mkba
+        if nb_p != nb:
+            pad = nb_p - nb
+            keys2d = jnp.pad(keys2d, ((0, pad), (0, 0)), constant_values=EMPTY)
+            vals2d = jnp.pad(vals2d, ((0, pad), (0, 0)))
+            node_max = jnp.pad(node_max, ((0, pad), (0, 0)), constant_values=EMPTY)
+            mkba = jnp.pad(mkba, (0, pad), constant_values=EMPTY - 1)
+            ik = jnp.pad(ik, ((0, pad), (0, 0)), constant_values=EMPTY)
+            iv = jnp.pad(iv, ((0, pad), (0, 0)))
+            dk_tile = jnp.pad(dk_tile, ((0, pad), (0, 0)), constant_values=EMPTY)
+            next_key = jnp.pad(next_key, (0, pad), constant_values=EMPTY)
+            next_val = jnp.pad(next_val, (0, pad))
+            # padded buckets own no ranks: empty [total, total) spans
+            total_post = pe_row_post[-1]
+            ps_row_post = jnp.concatenate(
+                [ps_row_post, jnp.full((pad,), total_post, jnp.int32)]
+            )
+            pe_row_post = jnp.concatenate(
+                [pe_row_post, jnp.full((pad,), total_post, jnp.int32)]
+            )
+        lfence = jnp.concatenate(
+            [jnp.array([jnp.iinfo(jnp.int32).min], KEY_DTYPE), mkba[:-1]]
         )
-        pe_row_post = jnp.concatenate(
-            [pe_row_post, jnp.full((pad,), total_post, jnp.int32)]
+        mrp = pl.cdiv(max_results, 128) * 128
+        g_row = jnp.pad(g, (0, mrp - max_results), constant_values=-1).reshape(1, mrp)
+
+        # --- pad ops to a window multiple (NOP pads never match) --------------
+        qp = pl.cdiv(max(n, 1), block_q) * block_q
+        from repro.core.ops import OP_NOP
+
+        tpad = jnp.pad(tag, (0, qp - n), constant_values=OP_NOP)
+        qpad = jnp.pad(key.astype(KEY_DTYPE), (0, qp - n), constant_values=EMPTY)
+        n_windows = qp // block_q
+
+        # per-window bucket-block bounds; window 0 widens to the full sweep —
+        # that is where every stripe's update pass is guaranteed to happen.
+        q2 = qpad.reshape(n_windows, block_q)
+        first_b = jnp.searchsorted(mkba, q2[:, 0], side="left")
+        last_b = jnp.searchsorted(mkba, q2[:, -1], side="left")
+        nb_blocks = nb_p // block_b
+        lo = jnp.minimum(first_b, nb_p - 1).astype(jnp.int32) // block_b
+        hi = jnp.minimum(last_b, nb_p - 1).astype(jnp.int32) // block_b
+        lo = lo.at[0].set(0)
+        hi = hi.at[0].set(nb_blocks - 1)
+
+        # Mosaic takes a block whose last two dims are (8, 128)-aligned or span
+        # the whole array, so every blocked array gets a leading grid axis and
+        # full trailing dims: per-bucket planes [nb_blocks, BB, W] (fences and
+        # the node-count/overflow/deleted triple included), op windows
+        # [n_windows, 1, QB].
+        def by_block(x):
+            return x.reshape(nb_blocks, block_b, x.shape[1])
+
+        fences = jnp.stack(
+            [mkba, lfence, next_key, next_val, ps_row_post, pe_row_post], axis=1
+        ).astype(jnp.int32)                        # [nb_p, N_FENCE_ROWS]
+        operands = (
+            lo,
+            hi,
+            tpad.reshape(n_windows, 1, block_q),
+            qpad.reshape(n_windows, 1, block_q),
+            by_block(keys2d),
+            by_block(vals2d),
+            by_block(node_max),
+            by_block(ik),
+            by_block(iv),
+            by_block(dk_tile),
+            by_block(fences),
+            g_row,
         )
-    lfence = jnp.concatenate(
-        [jnp.array([jnp.iinfo(jnp.int32).min], KEY_DTYPE), mkba[:-1]]
-    )
-    mrp = pl.cdiv(max_results, 128) * 128
-    g_row = jnp.pad(g, (0, mrp - max_results), constant_values=-1).reshape(1, mrp)
-
-    # --- pad ops to a window multiple (NOP pads never match) --------------
-    qp = pl.cdiv(max(n, 1), block_q) * block_q
-    from repro.core.ops import OP_NOP
-
-    tpad = jnp.pad(tag, (0, qp - n), constant_values=OP_NOP)
-    qpad = jnp.pad(key.astype(KEY_DTYPE), (0, qp - n), constant_values=EMPTY)
-    n_windows = qp // block_q
-
-    # per-window bucket-block bounds; window 0 widens to the full sweep —
-    # that is where every stripe's update pass is guaranteed to happen.
-    q2 = qpad.reshape(n_windows, block_q)
-    first_b = jnp.searchsorted(mkba, q2[:, 0], side="left")
-    last_b = jnp.searchsorted(mkba, q2[:, -1], side="left")
-    nb_blocks = nb_p // block_b
-    lo = jnp.minimum(first_b, nb_p - 1).astype(jnp.int32) // block_b
-    hi = jnp.minimum(last_b, nb_p - 1).astype(jnp.int32) // block_b
-    lo = lo.at[0].set(0)
-    hi = hi.at[0].set(nb_blocks - 1)
-
-    # Mosaic takes a block whose last two dims are (8, 128)-aligned or span
-    # the whole array, so every blocked array gets a leading grid axis and
-    # full trailing dims: per-bucket planes [nb_blocks, BB, W] (fences and
-    # the node-count/overflow/deleted triple included), op windows
-    # [n_windows, 1, QB].
-    def by_block(x):
-        return x.reshape(nb_blocks, block_b, x.shape[1])
-
-    fences = jnp.stack(
-        [mkba, lfence, next_key, next_val, ps_row_post, pe_row_post], axis=1
-    ).astype(jnp.int32)                        # [nb_p, N_FENCE_ROWS]
 
     okeys, ovals, ocnt, omax, bmeta, resv, resk, rngk, rngv = apply_call(
-        lo,
-        hi,
-        tpad.reshape(n_windows, 1, block_q),
-        qpad.reshape(n_windows, 1, block_q),
-        by_block(keys2d),
-        by_block(vals2d),
-        by_block(node_max),
-        by_block(ik),
-        by_block(iv),
-        by_block(dk_tile),
-        by_block(fences),
-        g_row,
-        ns=ns,
-        interpret=interpret,
-        pipeline=pipeline,
+        *operands, ns=ns, interpret=interpret, pipeline=pipeline
     )
-    bmeta = bmeta.reshape(nb_p, 3)[:nb]
-    onn, oflow, odel = bmeta[:, 0], bmeta[:, 1], bmeta[:, 2]
+    with jax.named_scope("flix.fused.state_out"):
+        bmeta = bmeta.reshape(nb_p, 3)[:nb]
+        onn, oflow, odel = bmeta[:, 0], bmeta[:, 1], bmeta[:, 2]
 
-    slice_overflow = true_counts > cap
-    any_overflow = (jnp.sum(oflow) > 0) | jnp.any(slice_overflow)
-    new_state = FliXState(
-        keys=okeys.reshape(nb_p, npb, ns)[:nb],
-        vals=ovals.reshape(nb_p, npb, ns)[:nb],
-        node_count=ocnt.reshape(nb_p, npb)[:nb],
-        node_max=omax.reshape(nb_p, npb)[:nb],
-        num_nodes=onn,
-        mkba=state.mkba,
-        needs_restructure=state.needs_restructure | any_overflow,
-    )
-    results = {
-        "value": resv.reshape(qp)[:n],
-        "succ_key": resk.reshape(qp)[:n],
-        "range_key": rngk[0, :max_results],
-        "range_val": rngv[0, :max_results],
-        "range_start": jnp.where(is_range, rstart, 0),
-        "range_count": jnp.where(is_range, remit, 0),
-    }
-    stats = {
-        "inserted": jnp.sum(jnp.minimum(true_counts, cap)),
-        "deleted": jnp.sum(odel),
-        "overflowed_buckets": jnp.sum((oflow > 0) | slice_overflow),
-        "range_truncated": rtrunc,
-    }
+        slice_overflow = true_counts > cap
+        any_overflow = (jnp.sum(oflow) > 0) | jnp.any(slice_overflow)
+        new_state = FliXState(
+            keys=okeys.reshape(nb_p, npb, ns)[:nb],
+            vals=ovals.reshape(nb_p, npb, ns)[:nb],
+            node_count=ocnt.reshape(nb_p, npb)[:nb],
+            node_max=omax.reshape(nb_p, npb)[:nb],
+            num_nodes=onn,
+            mkba=state.mkba,
+            needs_restructure=state.needs_restructure | any_overflow,
+        )
+        results = {
+            "value": resv.reshape(qp)[:n],
+            "succ_key": resk.reshape(qp)[:n],
+            "range_key": rngk[0, :max_results],
+            "range_val": rngv[0, :max_results],
+            "range_start": jnp.where(is_range, rstart, 0),
+            "range_count": jnp.where(is_range, remit, 0),
+        }
+        stats = {
+            "inserted": jnp.sum(jnp.minimum(true_counts, cap)),
+            "deleted": jnp.sum(odel),
+            "overflowed_buckets": jnp.sum((oflow > 0) | slice_overflow),
+            "range_truncated": rtrunc,
+        }
     return new_state, results, stats
 
 

@@ -1,4 +1,4 @@
-"""Shared cell-building logic for the dry-run and roofline tools.
+"""Shared cell-building logic for the dry-run tool (``launch/dryrun.py``).
 
 ``build_cell(arch, shape, mesh)`` returns the jitted step function plus the
 abstract inputs and shardings for one (architecture × input-shape × mesh)
