@@ -550,7 +550,9 @@ def _build_replicated(
                 max_results,
             )
 
-        # ONE fused combine psum over the whole contribution pytree
+        # ONE fused combine psum over the whole contribution pytree (the
+        # fused executor's ``updated_buckets`` is left out: the engine's
+        # stats are the same whichever executor the shards run)
         contrib = {
             "pv": jnp.where(hit, value, 0),
             "n_hit": hit.astype(jnp.int32),
@@ -789,7 +791,9 @@ def _build_a2a(
                 )
             )
 
-        # ONE fused combine psum over the whole contribution pytree
+        # ONE fused combine psum over the whole contribution pytree (the
+        # fused executor's ``updated_buckets`` is left out: the engine's
+        # stats are the same whichever executor the shards run)
         contrib = {
             "inserted": st["inserted"],
             "deleted": st["deleted"],

@@ -32,7 +32,11 @@ gathered from the whole batch, not on the window — so revisited stripe
 blocks are rewritten with byte-identical data and every flush of an output
 block happens after a full in-window rewrite.  Total state traffic is one
 full sweep plus boundary revisits, versus ≥ 4 full sweeps for the reference
-engine.
+engine.  Window 0 still visits every block, but only a bucket whose INSERT
+or DELETE tile holds a key pays the merge/delete compute; every other
+bucket writes its stripe through (on a state that keeps I1–I5 the merge and
+delete of empty tiles are the identity), so a batch's update compute scales
+with the buckets it updates, not with the table.
 
 The successor out-of-bucket fallback cannot be resolved block-locally, so
 the wrapper feeds the same fence-row trick as ``flix_successor``: it derives
@@ -159,6 +163,8 @@ def _bucket_body(
     the column scratch, so no [S, S] temporary exists and no reshape splits
     the lane axis; a lane's node is ``lane // ns``.  The formulas are those
     of ``core.insert`` / ``core.delete`` / ``core.query``, term for term.
+    A bucket with no INSERT and no DELETE keys writes its stripe through
+    instead of running phases 1-2, which would give it back unchanged.
     """
     S = npb * ns
     C = _chunk_rows(S)
@@ -179,161 +185,182 @@ def _bucket_body(
     A = A_ref[row, :]                          # [1, S]
     Av = Av_ref[row, :]
     B = ik_ref[row, :]                         # [1, cap] incoming
-    Bv = iv_ref[row, :]
     D = dk_ref[row, :]
     nmax = nmax_ref[row, :]                    # [1, npb]
-    for slot, vec in ((_C_A, A), (_C_AV, Av), (_C_B, B), (_C_BV, Bv), (_C_D, D)):
-        cols[slot] = _col(vec)
+    meta_lane = jax.lax.broadcasted_iota(jnp.int32, (1, 3), 1)
 
-    # ---- phase 1: upsert merge of the INSERT slice --------------------
-    validA = A != _EMPTY
-    validB_i = (B != _EMPTY).astype(jnp.int32)
+    def merge_delete():
+        Bv = iv_ref[row, :]
+        for slot, vec in (
+            (_C_A, A), (_C_AV, Av), (_C_B, B), (_C_BV, Bv), (_C_D, D)
+        ):
+            cols[slot] = _col(vec)
+        zero_row = jnp.zeros((1, S), jnp.int32)
+        # ---- phase 1: upsert merge of the INSERT slice ----------------
+        validA = A != _EMPTY
+        validB_i = (B != _EMPTY).astype(jnp.int32)
 
-    def dup_tile(c, acc):                      # is A[l] re-inserted by B?
-        return acc + _sum_rows(jnp.where(cols[_C_B, chunk(c), :] == A, 1, 0))
+        def dup_tile(c, acc):                      # is A[l] re-inserted by B?
+            return acc + _sum_rows(jnp.where(cols[_C_B, chunk(c), :] == A, 1, 0))
 
-    dupA = loop(dup_tile, jnp.zeros((1, S), jnp.int32)) > 0
-    keepA_i = (validA & ~dupA).astype(jnp.int32)   # incoming value wins
-    cols[_C_KEEP_A] = _col(keepA_i)
+        dupA = loop(dup_tile, jnp.zeros((1, S), jnp.int32)) > 0
+        keepA_i = (validA & ~dupA).astype(jnp.int32)   # incoming value wins
+        cols[_C_KEEP_A] = _col(keepA_i)
 
-    # original node regions (fixed boundaries; last region open-ended)
-    onn0 = _sum_lanes((nmax != _EMPTY).astype(jnp.int32))     # [1, 1]
-    onn_c = jnp.maximum(onn0 - 1, 0)
+        # original node regions (fixed boundaries; last region open-ended)
+        onn0 = _sum_lanes((nmax != _EMPTY).astype(jnp.int32))     # [1, 1]
+        onn_c = jnp.maximum(onn0 - 1, 0)
 
-    def rank_pass(z_slot, keep_slot, rank_slot, reg_slot):
-        # merged ranks by compare-count (both sides sorted & unique), the
-        # region of each key, and the live-key count of every region
-        def tile(c, m):
-            z = cols[z_slot, chunk(c), :]      # [C, 1]
-            keep = (
-                cols[keep_slot, chunk(c), :]
-                if keep_slot is not None
-                else (z != _EMPTY).astype(jnp.int32)
-            )
-            rank = _sum_lanes(jnp.where(A < z, keepA_i, 0)) + _sum_lanes(
-                jnp.where(B < z, validB_i, 0)
-            )
-            reg = jnp.minimum(
-                _sum_lanes((nmax < z).astype(jnp.int32)), onn_c
-            )
-            cols[rank_slot, chunk(c), :] = rank
-            cols[reg_slot, chunk(c), :] = reg
-            return m + _sum_rows(jnp.where(reg == node_row, keep, 0))
+        def rank_pass(z_slot, keep_slot, rank_slot, reg_slot):
+            # merged ranks by compare-count (both sides sorted & unique), the
+            # region of each key, and the live-key count of every region
+            def tile(c, m):
+                z = cols[z_slot, chunk(c), :]      # [C, 1]
+                keep = (
+                    cols[keep_slot, chunk(c), :]
+                    if keep_slot is not None
+                    else (z != _EMPTY).astype(jnp.int32)
+                )
+                rank = _sum_lanes(jnp.where(A < z, keepA_i, 0)) + _sum_lanes(
+                    jnp.where(B < z, validB_i, 0)
+                )
+                reg = jnp.minimum(
+                    _sum_lanes((nmax < z).astype(jnp.int32)), onn_c
+                )
+                cols[rank_slot, chunk(c), :] = rank
+                cols[reg_slot, chunk(c), :] = reg
+                return m + _sum_rows(jnp.where(reg == node_row, keep, 0))
 
-        return tile
+            return tile
 
-    m_j = loop(
-        rank_pass(_C_A, _C_KEEP_A, _C_RANK_A, _C_REG_A),
-        jnp.zeros((1, npb), jnp.int32),
-    )
-    m_j = loop(rank_pass(_C_B, None, _C_RANK_B, _C_REG_B), m_j)   # [1, npb]
+        m_j = loop(
+            rank_pass(_C_A, _C_KEEP_A, _C_RANK_A, _C_REG_A),
+            jnp.zeros((1, npb), jnp.int32),
+        )
+        m_j = loop(rank_pass(_C_B, None, _C_RANK_B, _C_REG_B), m_j)   # [1, npb]
 
-    def excl_prefix(x_row):
-        # exclusive prefix sum of a [1, npb] row (Mosaic has no cumsum)
-        return _sum_rows(jnp.where(node_col < node_row, _col(x_row), 0))
+        def excl_prefix(x_row):
+            # exclusive prefix sum of a [1, npb] row (Mosaic has no cumsum)
+            return _sum_rows(jnp.where(node_col < node_row, _col(x_row), 0))
 
-    s_j = (m_j + ns - 1) // ns                 # pieces per region
-    f_j = excl_prefix(m_j)                     # first rank of region
-    base_j = excl_prefix(s_j)                  # first output slot
-    total_new = _sum_lanes(s_j)                # [1, 1]
+        s_j = (m_j + ns - 1) // ns                 # pieces per region
+        f_j = excl_prefix(m_j)                     # first rank of region
+        base_j = excl_prefix(s_j)                  # first output slot
+        total_new = _sum_lanes(s_j)                # [1, 1]
 
-    def scatter_pass(z_slot, v_slot, keep_slot, rank_slot, reg_slot):
-        # balanced split within each region (same formulas as core/insert),
-        # then a one-hot scatter of each key to its merged lane
-        def tile(c, acc):
-            mk, mv, filled = acc
-            z = cols[z_slot, chunk(c), :]
-            keep = (
-                cols[keep_slot, chunk(c), :] > 0
-                if keep_slot is not None
-                else z != _EMPTY
-            )
-            oh = cols[reg_slot, chunk(c), :] == node_row      # [C, npb]
-            m_r = jnp.maximum(_sum_lanes(jnp.where(oh, m_j, 0)), 1)
-            s_r = jnp.maximum(_sum_lanes(jnp.where(oh, s_j, 0)), 1)
-            f_r = _sum_lanes(jnp.where(oh, f_j, 0))
-            b_r = _sum_lanes(jnp.where(oh, base_j, 0))
-            rr = cols[rank_slot, chunk(c), :] - f_r
-            piece = (rr * s_r) // m_r
-            start = (piece * m_r + s_r - 1) // s_r
-            slot = b_r + piece
-            dest = jnp.where(keep & (slot < npb), slot * ns + rr - start, S)
-            hit = dest == lane                                # [C, S]
+        def scatter_pass(z_slot, v_slot, keep_slot, rank_slot, reg_slot):
+            # balanced split within each region (same formulas as core/insert),
+            # then a one-hot scatter of each key to its merged lane
+            def tile(c, acc):
+                mk, mv, filled = acc
+                z = cols[z_slot, chunk(c), :]
+                keep = (
+                    cols[keep_slot, chunk(c), :] > 0
+                    if keep_slot is not None
+                    else z != _EMPTY
+                )
+                oh = cols[reg_slot, chunk(c), :] == node_row      # [C, npb]
+                m_r = jnp.maximum(_sum_lanes(jnp.where(oh, m_j, 0)), 1)
+                s_r = jnp.maximum(_sum_lanes(jnp.where(oh, s_j, 0)), 1)
+                f_r = _sum_lanes(jnp.where(oh, f_j, 0))
+                b_r = _sum_lanes(jnp.where(oh, base_j, 0))
+                rr = cols[rank_slot, chunk(c), :] - f_r
+                piece = (rr * s_r) // m_r
+                start = (piece * m_r + s_r - 1) // s_r
+                slot = b_r + piece
+                dest = jnp.where(keep & (slot < npb), slot * ns + rr - start, S)
+                hit = dest == lane                                # [C, S]
+                return (
+                    mk + _sum_rows(jnp.where(hit, z, 0)),
+                    mv + _sum_rows(jnp.where(hit, cols[v_slot, chunk(c), :], 0)),
+                    filled + _sum_rows(hit.astype(jnp.int32)),
+                )
+
+            return tile
+
+        acc = loop(
+            scatter_pass(_C_A, _C_AV, _C_KEEP_A, _C_RANK_A, _C_REG_A),
+            (zero_row, zero_row, zero_row),
+        )
+        mk, mv, filled = loop(
+            scatter_pass(_C_B, _C_BV, None, _C_RANK_B, _C_REG_B), acc
+        )
+        mk = jnp.where(filled > 0, mk, _EMPTY)     # [1, S] merged stripe
+        mv = jnp.where(filled > 0, mv, 0)
+
+        # ---- phase 2: physical delete on the merged stripe -----------
+        def hit_tile(c, acc):
+            return acc + _sum_rows(jnp.where(cols[_C_D, chunk(c), :] == mk, 1, 0))
+
+        hit = (loop(hit_tile, zero_row) > 0) & (mk != _EMPTY)
+        del_cnt = _sum_lanes(hit.astype(jnp.int32))               # [1, 1]
+        keep_i = ((~hit) & (mk != _EMPTY)).astype(jnp.int32)      # [1, S]
+        cols[_C_MK] = _col(mk)
+        cols[_C_MV] = _col(mv)
+
+        # chain compaction: surviving nodes shift into the lowest slots
+        cnt = _sum_lanes(jnp.where(node_of_lane == node_col, keep_i, 0))  # [npb, 1]
+        nonempty = (cnt > 0).astype(jnp.int32)
+        slot_dest = _sum_rows(jnp.where(node_col < node_row, nonempty, 0))  # [1, npb]
+
+        def compact_tile(c, acc):
+            # a kept lane moves to (its node's new slot, the count of kept
+            # lanes before it in its own node)
+            fk, fv, filled = acc
+            z = cols[_C_MK, chunk(c), :]
+            l = c * C + sub                                       # [C, 1]
+            kept = (z != _EMPTY) & (_sum_lanes(jnp.where(z == D, 1, 0)) == 0)
+            before = (lane < l) & (node_of_lane == l // ns)
+            dest = _sum_lanes(jnp.where(before, keep_i, 0))
+            slot = _sum_lanes(jnp.where(node_row == l // ns, slot_dest, 0))
+            final = jnp.where(kept, slot * ns + dest, S)
+            hit = final == lane
             return (
-                mk + _sum_rows(jnp.where(hit, z, 0)),
-                mv + _sum_rows(jnp.where(hit, cols[v_slot, chunk(c), :], 0)),
+                fk + _sum_rows(jnp.where(hit, z, 0)),
+                fv + _sum_rows(jnp.where(hit, cols[_C_MV, chunk(c), :], 0)),
                 filled + _sum_rows(hit.astype(jnp.int32)),
             )
 
-        return tile
+        fk, fv, filled = loop(compact_tile, (zero_row, zero_row, zero_row))
+        fk = jnp.where(filled > 0, fk, _EMPTY)                    # [1, S]
+        fv = jnp.where(filled > 0, fv, 0)
 
-    zero_row = jnp.zeros((1, S), jnp.int32)
-    acc = loop(
-        scatter_pass(_C_A, _C_AV, _C_KEEP_A, _C_RANK_A, _C_REG_A),
-        (zero_row, zero_row, zero_row),
-    )
-    mk, mv, filled = loop(
-        scatter_pass(_C_B, _C_BV, None, _C_RANK_B, _C_REG_B), acc
-    )
-    mk = jnp.where(filled > 0, mk, _EMPTY)     # [1, S] merged stripe
-    mv = jnp.where(filled > 0, mv, 0)
+        # metadata (keys ascend within a node, so its max is its last key)
+        live = fk != _EMPTY
+        mine_lane = (node_of_lane == node_col) & live            # [npb, S]
+        ocnt = _sum_lanes(mine_lane.astype(jnp.int32))            # [npb, 1]
+        omax = jnp.max(jnp.where(mine_lane, fk, _MIN), axis=1, keepdims=True)
+        omax = jnp.where(ocnt > 0, omax, _EMPTY)
+        onn = _sum_rows((ocnt > 0).astype(jnp.int32))             # [1, 1]
 
-    # ---- phase 2: physical delete on the merged stripe ---------------
-    def hit_tile(c, acc):
-        return acc + _sum_rows(jnp.where(cols[_C_D, chunk(c), :] == mk, 1, 0))
-
-    hit = (loop(hit_tile, zero_row) > 0) & (mk != _EMPTY)
-    del_cnt = _sum_lanes(hit.astype(jnp.int32))               # [1, 1]
-    keep_i = ((~hit) & (mk != _EMPTY)).astype(jnp.int32)      # [1, S]
-    cols[_C_MK] = _col(mk)
-    cols[_C_MV] = _col(mv)
-
-    # chain compaction: surviving nodes shift into the lowest slots
-    cnt = _sum_lanes(jnp.where(node_of_lane == node_col, keep_i, 0))  # [npb, 1]
-    nonempty = (cnt > 0).astype(jnp.int32)
-    slot_dest = _sum_rows(jnp.where(node_col < node_row, nonempty, 0))  # [1, npb]
-
-    def compact_tile(c, acc):
-        # a kept lane moves to (its node's new slot, the count of kept
-        # lanes before it in its own node)
-        fk, fv, filled = acc
-        z = cols[_C_MK, chunk(c), :]
-        l = c * C + sub                                       # [C, 1]
-        kept = (z != _EMPTY) & (_sum_lanes(jnp.where(z == D, 1, 0)) == 0)
-        before = (lane < l) & (node_of_lane == l // ns)
-        dest = _sum_lanes(jnp.where(before, keep_i, 0))
-        slot = _sum_lanes(jnp.where(node_row == l // ns, slot_dest, 0))
-        final = jnp.where(kept, slot * ns + dest, S)
-        hit = final == lane
-        return (
-            fk + _sum_rows(jnp.where(hit, z, 0)),
-            fv + _sum_rows(jnp.where(hit, cols[_C_MV, chunk(c), :], 0)),
-            filled + _sum_rows(hit.astype(jnp.int32)),
+        okeys_ref[row, :] = fk
+        ovals_ref[row, :] = fv
+        ocnt_ref[row, :] = _row(ocnt)
+        omax_ref[row, :] = _row(omax)
+        oflow = (total_new > npb).astype(jnp.int32)
+        bmeta_ref[row, :] = jnp.where(
+            meta_lane == 0, onn, jnp.where(meta_lane == 1, oflow, del_cnt)
         )
 
-    fk, fv, filled = loop(compact_tile, (zero_row, zero_row, zero_row))
-    fk = jnp.where(filled > 0, fk, _EMPTY)                    # [1, S]
-    fv = jnp.where(filled > 0, fv, 0)
+    def write_through():
+        # no INSERT: region j is node j and holds c_j <= ns keys, so the
+        # merge puts every key back in its own lane; no DELETE: compaction
+        # moves nothing.  On a stripe that keeps I1-I5 phases 1-2 are the
+        # identity, but for the values of EMPTY lanes, which they zero.
+        live_i = (A != _EMPTY).astype(jnp.int32)
+        okeys_ref[row, :] = A
+        ovals_ref[row, :] = jnp.where(live_i > 0, Av, 0)
+        ocnt_ref[row, :] = _row(
+            _sum_lanes(jnp.where(node_of_lane == node_col, live_i, 0))
+        )
+        omax_ref[row, :] = nmax
+        onn = _sum_lanes((nmax != _EMPTY).astype(jnp.int32))  # [1, 1]
+        bmeta_ref[row, :] = jnp.where(meta_lane == 0, onn, 0)
 
-    # metadata (keys ascend within a node, so its max is its last key)
-    live = fk != _EMPTY
-    mine_lane = (node_of_lane == node_col) & live            # [npb, S]
-    ocnt = _sum_lanes(mine_lane.astype(jnp.int32))            # [npb, 1]
-    omax = jnp.max(jnp.where(mine_lane, fk, _MIN), axis=1, keepdims=True)
-    omax = jnp.where(ocnt > 0, omax, _EMPTY)
-    onn = _sum_rows((ocnt > 0).astype(jnp.int32))             # [1, 1]
-
-    okeys_ref[row, :] = fk
-    ovals_ref[row, :] = fv
-    ocnt_ref[row, :] = _row(ocnt)
-    omax_ref[row, :] = _row(omax)
-    meta_lane = jax.lax.broadcasted_iota(jnp.int32, (1, 3), 1)
-    oflow = (total_new > npb).astype(jnp.int32)
-    bmeta_ref[row, :] = jnp.where(
-        meta_lane == 0, onn, jnp.where(meta_lane == 1, oflow, del_cnt)
-    )
-    cols[_C_FK] = _col(fk)
-    cols[_C_FV] = _col(fv)
+    # only a bucket whose INSERT or DELETE tile holds a key pays phases
+    # 1-2; a row reduction, so the tiles' order does not matter
+    has_update = jnp.sum(jnp.where((B != _EMPTY) | (D != _EMPTY), 1, 0)) > 0
+    jax.lax.cond(has_update, merge_delete, write_through)
 
     fences = fence_ref[row, :]                 # [1, N_FENCE_ROWS]
 
@@ -347,10 +374,21 @@ def _bucket_body(
     is_s = t == _OP_SUCCESSOR
     # the bucket owns the ops in (lower fence, mkba]
     mine = (is_p | is_s) & (q > fence(_FENCE_LF)) & (q <= fence(_FENCE_MKBA))
+    has_reads = jnp.sum(mine.astype(jnp.int32)) > 0
+    # valid RANGE slots are a prefix, so g[0] < 0 <=> nothing to emit
+    has_ranges = g_ref[0, 0] >= 0
 
-    @pl.when(jnp.sum(mine.astype(jnp.int32)) > 0)
+    # phases 3-4 read the post-update stripe from whichever branch wrote it
+    @pl.when(has_reads | has_ranges)
+    def _stage_stripe():
+        cols[_C_FK] = _col(okeys_ref[row, :])
+        cols[_C_FV] = _col(ovals_ref[row, :])
+
+    @pl.when(has_reads)
     def _reads():
         # node by post-update node-max votes, position by key votes
+        omax = _col(omax_ref[row, :])                         # [npb, 1]
+        onn = bmeta_ref[row, :][:, 0:1]                       # [1, 1]
         nidx = _sum_rows((omax < q).astype(jnp.int32))        # [1, QB]
         in_bucket = nidx < onn
         nidx_c = jnp.minimum(nidx, npb - 1)
@@ -393,13 +431,13 @@ def _bucket_body(
     # ---- phase 4: dense RANGE slots owned by this bucket --------------
     # slot p carries the post-update global rank of its key; the bucket
     # claims p iff the rank falls in its [pref[b], pref[b+1]) span, then
-    # maps the in-bucket rank to a (node, pos) of the stripe just rebuilt
-    # above (ocnt prefix sums = node boundaries).  Valid slots are a
-    # prefix, so g[0] < 0 ⇔ nothing to emit — batches with no RANGE
-    # output skip the gather compute entirely.
-    @pl.when(g_ref[0, 0] >= 0)
+    # maps the in-bucket rank to a (node, pos) of the post-update stripe
+    # (ocnt prefix sums = node boundaries).  Batches with no RANGE output
+    # skip the gather compute entirely.
+    @pl.when(has_ranges)
     def _range_gather():
         g = g_ref[...]                         # [1, MR]
+        ocnt = _col(ocnt_ref[row, :])          # [npb, 1]
         ps = fence(_FENCE_PS)
         mine_r = (g >= ps) & (g < fence(_FENCE_PE))
         r = g - ps                             # rank within the bucket
@@ -897,6 +935,12 @@ def _fused_apply(
             "deleted": jnp.sum(odel),
             "overflowed_buckets": jnp.sum((oflow > 0) | slice_overflow),
             "range_truncated": rtrunc,
+            # buckets that ran the kernel's merge/delete phases: those whose
+            # INSERT or DELETE tile holds a key, i.e. whose slice is not
+            # empty.  Counted from the slice bounds, not from the tiles: a
+            # read of a tile here would keep that 1 GiB plane (at 2^23 keys)
+            # alive past the kernel, beyond what a v5e can load
+            "updated_buckets": jnp.sum((true_counts > 0) | (dends > dstarts)),
         }
     return new_state, results, stats
 

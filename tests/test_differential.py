@@ -282,18 +282,21 @@ def test_apply_ops_partial_mixes(adversarial, rng, present):
 
 
 def _assert_fused_matches_reference(
-    st, tags, keys, vals, *, pad_to, max_results=128, pipeline="auto"
+    st, tags, keys, vals, *, pad_to, max_results=128, pipeline="auto", now=None
 ):
+    """Returns ``(ops, reference results, fused stats, fused state)``."""
     ops, _ = core.make_ops(tags, keys, vals, pad_to=pad_to)
     s_ref, r_ref, stats_ref = core.apply_ops(
-        st, ops, config=ExecConfig(impl="reference", max_results=max_results)
+        st, ops, now=now, config=ExecConfig(impl="reference", max_results=max_results)
     )
     s_f, r_f, stats_f = core.apply_ops(
         st,
         ops,
+        now=now,
         config=ExecConfig(impl="fused", max_results=max_results, pipeline=pipeline),
     )
-    for f in ("keys", "node_count", "node_max", "num_nodes", "mkba"):
+    fields = ("keys", "node_count", "node_max", "num_nodes", "mkba")
+    for f in fields + (("exps",) if s_ref.exps is not None else ()):
         np.testing.assert_array_equal(
             np.asarray(getattr(s_ref, f)), np.asarray(getattr(s_f, f)), err_msg=f
         )
@@ -310,9 +313,9 @@ def _assert_fused_matches_reference(
     for k in stats_ref:
         assert int(stats_ref[k]) == int(stats_f[k]), k
     if not bool(s_f.needs_restructure):
-        check_invariants(s_f)
+        check_invariants(s_f, now=now)
         core.check_range_results(ops, r_f, max_results=max_results)
-    return ops, r_ref, stats_ref
+    return ops, r_ref, stats_f, s_f
 
 
 @pytest.mark.parametrize(
@@ -479,7 +482,7 @@ def test_range_observes_same_batch_updates(adversarial, rng):
     ]).astype(np.int32)
     keys = np.concatenate([ins, dels, rlo]).astype(np.int32)
     vals = np.concatenate([iv, np.zeros(len(dels), np.int32), rhi])
-    ops, r_ref, _ = _assert_fused_matches_reference(
+    ops, r_ref, _, _ = _assert_fused_matches_reference(
         st, tags, keys, vals, pad_to=512, max_results=2048
     )
     # model the post-update region contents
@@ -527,6 +530,128 @@ def test_apply_ops_safe_overflow_recovery(rng):
     np.testing.assert_array_equal(res_in[len(flood):], points)
     got = np.asarray(core.point_query(st2, jnp.asarray(np.sort(flood))))
     np.testing.assert_array_equal(got, np.sort(flood))
+
+# ---------------------------------------------------------------------------
+# fused kernel write-through: a bucket with no INSERT and no present DELETE
+# skips the merge/delete phases and keeps its stripe
+# ---------------------------------------------------------------------------
+
+
+def _updated_buckets(st, tags, keys):
+    """Buckets holding an INSERT, or a DELETE of a stored key, under the
+    pre-batch fences — in numpy, apart from the executors."""
+    mkba = np.asarray(st.mkba)
+    stored = np.asarray(st.keys)
+    stored = stored[stored != int(EMPTY)]
+    upd = (tags == core.OP_INSERT) | ((tags == core.OP_DELETE) & np.isin(keys, stored))
+    b = np.minimum(np.searchsorted(mkba, keys[upd], side="left"), mkba.size - 1)
+    return np.unique(b)
+
+
+def _state_of_kind(kind, rng):
+    """``(state, live keys, now)``: a fresh build, or one after deletes,
+    after a restructure, or after an expiry pass — each satisfying I1-I6."""
+    keys = np.unique(rng.choice(120000, 3000, replace=False)).astype(np.int32)
+    st = core.build(keys, keys + 11, node_size=8, nodes_per_bucket=8)
+    now = None
+    if kind in ("deleted", "restructured"):
+        gone = np.concatenate([
+            keys[(keys >= 40000) & (keys < 52000)],   # whole buckets emptied
+            rng.choice(keys, 300, replace=False),
+        ])
+        st, _ = core.delete(st, jnp.asarray(np.unique(gone).astype(np.int32)))
+        keys = np.setdiff1d(keys, gone).astype(np.int32)
+    if kind == "restructured":
+        st = core.restructure(st, num_buckets=st.num_buckets // 3)
+    if kind == "expired":
+        now = 100
+        live = np.asarray(st.keys) != int(EMPTY)
+        exps = np.where(live, rng.integers(0, 400, st.keys.shape), int(core.NO_EXPIRY))
+        st, _ = core.expire_state(
+            core.attach_expiry(st, jnp.asarray(exps.astype(np.int32))), now
+        )
+        keys = np.sort(np.asarray(st.keys)[np.asarray(st.keys) != int(EMPTY)])
+    check_invariants(st, now=now)
+    return st, keys, now
+
+
+def _minority_batch(rng, live):
+    """Updates in a few buckets (a present and an absent DELETE, fresh
+    INSERTs, an upsert), reads and ranges spread over the whole table."""
+    absent = np.setdiff1d(np.arange(0, 125000, 7, dtype=np.int32), live)
+    ins = rng.choice(absent, 10, replace=False)
+    ups = rng.choice(live, 3, replace=False)
+    dels = rng.choice(np.setdiff1d(live, ups), 10, replace=False)
+    gone = rng.choice(np.setdiff1d(absent, ins), 6, replace=False)
+    reads = np.concatenate([rng.choice(live, 60), rng.integers(0, 125000, 40)])
+    rlo = np.sort(rng.integers(0, 120000, 6))
+    tags = np.concatenate([
+        np.full(13, core.OP_INSERT), np.full(16, core.OP_DELETE),
+        np.where(np.arange(100) % 3 == 0, core.OP_SUCCESSOR, core.OP_POINT),
+        np.full(6, core.OP_RANGE),
+    ]).astype(np.int32)
+    keys = np.concatenate([ins, ups, dels, gone, reads, rlo]).astype(np.int32)
+    vals = np.concatenate([
+        np.arange(13) + 7_000_000, np.zeros(116), rlo + rng.integers(0, 900, 6)
+    ]).astype(np.int32)
+    return tags, keys, vals
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+@pytest.mark.parametrize("kind", ["fresh", "deleted", "restructured", "expired"])
+def test_fused_write_through_keeps_untouched_buckets(rng, kind, pipeline):
+    """A batch that updates a minority of buckets: the fused executor equals
+    the reference, and every bucket it does not update comes out with its
+    keys, node counts, node maxima and node count byte-equal to the input
+    and its values equal at every stored key (0 at EMPTY slots, the merge
+    phase's canonical output, which deletes and expiry passes do not
+    write)."""
+    st, live, now = _state_of_kind(kind, rng)
+    tags, keys, vals = _minority_batch(rng, live)
+    updated = _updated_buckets(st, tags, keys)
+    assert 0 < updated.size < st.num_buckets // 10
+    _, _, stats, s_f = _assert_fused_matches_reference(
+        st, tags, keys, vals, pad_to=256, max_results=512, pipeline=pipeline, now=now
+    )
+    assert int(stats["updated_buckets"]) == updated.size
+    same = np.setdiff1d(np.arange(st.num_buckets), updated)
+    for f in ("keys", "node_count", "node_max", "num_nodes"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(s_f, f))[same], np.asarray(getattr(st, f))[same],
+            err_msg=f,
+        )
+    stored = np.asarray(st.keys)[same] != int(EMPTY)
+    np.testing.assert_array_equal(
+        np.asarray(s_f.vals)[same], np.where(stored, np.asarray(st.vals)[same], 0)
+    )
+    if st.exps is not None:
+        np.testing.assert_array_equal(np.asarray(s_f.exps)[same], np.asarray(st.exps)[same])
+
+
+@pytest.mark.parametrize("batch", ["minority", "read_only", "every_bucket"])
+def test_fused_updated_buckets_counter(rng, batch):
+    """``stats["updated_buckets"]`` counts the buckets that ran the merge
+    and delete phases: those holding an INSERT or a present DELETE under
+    the pre-batch fences — none for a read-only batch forced onto the
+    fused executor, all of them when every bucket takes an INSERT."""
+    st, live, _ = _state_of_kind("fresh", rng)
+    if batch == "minority":
+        tags, keys, vals = _minority_batch(rng, live)
+    elif batch == "read_only":
+        keys = np.sort(rng.choice(live, 300, replace=False)).astype(np.int32)
+        tags = np.full(keys.size, core.OP_POINT, np.int32)
+        vals = np.zeros(keys.size, np.int32)
+    else:  # an upsert of its smallest key in every bucket
+        keys = np.asarray(st.keys)[:, 0, 0].astype(np.int32)
+        tags = np.full(keys.size, core.OP_INSERT, np.int32)
+        vals = (keys + 5).astype(np.int32)
+    want = _updated_buckets(st, tags, keys).size
+    if batch != "minority":
+        assert want == (0 if batch == "read_only" else st.num_buckets)
+    ops, _ = core.make_ops(tags, keys, vals)
+    _, _, stats = core.apply_ops(st, ops, config=ExecConfig(impl="fused"))
+    assert int(stats["updated_buckets"]) == want
+
 
 # ---------------------------------------------------------------------------
 # pipelined fused kernel: double-buffered staging == single-buffer, byte-exact
