@@ -72,12 +72,14 @@ from repro.core.query import _suffix_min_with_index, flat_rank, range_offsets
 from repro.core.state import (
     EMPTY,
     KEY_DTYPE,
+    MAX_VALID,
     MIN_KEY,
     NOT_FOUND,
     VAL_DTYPE,
     FliXState,
     flatten_bucket_sorted,
 )
+from repro.core.trace import span
 
 # max_results handed to the *inner* apply_ops when the cross-shard range
 # phase answers the batch's RANGE ops (the inner dense arrays are ignored)
@@ -122,6 +124,36 @@ def make_shard_mesh(n_shards: int, *, axis: str = "shards") -> jax.sharding.Mesh
     return jax.sharding.Mesh(np.array(devs[:n_shards]), (axis,))
 
 
+@functools.partial(
+    jax.jit, static_argnames=("num_buckets", "nodes_per_bucket", "node_size", "fill")
+)
+def _build_shard(
+    keys, vals, exps, last, *, num_buckets, nodes_per_bucket, node_size, fill
+):
+    """One shard's slice of the table, built on the device its inputs are on.
+
+    The slice build closes the key space at its own last bucket
+    (``MAX_VALID``); unless ``last``, that fence is set back to the bucket's
+    largest key, as the whole-table build has it."""
+    kw = dict(
+        num_buckets=num_buckets,
+        nodes_per_bucket=nodes_per_bucket,
+        node_size=node_size,
+        fill=fill,
+    )
+    state = build_from_sorted(keys, vals, **kw)
+    if exps is not None:
+        # expiry plane of the same build: identical layout, exps in vals
+        built_e = build_from_sorted(keys, exps, **kw)
+        state = dataclasses.replace(
+            state, exps=jnp.where(state.keys == EMPTY, NO_EXPIRY, built_e.vals)
+        )
+    top = jnp.where(state.num_nodes[-1] > 0, state.node_max[-1, 0], MAX_VALID)
+    return dataclasses.replace(
+        state, mkba=state.mkba.at[-1].set(jnp.where(last, MAX_VALID, top))
+    )
+
+
 def shard_build(
     sorted_keys,
     sorted_vals,
@@ -134,7 +166,14 @@ def shard_build(
     extra_keys: int = 0,
     sorted_exps=None,
 ) -> ShardedFliX:
-    """Build then range-partition across ``mesh``'s ``axis``.
+    """Build a range-partitioned table across ``mesh``'s ``axis``, each
+    shard on its own device.
+
+    Bucket ``i`` takes sorted keys ``[i * p, (i + 1) * p)``, so shard ``s``
+    builds its buckets from one contiguous slice of the sorted keys; no
+    device holds more than its own shard and that shard's build
+    temporaries.  The result equals the whole-table ``build_from_sorted``
+    placed over the mesh, array by array.
 
     ``extra_keys`` over-provisions the bucket count (the distributed
     analogue of ``restructure_grow``'s headroom argument) so a subsequent
@@ -144,45 +183,57 @@ def shard_build(
     """
     n_shards = int(mesh.shape[axis])
     p = max(1, int(node_size * fill))
-    n = int(jnp.sum(sorted_keys != EMPTY)) + extra_keys
+    keys = np.asarray(sorted_keys).astype(np.int32)
+    vals = np.asarray(sorted_vals).astype(np.int32)
+    exps = None if sorted_exps is None else np.asarray(sorted_exps).astype(np.int32)
+    n = int(np.sum(keys != EMPTY)) + extra_keys
     per_shard_buckets = max(1, math.ceil(math.ceil(n / p) / n_shards))
-    nb = per_shard_buckets * n_shards
-    state = build_from_sorted(
-        sorted_keys,
-        sorted_vals,
-        num_buckets=nb,
-        nodes_per_bucket=nodes_per_bucket,
-        node_size=node_size,
-        fill=fill,
-    )
-    exps = None
-    if sorted_exps is not None:
-        # expiry plane of the same build: identical layout, exps in vals
-        built_e = build_from_sorted(
-            sorted_keys,
-            jnp.asarray(sorted_exps, KEY_DTYPE),
-            num_buckets=nb,
-            nodes_per_bucket=nodes_per_bucket,
-            node_size=node_size,
-            fill=fill,
-        )
-        exps = jnp.where(state.keys == EMPTY, NO_EXPIRY, built_e.vals)
-    part_fences = state.mkba.reshape(n_shards, -1)[:, -1]
-    lower_fence = jnp.concatenate([jnp.array([MIN_KEY], KEY_DTYPE), part_fences[:-1]])
+    width = per_shard_buckets * p
+
+    def piece(a, s, pad):
+        out = np.full((width,), pad, np.int32)
+        part = a[s * width : (s + 1) * width]
+        out[: part.size] = part
+        return out
 
     shard3 = NamedSharding(mesh, P(axis, None, None))
     shard2 = NamedSharding(mesh, P(axis, None))
     shard1 = NamedSharding(mesh, P(axis))
     rep = NamedSharding(mesh, P())
+    shape = (per_shard_buckets * n_shards, nodes_per_bucket, node_size)
+    built, tops = [], [None] * n_shards
+    for dev, index in shard3.addressable_devices_indices_map(shape).items():
+        s = (index[0].start or 0) // per_shard_buckets
+        state = _build_shard(
+            jax.device_put(piece(keys, s, EMPTY), dev),
+            jax.device_put(piece(vals, s, 0), dev),
+            None if exps is None else jax.device_put(piece(exps, s, 0), dev),
+            jax.device_put(np.bool_(s == n_shards - 1), dev),
+            num_buckets=per_shard_buckets,
+            nodes_per_bucket=nodes_per_bucket,
+            node_size=node_size,
+            fill=fill,
+        )
+        built.append(state)
+        tops[s] = state.mkba[-1]
+    part_fences = np.asarray(jax.device_get(tops), np.int32)
+    lower_fence = np.concatenate([np.array([MIN_KEY], np.int32), part_fences[:-1]])
+
+    def assemble(field, sharding):
+        parts = [getattr(st, field) for st in built]
+        return jax.make_array_from_single_device_arrays(
+            (shape[0], *parts[0].shape[1:]), sharding, parts
+        )
+
     state = FliXState(
-        keys=jax.device_put(state.keys, shard3),
-        vals=jax.device_put(state.vals, shard3),
-        node_count=jax.device_put(state.node_count, shard2),
-        node_max=jax.device_put(state.node_max, shard2),
-        num_nodes=jax.device_put(state.num_nodes, shard1),
-        mkba=jax.device_put(state.mkba, shard1),
-        needs_restructure=jax.device_put(state.needs_restructure, rep),
-        exps=None if exps is None else jax.device_put(exps, shard3),
+        keys=assemble("keys", shard3),
+        vals=assemble("vals", shard3),
+        node_count=assemble("node_count", shard2),
+        node_max=assemble("node_max", shard2),
+        num_nodes=assemble("num_nodes", shard1),
+        mkba=assemble("mkba", shard1),
+        needs_restructure=jax.device_put(np.bool_(False), rep),
+        exps=None if exps is None else assemble("exps", shard3),
     )
     return ShardedFliX(
         state=state,
@@ -230,15 +281,15 @@ def shard_restructure(
     else:
         npb = state.nodes_per_bucket
     return shard_build(
-        jnp.asarray(sorted_k),
-        jnp.asarray(sorted_v),
+        sorted_k,
+        sorted_v,
         mesh,
         axis=idx.axis,
         node_size=state.node_size,
         nodes_per_bucket=npb,
         fill=fill,
         extra_keys=extra_keys,
-        sorted_exps=None if sorted_e is None else jnp.asarray(sorted_e),
+        sorted_exps=sorted_e,
     )
 
 
@@ -462,148 +513,161 @@ def _build_replicated(
     (plus the one unavoidable ``pmin`` for the successor winner).
     """
 
+    n_shards = int(mesh.shape[axis])
+
     def body(state, lf, tag, key, val, *extra):
         # extra = (exp,) / (exp, now) when the TTL lanes are enabled
         exp = extra[0] if has_ttl else None
         now = extra[1] if has_now else None
-        lf = lf[0]
-        upper = state.mkba[-1]
-        is_upd = (tag == OP_INSERT) | (tag == OP_DELETE) | (tag == OP_EXPIRE)
-        is_rng = tag == OP_RANGE
-        # updates run on their owner shard only; POINT/SUCCESSOR run
-        # everywhere (a successor answer may live past the owner's fence);
-        # RANGE is lifted out entirely for the cross-shard phase
-        keep = (~is_upd | ((key > lf) & (key <= upper))) & ~is_rng
-        mtag = jnp.where(keep, tag, OP_NOP)
-        mkey = jnp.where(keep, key, EMPTY)
-        mval = jnp.where(keep, val, 0)
-        order = jnp.argsort(mkey, stable=True)
-        inv = _inverse_permutation(order)
-        stag, skey = mtag[order], mkey[order]
+        with jax.named_scope("flix.shard.mask"):
+            lf = lf[0]
+            upper = state.mkba[-1]
+            is_upd = (tag == OP_INSERT) | (tag == OP_DELETE) | (tag == OP_EXPIRE)
+            is_rng = tag == OP_RANGE
+            # updates run on their owner shard only; POINT/SUCCESSOR run
+            # everywhere (a successor answer may live past the owner's fence);
+            # RANGE is lifted out entirely for the cross-shard phase
+            keep = (~is_upd | ((key > lf) & (key <= upper))) & ~is_rng
+            mtag = jnp.where(keep, tag, OP_NOP)
+            mkey = jnp.where(keep, key, EMPTY)
+            mval = jnp.where(keep, val, 0)
+            order = jnp.argsort(mkey, stable=True)
+            inv = _inverse_permutation(order)
+            stag, skey = mtag[order], mkey[order]
 
         # overlapped RANGE counts phase: issued pre-apply from the predicted
         # post-update layout (invalid under an expiry pass at ``now`` — the
         # prediction cannot see which keys the clock removes)
         overlap = has_ranges and not has_now
         if overlap:
-            ins_keys = _compact_by_mask(
-                skey, (stag == OP_INSERT) | (stag == OP_EXPIRE)
+            with jax.named_scope("flix.shard.range_counts"):
+                ins_keys = _compact_by_mask(
+                    skey, (stag == OP_INSERT) | (stag == OP_EXPIRE)
+                )
+                del_keys = _compact_by_mask(skey, stag == OP_DELETE)
+                post_keys, pref = _predict_post_keys(state, ins_keys, del_keys)
+                src_b, src_p, mine, rvalid, rstart, rcnt, rtrunc = _range_counts_phase(
+                    post_keys,
+                    pref,
+                    state.mkba,
+                    is_rng,
+                    key,
+                    val.astype(KEY_DTYPE),
+                    axis,
+                    max_results,
+                )
+
+        with jax.named_scope("flix.shard.apply"):
+            new_state, res, st = apply_ops(
+                state,
+                OpBatch(
+                    tag=stag,
+                    key=skey,
+                    val=mval[order],
+                    exp=None
+                    if exp is None
+                    else jnp.where(keep, exp, NO_EXPIRY)[order],
+                ),
+                config=inner_cfg,
+                now=now,
             )
-            del_keys = _compact_by_mask(skey, stag == OP_DELETE)
-            post_keys, pref = _predict_post_keys(state, ins_keys, del_keys)
-            src_b, src_p, mine, rvalid, rstart, rcnt, rtrunc = _range_counts_phase(
-                post_keys,
-                pref,
-                state.mkba,
-                is_rng,
-                key,
-                val.astype(KEY_DTYPE),
-                axis,
-                max_results,
-            )
-
-        new_state, res, st = apply_ops(
-            state,
-            OpBatch(
-                tag=stag,
-                key=skey,
-                val=mval[order],
-                exp=None
-                if exp is None
-                else jnp.where(keep, exp, NO_EXPIRY)[order],
-            ),
-            config=inner_cfg,
-            now=now,
-        )
-        value = res["value"][inv]
-        succ_key = res["succ_key"][inv]
-
-        # POINT: at most one shard holds the key, the rest answer NOT_FOUND.
-        # EXPIRE recombines the same way: it is masked to its owner shard,
-        # whose get-or-set answer comes back through the value lane
-        is_point = (tag == OP_POINT) | (tag == OP_EXPIRE)
-        hit = is_point & (value != NOT_FOUND)
-
-        # SUCCESSOR: shard-local candidates, global min; shard key ranges
-        # are disjoint so the min is attained by exactly one shard
-        is_succ = tag == OP_SUCCESSOR
-        cand = jnp.where(is_succ, succ_key, EMPTY)
-        kmin = jax.lax.pmin(cand, axis)
-        winner = is_succ & (cand == kmin) & (cand != EMPTY)
 
         if has_ranges and not overlap:
             # sequential fallback (TTL with ``now``): counts phase against
             # the actually-updated state
-            flat_k, _ = flatten_bucket_sorted(new_state)
-            live = jnp.sum(flat_k != EMPTY, axis=1).astype(jnp.int32)
-            pref = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), jnp.cumsum(live).astype(jnp.int32)]
-            )
-            src_b, src_p, mine, rvalid, rstart, rcnt, rtrunc = _range_counts_phase(
-                flat_k,
-                pref,
-                new_state.mkba,
-                is_rng,
-                key,
-                val.astype(KEY_DTYPE),
-                axis,
-                max_results,
-            )
+            with jax.named_scope("flix.shard.range_counts"):
+                flat_k, _ = flatten_bucket_sorted(new_state)
+                live = jnp.sum(flat_k != EMPTY, axis=1).astype(jnp.int32)
+                pref = jnp.concatenate(
+                    [jnp.zeros((1,), jnp.int32), jnp.cumsum(live).astype(jnp.int32)]
+                )
+                src_b, src_p, mine, rvalid, rstart, rcnt, rtrunc = _range_counts_phase(
+                    flat_k,
+                    pref,
+                    new_state.mkba,
+                    is_rng,
+                    key,
+                    val.astype(KEY_DTYPE),
+                    axis,
+                    max_results,
+                )
 
-        # ONE fused combine psum over the whole contribution pytree (the
-        # fused executor's ``updated_buckets`` is left out: the engine's
-        # stats are the same whichever executor the shards run)
-        contrib = {
-            "pv": jnp.where(hit, value, 0),
-            "n_hit": hit.astype(jnp.int32),
-            "sv": jnp.where(winner, value, 0),
-            "inserted": st["inserted"],
-            "deleted": st["deleted"],
-            "overflowed_buckets": st["overflowed_buckets"],
-            "restructure": new_state.needs_restructure.astype(jnp.int32),
-        }
-        if has_ttl:
-            contrib["expired"] = st["expired"]
-        if has_ranges:
-            rk_c, rv_c = _range_extract_contrib(new_state, src_b, src_p, mine)
-            contrib["rk"] = rk_c
-            contrib["rv"] = rv_c
-        summed = jax.lax.psum(contrib, axis)
+        with jax.named_scope("flix.shard.combine"):
+            value = res["value"][inv]
+            succ_key = res["succ_key"][inv]
 
-        point_val = jnp.where(summed["n_hit"] > 0, summed["pv"], NOT_FOUND)
-        succ_val = jnp.where(kmin != EMPTY, summed["sv"], NOT_FOUND)
-        if has_ranges:
-            rk = jnp.where(rvalid, summed["rk"], EMPTY)
-            rv = jnp.where(rvalid, summed["rv"], NOT_FOUND)
-        else:
-            rk, rv, rstart, rcnt, rtrunc = _empty_range_outputs(
-                key.shape[0], max_results
+            # POINT: at most one shard holds the key, the rest answer NOT_FOUND.
+            # EXPIRE recombines the same way: it is masked to its owner shard,
+            # whose get-or-set answer comes back through the value lane
+            is_point = (tag == OP_POINT) | (tag == OP_EXPIRE)
+            hit = is_point & (value != NOT_FOUND)
+
+            # SUCCESSOR: shard-local candidates, global min; shard key ranges
+            # are disjoint so the min is attained by exactly one shard
+            is_succ = tag == OP_SUCCESSOR
+            cand = jnp.where(is_succ, succ_key, EMPTY)
+            kmin = jax.lax.pmin(cand, axis)
+            winner = is_succ & (cand == kmin) & (cand != EMPTY)
+
+            # ONE fused combine psum over the whole contribution pytree (the
+            # fused executor's ``updated_buckets`` is left out: the engine's
+            # stats are the same whichever executor the shards run)
+            contrib = {
+                "pv": jnp.where(hit, value, 0),
+                "n_hit": hit.astype(jnp.int32),
+                "sv": jnp.where(winner, value, 0),
+                "inserted": st["inserted"],
+                "deleted": st["deleted"],
+                "overflowed_buckets": st["overflowed_buckets"],
+                "restructure": new_state.needs_restructure.astype(jnp.int32),
+                # this shard's entry: the batch's updates it owns
+                "shard_updates": jnp.zeros((n_shards,), jnp.int32)
+                .at[jax.lax.axis_index(axis)]
+                .set(jnp.sum(keep & is_upd).astype(jnp.int32)),
+            }
+            if has_ttl:
+                contrib["expired"] = st["expired"]
+            if has_ranges:
+                rk_c, rv_c = _range_extract_contrib(new_state, src_b, src_p, mine)
+                contrib["rk"] = rk_c
+                contrib["rv"] = rv_c
+            summed = jax.lax.psum(contrib, axis)
+
+            point_val = jnp.where(summed["n_hit"] > 0, summed["pv"], NOT_FOUND)
+            succ_val = jnp.where(kmin != EMPTY, summed["sv"], NOT_FOUND)
+            if has_ranges:
+                rk = jnp.where(rvalid, summed["rk"], EMPTY)
+                rv = jnp.where(rvalid, summed["rv"], NOT_FOUND)
+            else:
+                rk, rv, rstart, rcnt, rtrunc = _empty_range_outputs(
+                    key.shape[0], max_results
+                )
+
+            results = {
+                "value": jnp.where(
+                    is_point, point_val, jnp.where(is_succ, succ_val, NOT_FOUND)
+                ),
+                "succ_key": jnp.where(is_succ, kmin, EMPTY),
+                "range_key": rk,
+                "range_val": rv,
+                "range_start": rstart,
+                "range_count": rcnt,
+            }
+            stats = {
+                "inserted": summed["inserted"],
+                "deleted": summed["deleted"],
+                "overflowed_buckets": summed["overflowed_buckets"],
+                "range_truncated": rtrunc,
+                "a2a_overflow": jnp.int32(0),
+                "shard_updates": summed["shard_updates"],
+            }
+            if has_ttl:
+                stats["expired"] = summed["expired"]
+            new_state = dataclasses.replace(
+                new_state,
+                needs_restructure=(summed["restructure"] > 0),
             )
-
-        results = {
-            "value": jnp.where(
-                is_point, point_val, jnp.where(is_succ, succ_val, NOT_FOUND)
-            ),
-            "succ_key": jnp.where(is_succ, kmin, EMPTY),
-            "range_key": rk,
-            "range_val": rv,
-            "range_start": rstart,
-            "range_count": rcnt,
-        }
-        stats = {
-            "inserted": summed["inserted"],
-            "deleted": summed["deleted"],
-            "overflowed_buckets": summed["overflowed_buckets"],
-            "range_truncated": rtrunc,
-            "a2a_overflow": jnp.int32(0),
-        }
-        if has_ttl:
-            stats["expired"] = summed["expired"]
-        new_state = dataclasses.replace(
-            new_state,
-            needs_restructure=(summed["restructure"] > 0),
-        )
-        return new_state, results, stats
+            return new_state, results, stats
 
     specs = _state_specs(axis, has_ttl)
     rep_results = {
@@ -620,6 +684,7 @@ def _build_replicated(
         "overflowed_buckets": P(),
         "range_truncated": P(),
         "a2a_overflow": P(),
+        "shard_updates": P(),
     }
     if has_ttl:
         rep_stats["expired"] = P()
@@ -628,15 +693,20 @@ def _build_replicated(
         in_specs += (P(),)
     if has_now:
         in_specs += (P(),)
-    fn = jax.shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(specs, rep_results, rep_stats),
         check_vma=False,
     )
+
+    def flix_shard_replicated(*args):
+        # the program's name on a device trace: ``jit_flix_shard_replicated``
+        return sharded(*args)
+
     donate_argnums = (0,) if donate else ()
-    return jax.jit(fn, donate_argnums=donate_argnums)
+    return jax.jit(flix_shard_replicated, donate_argnums=donate_argnums)
 
 
 @functools.lru_cache(maxsize=64)
@@ -673,179 +743,214 @@ def _build_a2a(
         if has_ranges:
             # gather every shard's RANGE rows up front — depends only on the
             # batch inputs, so it overlaps the routing + update below
-            g_tag = jax.lax.all_gather(tag, axis).reshape(-1)
-            g_lo = jax.lax.all_gather(key, axis).reshape(-1)
-            g_hi = jax.lax.all_gather(val, axis).reshape(-1)
-            g_isr = g_tag == OP_RANGE
-            gorder = jnp.argsort(jnp.where(g_isr, g_lo, EMPTY), stable=True)
-            isr_s = g_isr[gorder]
-            q_lo = g_lo[gorder]
-            q_hi = g_hi[gorder].astype(KEY_DTYPE)
+            with jax.named_scope("flix.shard.range_counts"):
+                g_tag = jax.lax.all_gather(tag, axis).reshape(-1)
+                g_lo = jax.lax.all_gather(key, axis).reshape(-1)
+                g_hi = jax.lax.all_gather(val, axis).reshape(-1)
+                g_isr = g_tag == OP_RANGE
+                gorder = jnp.argsort(jnp.where(g_isr, g_lo, EMPTY), stable=True)
+                isr_s = g_isr[gorder]
+                q_lo = g_lo[gorder]
+                q_hi = g_hi[gorder].astype(KEY_DTYPE)
 
-        # RANGE rows never ride the a2a (the cross-shard phase answers them
-        # from the gathered batch); masking them to the EMPTY tail keeps the
-        # local sort a valid routing order
-        rkey = jnp.where(is_rng, EMPTY, key)
-        order = jnp.argsort(rkey, stable=True)
-        inv = _inverse_permutation(order)
-        s_tag, s_key, s_val = tag[order], rkey[order], val[order]
-        s_exp = None if exp is None else exp[order]
+        with jax.named_scope("flix.shard.mask"):
+            # RANGE rows never ride the a2a (the cross-shard phase answers
+            # them from the gathered batch); masking them to the EMPTY tail
+            # keeps the local sort a valid routing order
+            rkey = jnp.where(is_rng, EMPTY, key)
+            order = jnp.argsort(rkey, stable=True)
+            inv = _inverse_permutation(order)
+            s_tag, s_key, s_val = tag[order], rkey[order], val[order]
+            s_exp = None if exp is None else exp[order]
 
-        # per-destination slices by one partition-fence searchsorted
-        ends = jnp.searchsorted(s_key, part_fences, side="right").astype(jnp.int32)
-        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
-        counts = ends - starts
-        overflow = jnp.sum(jnp.maximum(counts - capacity, 0))
+            # per-destination slices by one partition-fence searchsorted
+            ends = jnp.searchsorted(s_key, part_fences, side="right").astype(jnp.int32)
+            starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+            counts = ends - starts
+            overflow = jnp.sum(jnp.maximum(counts - capacity, 0))
 
-        idx = starts[:, None] + jnp.arange(capacity, dtype=jnp.int32)[None]
-        valid = idx < ends[:, None]
-        idx_c = jnp.minimum(idx, n_local - 1)
-        send_t = jnp.where(valid, s_tag[idx_c], OP_NOP)
-        send_k = jnp.where(valid, s_key[idx_c], EMPTY)
-        send_v = jnp.where(valid, s_val[idx_c], 0)
+            idx = starts[:, None] + jnp.arange(capacity, dtype=jnp.int32)[None]
+            valid = idx < ends[:, None]
+            idx_c = jnp.minimum(idx, n_local - 1)
+            send_t = jnp.where(valid, s_tag[idx_c], OP_NOP)
+            send_k = jnp.where(valid, s_key[idx_c], EMPTY)
+            send_v = jnp.where(valid, s_val[idx_c], 0)
 
-        recv_t = jax.lax.all_to_all(send_t, axis, 0, 0).reshape(-1)
-        recv_k = jax.lax.all_to_all(send_k, axis, 0, 0).reshape(-1)
-        recv_v = jax.lax.all_to_all(send_v, axis, 0, 0).reshape(-1)
-        recv_e = None
-        if s_exp is not None:
-            # the expiry deadline rides as a fourth send lane; EXPIRE rows
-            # route to their owner by key exactly like other update ops
-            send_e = jnp.where(valid, s_exp[idx_c], NO_EXPIRY)
-            recv_e = jax.lax.all_to_all(send_e, axis, 0, 0).reshape(-1)
-        rord = jnp.argsort(recv_k, stable=True)
-        rinv = _inverse_permutation(rord)
-        r_tag, r_key = recv_t[rord], recv_k[rord]
+        with jax.named_scope("flix.shard.exchange"):
+            recv_t = jax.lax.all_to_all(send_t, axis, 0, 0).reshape(-1)
+            recv_k = jax.lax.all_to_all(send_k, axis, 0, 0).reshape(-1)
+            recv_v = jax.lax.all_to_all(send_v, axis, 0, 0).reshape(-1)
+            recv_e = None
+            if s_exp is not None:
+                # the expiry deadline rides as a fourth send lane; EXPIRE rows
+                # route to their owner by key exactly like other update ops
+                send_e = jnp.where(valid, s_exp[idx_c], NO_EXPIRY)
+                recv_e = jax.lax.all_to_all(send_e, axis, 0, 0).reshape(-1)
+
+        with jax.named_scope("flix.shard.mask"):
+            rord = jnp.argsort(recv_k, stable=True)
+            rinv = _inverse_permutation(rord)
+            r_tag, r_key = recv_t[rord], recv_k[rord]
 
         if overlap:
             # counts collective pre-apply: the received rows ARE this
             # shard's update batch, so the prediction sees exactly what the
             # update pass will apply
-            ins_keys = _compact_by_mask(
-                r_key, (r_tag == OP_INSERT) | (r_tag == OP_EXPIRE)
-            )
-            del_keys = _compact_by_mask(r_key, r_tag == OP_DELETE)
-            post_keys, pref = _predict_post_keys(state, ins_keys, del_keys)
-            src_b, src_p, mine, rvalid, start_s, emit_s, rtrunc = (
-                _range_counts_phase(
-                    post_keys, pref, state.mkba, isr_s, q_lo, q_hi, axis, max_results
+            with jax.named_scope("flix.shard.range_counts"):
+                ins_keys = _compact_by_mask(
+                    r_key, (r_tag == OP_INSERT) | (r_tag == OP_EXPIRE)
                 )
+                del_keys = _compact_by_mask(r_key, r_tag == OP_DELETE)
+                post_keys, pref = _predict_post_keys(state, ins_keys, del_keys)
+                src_b, src_p, mine, rvalid, start_s, emit_s, rtrunc = (
+                    _range_counts_phase(
+                        post_keys,
+                        pref,
+                        state.mkba,
+                        isr_s,
+                        q_lo,
+                        q_hi,
+                        axis,
+                        max_results,
+                    )
+                )
+
+        with jax.named_scope("flix.shard.apply"):
+            new_state, res, st = apply_ops(
+                state,
+                OpBatch(
+                    tag=r_tag,
+                    key=r_key,
+                    val=recv_v[rord],
+                    exp=None if recv_e is None else recv_e[rord],
+                ),
+                config=inner_cfg,
+                now=now,
             )
-
-        new_state, res, st = apply_ops(
-            state,
-            OpBatch(
-                tag=r_tag,
-                key=r_key,
-                val=recv_v[rord],
-                exp=None if recv_e is None else recv_e[rord],
-            ),
-            config=inner_cfg,
-            now=now,
-        )
-        value_r = res["value"][rinv]
-        skey_r = res["succ_key"][rinv]
-
-        # successor fallback across shards: an owner whose local state has
-        # no key ≥ q answers with the first non-empty *later* shard's
-        # minimum — the §8 fence-row trick one level up the hierarchy
-        m, mv = _post_update_shard_min(new_state)
-        mins = jax.lax.all_gather(m.reshape(1), axis).reshape(-1)      # [S]
-        mvals = jax.lax.all_gather(mv.reshape(1), axis).reshape(-1)
-        sufk, sufi = _suffix_min_with_index(mins)
-        sufk_pad = jnp.concatenate([sufk, jnp.array([EMPTY], KEY_DTYPE)])
-        sufi_pad = jnp.concatenate([sufi, jnp.array([0], jnp.int32)])
-        fb_key = sufk_pad[me + 1]
-        fb_val = jnp.where(fb_key != EMPTY, mvals[sufi_pad[me + 1]], NOT_FOUND)
-        needs_fb = (recv_t == OP_SUCCESSOR) & (skey_r == EMPTY)
-        skey_r = jnp.where(needs_fb, fb_key, skey_r)
-        value_r = jnp.where(needs_fb, fb_val, value_r)
-
-        # inverse a2a: owner d's row s carries results for the rows source
-        # s sent to d, in their original slots
-        back_v = jax.lax.all_to_all(value_r.reshape(n_shards, capacity), axis, 0, 0)
-        back_sk = jax.lax.all_to_all(skey_r.reshape(n_shards, capacity), axis, 0, 0)
-        dest = jnp.where(valid, idx_c, n_local).reshape(-1)
-        out_v = (
-            jnp.full((n_local + 1,), NOT_FOUND, VAL_DTYPE)
-            .at[dest]
-            .set(back_v.reshape(-1))[:n_local][inv]
-        )
-        out_sk = (
-            jnp.full((n_local + 1,), EMPTY, KEY_DTYPE)
-            .at[dest]
-            .set(back_sk.reshape(-1))[:n_local][inv]
-        )
 
         if has_ranges and not overlap:
             # sequential fallback (TTL with ``now``): counts phase against
             # the actually-updated state
-            flat_k, _ = flatten_bucket_sorted(new_state)
-            live = jnp.sum(flat_k != EMPTY, axis=1).astype(jnp.int32)
-            pref = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), jnp.cumsum(live).astype(jnp.int32)]
-            )
-            src_b, src_p, mine, rvalid, start_s, emit_s, rtrunc = (
-                _range_counts_phase(
-                    flat_k, pref, new_state.mkba, isr_s, q_lo, q_hi, axis, max_results
+            with jax.named_scope("flix.shard.range_counts"):
+                flat_k, _ = flatten_bucket_sorted(new_state)
+                live = jnp.sum(flat_k != EMPTY, axis=1).astype(jnp.int32)
+                pref = jnp.concatenate(
+                    [jnp.zeros((1,), jnp.int32), jnp.cumsum(live).astype(jnp.int32)]
                 )
+                src_b, src_p, mine, rvalid, start_s, emit_s, rtrunc = (
+                    _range_counts_phase(
+                        flat_k,
+                        pref,
+                        new_state.mkba,
+                        isr_s,
+                        q_lo,
+                        q_hi,
+                        axis,
+                        max_results,
+                    )
+                )
+
+        with jax.named_scope("flix.shard.combine"):
+            value_r = res["value"][rinv]
+            skey_r = res["succ_key"][rinv]
+
+            # successor fallback across shards: an owner whose local state has
+            # no key ≥ q answers with the first non-empty *later* shard's
+            # minimum — the §8 fence-row trick one level up the hierarchy
+            m, mv = _post_update_shard_min(new_state)
+            mins = jax.lax.all_gather(m.reshape(1), axis).reshape(-1)      # [S]
+            mvals = jax.lax.all_gather(mv.reshape(1), axis).reshape(-1)
+            sufk, sufi = _suffix_min_with_index(mins)
+            sufk_pad = jnp.concatenate([sufk, jnp.array([EMPTY], KEY_DTYPE)])
+            sufi_pad = jnp.concatenate([sufi, jnp.array([0], jnp.int32)])
+            fb_key = sufk_pad[me + 1]
+            fb_val = jnp.where(fb_key != EMPTY, mvals[sufi_pad[me + 1]], NOT_FOUND)
+            needs_fb = (recv_t == OP_SUCCESSOR) & (skey_r == EMPTY)
+            skey_r = jnp.where(needs_fb, fb_key, skey_r)
+            value_r = jnp.where(needs_fb, fb_val, value_r)
+
+        with jax.named_scope("flix.shard.exchange"):
+            # inverse a2a: owner d's row s carries results for the rows source
+            # s sent to d, in their original slots
+            back_v = jax.lax.all_to_all(value_r.reshape(n_shards, capacity), axis, 0, 0)
+            back_sk = jax.lax.all_to_all(skey_r.reshape(n_shards, capacity), axis, 0, 0)
+
+        with jax.named_scope("flix.shard.combine"):
+            dest = jnp.where(valid, idx_c, n_local).reshape(-1)
+            out_v = (
+                jnp.full((n_local + 1,), NOT_FOUND, VAL_DTYPE)
+                .at[dest]
+                .set(back_v.reshape(-1))[:n_local][inv]
+            )
+            out_sk = (
+                jnp.full((n_local + 1,), EMPTY, KEY_DTYPE)
+                .at[dest]
+                .set(back_sk.reshape(-1))[:n_local][inv]
             )
 
-        # ONE fused combine psum over the whole contribution pytree (the
-        # fused executor's ``updated_buckets`` is left out: the engine's
-        # stats are the same whichever executor the shards run)
-        contrib = {
-            "inserted": st["inserted"],
-            "deleted": st["deleted"],
-            "overflowed_buckets": st["overflowed_buckets"],
-            "a2a_overflow": overflow.astype(jnp.int32),
-            "restructure": new_state.needs_restructure.astype(jnp.int32),
-        }
-        if has_ttl:
-            contrib["expired"] = st["expired"]
-        if has_ranges:
-            rk_c, rv_c = _range_extract_contrib(new_state, src_b, src_p, mine)
-            contrib["rk"] = rk_c
-            contrib["rv"] = rv_c
-        summed = jax.lax.psum(contrib, axis)
+            # ONE fused combine psum over the whole contribution pytree (the
+            # fused executor's ``updated_buckets`` is left out: the engine's
+            # stats are the same whichever executor the shards run)
+            received = (
+                (r_tag == OP_INSERT) | (r_tag == OP_DELETE) | (r_tag == OP_EXPIRE)
+            )
+            contrib = {
+                "inserted": st["inserted"],
+                "deleted": st["deleted"],
+                "overflowed_buckets": st["overflowed_buckets"],
+                "a2a_overflow": overflow.astype(jnp.int32),
+                "restructure": new_state.needs_restructure.astype(jnp.int32),
+                # this shard's entry: the updates it received
+                "shard_updates": jnp.zeros((n_shards,), jnp.int32)
+                .at[me]
+                .set(jnp.sum(received).astype(jnp.int32)),
+            }
+            if has_ttl:
+                contrib["expired"] = st["expired"]
+            if has_ranges:
+                rk_c, rv_c = _range_extract_contrib(new_state, src_b, src_p, mine)
+                contrib["rk"] = rk_c
+                contrib["rv"] = rv_c
+            summed = jax.lax.psum(contrib, axis)
 
-        if has_ranges:
-            rk = jnp.where(rvalid, summed["rk"], EMPTY)
-            rv = jnp.where(rvalid, summed["rv"], NOT_FOUND)
-            # scatter per-op offsets back to this shard's input rows
-            gid = gorder
-            op_mine = isr_s & (gid // n_local == me)
-            back = jnp.where(op_mine, gid - me * n_local, n_local)
-            zeros = jnp.zeros((n_local + 1,), jnp.int32)
-            rstart = zeros.at[back].set(jnp.where(isr_s, start_s, 0))[:n_local]
-            rcnt = zeros.at[back].set(jnp.where(isr_s, emit_s, 0))[:n_local]
-        else:
-            rk, rv, _, _, rtrunc = _empty_range_outputs(n_local, max_results)
-            rstart = jnp.zeros((n_local,), jnp.int32)
-            rcnt = jnp.zeros((n_local,), jnp.int32)
+            if has_ranges:
+                rk = jnp.where(rvalid, summed["rk"], EMPTY)
+                rv = jnp.where(rvalid, summed["rv"], NOT_FOUND)
+                # scatter per-op offsets back to this shard's input rows
+                gid = gorder
+                op_mine = isr_s & (gid // n_local == me)
+                back = jnp.where(op_mine, gid - me * n_local, n_local)
+                zeros = jnp.zeros((n_local + 1,), jnp.int32)
+                rstart = zeros.at[back].set(jnp.where(isr_s, start_s, 0))[:n_local]
+                rcnt = zeros.at[back].set(jnp.where(isr_s, emit_s, 0))[:n_local]
+            else:
+                rk, rv, _, _, rtrunc = _empty_range_outputs(n_local, max_results)
+                rstart = jnp.zeros((n_local,), jnp.int32)
+                rcnt = jnp.zeros((n_local,), jnp.int32)
 
-        results = {
-            "value": out_v,
-            "succ_key": out_sk,
-            "range_key": rk,
-            "range_val": rv,
-            "range_start": rstart,
-            "range_count": rcnt,
-        }
-        stats = {
-            "inserted": summed["inserted"],
-            "deleted": summed["deleted"],
-            "overflowed_buckets": summed["overflowed_buckets"],
-            "range_truncated": rtrunc,
-            "a2a_overflow": summed["a2a_overflow"],
-        }
-        if has_ttl:
-            stats["expired"] = summed["expired"]
-        new_state = dataclasses.replace(
-            new_state,
-            needs_restructure=(summed["restructure"] > 0),
-        )
-        return new_state, results, stats
+            results = {
+                "value": out_v,
+                "succ_key": out_sk,
+                "range_key": rk,
+                "range_val": rv,
+                "range_start": rstart,
+                "range_count": rcnt,
+            }
+            stats = {
+                "inserted": summed["inserted"],
+                "deleted": summed["deleted"],
+                "overflowed_buckets": summed["overflowed_buckets"],
+                "range_truncated": rtrunc,
+                "a2a_overflow": summed["a2a_overflow"],
+                "shard_updates": summed["shard_updates"],
+            }
+            if has_ttl:
+                stats["expired"] = summed["expired"]
+            new_state = dataclasses.replace(
+                new_state,
+                needs_restructure=(summed["restructure"] > 0),
+            )
+            return new_state, results, stats
 
     specs = _state_specs(axis, has_ttl)
     out_results = {
@@ -862,6 +967,7 @@ def _build_a2a(
         "overflowed_buckets": P(),
         "range_truncated": P(),
         "a2a_overflow": P(),
+        "shard_updates": P(),
     }
     if has_ttl:
         rep_stats["expired"] = P()
@@ -870,15 +976,20 @@ def _build_a2a(
         in_specs += (P(axis),)
     if has_now:
         in_specs += (P(),)
-    fn = jax.shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(specs, out_results, rep_stats),
         check_vma=False,
     )
+
+    def flix_shard_a2a(*args):
+        # the program's name on a device trace: ``jit_flix_shard_a2a``
+        return sharded(*args)
+
     donate_argnums = (0,) if donate else ()
-    return jax.jit(fn, donate_argnums=donate_argnums)
+    return jax.jit(flix_shard_a2a, donate_argnums=donate_argnums)
 
 
 # a2a capacity headroom over the uniform per-destination share.  The value
@@ -976,25 +1087,49 @@ def shard_apply_ops(
         donate=donate,
         capacity=capacity,
     )
+    fn, args, kwargs = shard_executor(
+        idx, ops, mesh, cfg=cfg, has_updates=has_updates, has_ranges=has_ranges, now=now
+    )
+    with span("dispatch.sharded"):
+        new_state, results, stats = fn(*args, **kwargs)
+    return idx._replace(state=new_state), results, stats
+
+
+def shard_executor(
+    idx: ShardedFliX,
+    ops: OpBatch,
+    mesh,
+    *,
+    cfg: ExecConfig,
+    has_updates: bool | None = None,
+    has_ranges: bool | None = None,
+    now=None,
+):
+    """The jitted sharded executor for one batch and its call:
+    ``(fn, args, kwargs)``, the sharded analogue of
+    ``core.ops.plain_executor``.  ``fn.lower(*args, **kwargs)`` compiles
+    exactly what :func:`shard_apply_ops` runs, which calls this.  With
+    ``cfg.impl`` not ``"auto"`` and ``has_ranges`` given, it reads nothing
+    of the batch, so ``idx`` and ``ops`` may be ``jax.ShapeDtypeStruct``s."""
     routing = cfg.routing
     impl = resolve_impl(cfg.impl, ops, has_updates)
     max_results = cfg.max_results
     capacity = cfg.capacity
     if has_ranges is None:
-        has_ranges = bool(jnp.any(ops.tag == OP_RANGE))
+        with span("sync.has_ranges"):
+            has_ranges = bool(jnp.any(ops.tag == OP_RANGE))
     donate_r = cfg.donate and jax.default_backend() != "cpu"
     inner_cfg = _inner_config(cfg, impl)
 
     # TTL activation is structural, exactly as in single-device apply_ops: a
     # batch-side expiry column promotes the state (attaching an all-NO_EXPIRY
     # sharded column) so the shard_map pytree matches the TTL specs
-    has_ttl = idx.state.exps is not None or ops.exp is not None
-    if has_ttl and idx.state.exps is None:
+    state = idx.state
+    has_ttl = state.exps is not None or ops.exp is not None
+    if has_ttl and state.exps is None:
         shard3 = NamedSharding(mesh, P(idx.axis, None, None))
-        exps = jax.device_put(
-            jnp.full(idx.state.keys.shape, NO_EXPIRY, KEY_DTYPE), shard3
-        )
-        idx = idx._replace(state=dataclasses.replace(idx.state, exps=exps))
+        exps = jax.device_put(jnp.full(state.keys.shape, NO_EXPIRY, KEY_DTYPE), shard3)
+        state = dataclasses.replace(state, exps=exps)
     has_now = has_ttl and now is not None
     extra = ()
     if has_ttl:
@@ -1011,32 +1146,25 @@ def shard_apply_ops(
         fn = _build_replicated(
             mesh, idx.axis, inner_cfg, max_results, has_ranges, donate_r, has_ttl, has_now
         )
-        new_state, results, stats = fn(
-            idx.state, idx.lower_fence, ops.tag, ops.key, ops.val, *extra
-        )
-    else:
-        n_shards = int(mesh.shape[idx.axis])
-        if ops.size % n_shards:
-            raise ValueError(
-                f"a2a batch size {ops.size} not divisible by {n_shards} shards"
-            )
-        if capacity is None:
-            capacity = ops.size // n_shards
-        fn = _build_a2a(
-            mesh,
-            idx.axis,
-            inner_cfg,
-            max_results,
-            has_ranges,
-            capacity,
-            donate_r,
-            has_ttl,
-            has_now,
-        )
-        new_state, results, stats = fn(
-            idx.state, idx.part_fences, ops.tag, ops.key, ops.val, *extra
-        )
-    return idx._replace(state=new_state), results, stats
+        return fn, (state, idx.lower_fence, ops.tag, ops.key, ops.val, *extra), {}
+    n_shards = int(mesh.shape[idx.axis])
+    size = ops.tag.shape[0]
+    if size % n_shards:
+        raise ValueError(f"a2a batch size {size} not divisible by {n_shards} shards")
+    if capacity is None:
+        capacity = size // n_shards
+    fn = _build_a2a(
+        mesh,
+        idx.axis,
+        inner_cfg,
+        max_results,
+        has_ranges,
+        capacity,
+        donate_r,
+        has_ttl,
+        has_now,
+    )
+    return fn, (state, idx.part_fences, ops.tag, ops.key, ops.val, *extra), {}
 
 
 def shard_apply_ops_safe(
@@ -1083,60 +1211,66 @@ def shard_apply_ops_safe(
       attempts (the final attempt's own ``a2a_overflow`` stays 0 on
       success — this counter is how the retries remain visible).
     """
-    cfg = resolve_config(
-        "shard_apply_ops_safe",
-        config,
-        routing=routing,
-        impl=impl,
-        max_results=max_results,
-        capacity=capacity,
-    )
-    cap = cfg.capacity
-    if cfg.routing == "a2a" and cap is None:
-        cap = default_a2a_capacity(
-            ops.size // int(mesh.shape[idx.axis]), int(mesh.shape[idx.axis])
+    with span("shard_apply_ops_safe"):
+        cfg = resolve_config(
+            "shard_apply_ops_safe",
+            config,
+            routing=routing,
+            impl=impl,
+            max_results=max_results,
+            capacity=capacity,
         )
-    # this driver replays batches, so it must own the buffers: never donate
-    run_cfg = cfg.replace(donate=False, capacity=cap)
-    a2a_retries = 0
-    a2a_dropped = 0
-    while True:
-        new_idx, results, stats = shard_apply_ops(
-            idx,
-            ops,
-            mesh,
-            config=run_cfg,
-            has_updates=has_updates,
-            has_ranges=has_ranges,
-            now=now,
-        )
-        if cfg.routing != "a2a":
-            break
-        chunk = ops.size // int(mesh.shape[idx.axis])
-        overflow = int(stats["a2a_overflow"])
-        if overflow == 0 or run_cfg.capacity >= chunk:
-            break
-        a2a_retries += 1
-        a2a_dropped += overflow
-        run_cfg = run_cfg.replace(capacity=min(chunk, run_cfg.capacity * 2))
-    overflowed = bool(new_idx.state.needs_restructure) and not bool(
-        idx.state.needs_restructure
-    )
-    if overflowed:
-        n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-        grown = shard_restructure(idx, mesh, extra_keys=max(n_ins, 1))
-        new_idx, results, stats = shard_apply_ops(
-            grown,
-            ops,
-            mesh,
-            config=run_cfg,
-            has_updates=has_updates,
-            has_ranges=has_ranges,
-            now=now,
-        )
-        assert not bool(new_idx.state.needs_restructure), "post-restructure overflow"
-    stats = dict(stats)
-    stats["restructure_retries"] = int(overflowed)
-    stats["a2a_retries"] = a2a_retries
-    stats["a2a_overflow_dropped"] = a2a_dropped
-    return new_idx, results, stats
+        cap = cfg.capacity
+        if cfg.routing == "a2a" and cap is None:
+            cap = default_a2a_capacity(
+                ops.size // int(mesh.shape[idx.axis]), int(mesh.shape[idx.axis])
+            )
+        # replays need the pre-batch buffers: never donate
+        run_cfg = cfg.replace(donate=False, capacity=cap)
+        a2a_retries = 0
+        a2a_dropped = 0
+        while True:
+            new_idx, results, stats = shard_apply_ops(
+                idx,
+                ops,
+                mesh,
+                config=run_cfg,
+                has_updates=has_updates,
+                has_ranges=has_ranges,
+                now=now,
+            )
+            if cfg.routing != "a2a":
+                break
+            chunk = ops.size // int(mesh.shape[idx.axis])
+            overflow = int(stats["a2a_overflow"])
+            if overflow == 0 or run_cfg.capacity >= chunk:
+                break
+            a2a_retries += 1
+            a2a_dropped += overflow
+            run_cfg = run_cfg.replace(capacity=min(chunk, run_cfg.capacity * 2))
+        # waits for the executor: the device's tail of the batch ends here
+        with span("sync.needs_restructure"):
+            overflowed = bool(new_idx.state.needs_restructure) and not bool(
+                idx.state.needs_restructure
+            )
+        if overflowed:
+            with span("restructure"):
+                n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
+                grown = shard_restructure(idx, mesh, extra_keys=max(n_ins, 1))
+                new_idx, results, stats = shard_apply_ops(
+                    grown,
+                    ops,
+                    mesh,
+                    config=run_cfg,
+                    has_updates=has_updates,
+                    has_ranges=has_ranges,
+                    now=now,
+                )
+                assert not bool(new_idx.state.needs_restructure), (
+                    "post-restructure overflow"
+                )
+        stats = dict(stats)
+        stats["restructure_retries"] = int(overflowed)
+        stats["a2a_retries"] = a2a_retries
+        stats["a2a_overflow_dropped"] = a2a_dropped
+        return new_idx, results, stats

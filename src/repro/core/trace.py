@@ -16,9 +16,19 @@ The spans, each where its work happens:
     overflow flag, which waits for the executor;
   * ``restructure`` — regrowing the table and replaying the batch.
 
+``core/distributed.shard_apply_ops_safe`` writes its own around the same
+steps, and reuses ``sync.has_updates``, ``sync.needs_restructure`` and
+``restructure``:
+
+  * ``shard_apply_ops_safe`` — the whole sharded host call;
+  * ``sync.has_ranges`` — ``shard_apply_ops``' blocking read of the tags,
+    only when the caller does not say whether the batch holds a RANGE;
+  * ``dispatch.sharded`` — the sharded executor call.
+
 The device side is named by ``jax.named_scope``: ``flix.fused.*`` in the
-fused executor's wrapper (``kernels/flix_apply``) and ``flix.reference.*``
-in the reference executor (``core/ops``).
+fused executor's wrapper (``kernels/flix_apply``), ``flix.reference.*``
+in the reference executor (``core/ops``) and ``flix.shard.*`` in the
+sharded executors' bodies (``core/distributed``), around the inner ones.
 """
 
 from __future__ import annotations
@@ -34,6 +44,9 @@ SPANS = (
     "dispatch.fused",
     "dispatch.reference",
     "restructure",
+    "shard_apply_ops_safe",
+    "sync.has_ranges",
+    "dispatch.sharded",
 )
 _NAMES = {name: PREFIX + name for name in SPANS}
 
