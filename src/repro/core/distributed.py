@@ -610,8 +610,9 @@ def _build_replicated(
             winner = is_succ & (cand == kmin) & (cand != EMPTY)
 
             # ONE fused combine psum over the whole contribution pytree (the
-            # fused executor's ``updated_buckets`` is left out: the engine's
-            # stats are the same whichever executor the shards run)
+            # fused executor's ``updated_buckets`` and ``delete_min_fixups``
+            # are left out: the engine's stats are the same whichever
+            # executor the shards run)
             contrib = {
                 "pv": jnp.where(hit, value, 0),
                 "n_hit": hit.astype(jnp.int32),
@@ -889,8 +890,9 @@ def _build_a2a(
             )
 
             # ONE fused combine psum over the whole contribution pytree (the
-            # fused executor's ``updated_buckets`` is left out: the engine's
-            # stats are the same whichever executor the shards run)
+            # fused executor's ``updated_buckets`` and ``delete_min_fixups``
+            # are left out: the engine's stats are the same whichever
+            # executor the shards run)
             received = (
                 (r_tag == OP_INSERT) | (r_tag == OP_DELETE) | (r_tag == OP_EXPIRE)
             )

@@ -42,14 +42,22 @@ def _locate(state: FliXState, queries: jax.Array):
     return b, nidx_c, pos_c, key_at, in_bucket, pos
 
 
+def locate_stored(state: FliXState, queries: jax.Array):
+    """For each query: (stored?, bucket, node-slot, in-node position).
+
+    The slot is the query's own where ``stored`` holds; elsewhere it is
+    clamped in range and names no key of the query."""
+    b, nidx, pos, key_at, in_bucket, raw_pos = _locate(state, queries)
+    hit = in_bucket & (raw_pos < state.node_size) & (key_at == queries)
+    return hit, b, nidx, pos
+
+
 @jax.jit
 def point_query(state: FliXState, sorted_queries: jax.Array) -> jax.Array:
     """Point lookups for a sorted query batch. Misses return NOT_FOUND."""
     q = sorted_queries.astype(KEY_DTYPE)
-    b, nidx, pos, key_at, in_bucket, raw_pos = _locate(state, q)
-    hit = in_bucket & (raw_pos < state.node_size) & (key_at == q)
-    vals = state.vals[b, nidx, pos]
-    return jnp.where(hit, vals, NOT_FOUND)
+    hit, b, nidx, pos = locate_stored(state, q)
+    return jnp.where(hit, state.vals[b, nidx, pos], NOT_FOUND)
 
 
 def _suffix_min_with_index(g: jax.Array):

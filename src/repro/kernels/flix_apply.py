@@ -78,7 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flix_query import DEFAULT_BLOCK_Q
 from repro.core.batch import bucket_slices, gather_kv_sublists, gather_sublists
-from repro.core.state import EMPTY, KEY_DTYPE, NOT_FOUND, FliXState
+from repro.core.state import EMPTY, KEY_DTYPE, FliXState
 
 DEFAULT_BLOCK_B = 2     # bucket stripes per block (walked one bucket at a time)
 # Scoped VMEM the kernel is compiled with, set explicitly rather than left
@@ -717,6 +717,40 @@ def apply_call(
     )(lo, hi, tags, keys, stripe_k, stripe_v, node_max, ik, iv, dk, fences, g_row)
 
 
+def _surviving_min(keys2d, vals2d, hit, bkt, slot):
+    """Each bucket's smallest stored key that the batch's deletes leave, and
+    its value, from the delete lanes' own slots: ``(min [nb], val [nb],
+    buckets recomputed)``.
+
+    ``keys2d``/``vals2d`` are the stripes ``[nb, S]``; lane ``i`` of the
+    sorted delete keys is stored where ``hit[i]``, at stripe slot
+    ``slot[i]`` of bucket ``bkt[i]``.  A bucket without such a lane keeps
+    its stored minimum, slot 0 (I1–I2; EMPTY when the bucket is empty).  A
+    bucket with one is recomputed once, in the row of its first hit lane:
+    the keys are sorted, so the buckets of the hit lanes never decrease
+    and each bucket's deletes form one run.  Costs O(nb + n·S) for n lanes,
+    where a search of every stored slot would cost O(nb·S·log n)."""
+    nb, S = keys2d.shape
+    n = hit.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    seen = jax.lax.cummax(jnp.where(hit, bkt, -1))
+    prev = jnp.concatenate([jnp.full((min(n, 1),), -1, jnp.int32), seen[:-1]])
+    first = hit & (bkt > prev)                 # first hit lane of its bucket
+    run = jax.lax.cummax(jnp.where(first, lane, 0))
+    drop = (
+        jnp.zeros((n, S), jnp.bool_)
+        .at[jnp.where(hit, run, n), slot]
+        .set(True, mode="drop")
+    )
+    rows = jnp.where(drop, EMPTY, keys2d[bkt])  # [n, S]: lane i's bucket
+    row_min = jnp.min(rows, axis=1)
+    row_val = vals2d[bkt, jnp.argmin(rows, axis=1)]
+    fix = jnp.where(first, bkt, nb)
+    surv_min = keys2d[:, 0].at[fix].set(row_min, mode="drop")
+    surv_val = vals2d[:, 0].at[fix].set(row_val, mode="drop")
+    return surv_min, surv_val, jnp.sum(first).astype(jnp.int32)
+
+
 def _fused_apply(
     state, tag, key, val, *, block_q, block_b, max_results, interpret, pipeline
 ):
@@ -725,7 +759,7 @@ def _fused_apply(
     from repro.core.query import (
         _suffix_min_with_index,
         flat_rank,
-        point_query,
+        locate_stored,
         range_offsets,
         range_slot_ranks,
     )
@@ -750,24 +784,26 @@ def _fused_apply(
         # per-bucket DELETE tiles, pre-filtered to PRESENT keys so each bucket's
         # sublist fits its capacity tile (same trick as flix_delete; filtering
         # against the pre-insert state is exact because one batch never inserts
-        # and deletes the same key).
-        present = point_query(state, del_keys) != NOT_FOUND
+        # and deletes the same key).  The located slots of the present keys
+        # also serve the fence rows and the RANGE plumbing below.
+        present, dbkt, dnode, dpos = locate_stored(state, del_keys)
+        dslot = dnode * ns + dpos
         dk_sorted = jnp.sort(jnp.where(present, del_keys, EMPTY))
         dstarts, dends = bucket_slices(state, dk_sorted)
         dk_tile, _, _ = gather_sublists(dk_sorted, dstarts, dends, cap)
 
     # --- post-update successor fence rows (one O(nb) suffix scan) ---------
     with jax.named_scope("flix.fused.fence_rows"):
-        # surviving stripe minimum: smallest stored key not in the delete batch
-        flat_k = state.keys.reshape(nb, S)
-        flat_v = state.vals.reshape(nb, S)
-        dpos = jnp.searchsorted(del_keys, flat_k.reshape(-1), side="left")
-        dpos = jnp.minimum(dpos, jnp.maximum(del_keys.shape[0] - 1, 0))
-        dhit = (del_keys[dpos] == flat_k.reshape(-1)) & (flat_k.reshape(-1) != EMPTY)
-        masked = jnp.where(dhit.reshape(nb, S), EMPTY, flat_k)
-        surv_min = jnp.min(masked, axis=1)
-        amin = jnp.argmin(masked, axis=1)
-        surv_val = flat_v[jnp.arange(nb), amin]
+        # the stripes in row-major [nb, S], made once for every reader below
+        # and the kernel: left alone, XLA fuses the layout change into each
+        # reader, and at 2^23 keys the v5e compile then counts 12.3 GiB of
+        # temporaries against 6.3 GiB with the one copy
+        flat_k, flat_v = jax.lax.optimization_barrier(
+            (state.keys.reshape(nb, S), state.vals.reshape(nb, S))
+        )
+        surv_min, surv_val, min_fixups = _surviving_min(
+            flat_k, flat_v, present, dbkt, dslot
+        )
         ins_min = ik[:, 0]                       # tiles are sorted, EMPTY-padded
         ins_val = iv[:, 0]
         bucket_min = jnp.minimum(surv_min, ins_min)
@@ -789,13 +825,15 @@ def _fused_apply(
 
     with jax.named_scope("flix.fused.range_plumbing"):
         def _range_plumbing():
-            mflat = masked.reshape(-1)
-            ipos = jnp.clip(
-                jnp.searchsorted(ins_keys, mflat, side="left"), 0, max(n - 1, 0)
-            )
-            upserted = (ins_keys[ipos] == mflat) & (mflat != EMPTY)
+            # the stripes less the batch's present deletes and its upserted
+            # keys, each emptied at its own slot (the insert tiles bring the
+            # upserts back with their new values)
+            upserted, ubkt, unode, upos = locate_stored(state, ins_keys)
+            gone = jnp.concatenate([present, upserted])
+            rows = jnp.where(gone, jnp.concatenate([dbkt, ubkt]), nb)
+            cols = jnp.concatenate([dslot, unode * ns + upos])
             post_rows = jnp.concatenate(
-                [jnp.where(upserted.reshape(nb, S), EMPTY, masked), ik], axis=1
+                [flat_k.at[rows, cols].set(EMPTY, mode="drop"), ik], axis=1
             )
             post_sorted = jnp.sort(post_rows, axis=1)
             live_post = jnp.sum(post_sorted != EMPTY, axis=1).astype(jnp.int32)
@@ -941,6 +979,9 @@ def _fused_apply(
             # read of a tile here would keep that 1 GiB plane (at 2^23 keys)
             # alive past the kernel, beyond what a v5e can load
             "updated_buckets": jnp.sum((true_counts > 0) | (dends > dstarts)),
+            # buckets whose post-update minimum was recomputed: those holding
+            # a present DELETE (counted from the delete lanes, O(n))
+            "delete_min_fixups": min_fixups,
         }
     return new_state, results, stats
 

@@ -10,6 +10,9 @@ topology: single- and double-buffered, at the ``KVPageIndex`` geometry
 on a grid of several windows and bucket blocks, with the default tile and
 with each corner of the autotuner's tile grid (smallest and largest
 ``block_q`` × ``block_b``, the largest being the biggest VMEM footprint).
+One more compiles the whole fused executor, wrapper and kernel, at the
+benchmark's one-chip store (2^23 keys, 4 096-op batches) and holds its
+memory to what a v5e can load.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library.
@@ -30,7 +33,9 @@ from repro.kernels.autotune import (
     VMEM_BUDGET_BYTES,
     vmem_bytes,
 )
-from repro.kernels.flix_apply import N_FENCE_ROWS, apply_call
+from repro.core.config import ExecConfig
+from repro.core.state import FliXState
+from repro.kernels.flix_apply import N_FENCE_ROWS, apply_call, flix_apply_pallas
 
 GEOMETRIES = pytest.mark.parametrize(
     "ns,npb", [(16, 8), (32, 16)], ids=["kv_index_16x8", "default_32x16"]
@@ -113,3 +118,33 @@ def test_tile_grid_corners_compile_for_v5e(
     _compile(
         one_chip, ns=ns, npb=npb, pipeline=pipeline, block_q=block_q, block_b=block_b
     )
+
+
+def test_fused_executor_memory_fits_v5e(one_chip):
+    """The fused executor at 2^23 keys (2^19 buckets of 16 × 32 slots) and
+    4 096 ops: its arguments, outputs and temporaries fit one v5e's HBM,
+    and the temporaries stay near the 6.26 GiB this compile gives (a whole
+    1 GiB plane kept alive, or the stripes' layout copies left to fuse into
+    each reader, adds 1 GiB or more and the chip refuses to load it)."""
+    nb, npb, ns, n = 2**19, 16, 32, 4096
+
+    def sds(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = FliXState(
+        keys=sds(nb, npb, ns),
+        vals=sds(nb, npb, ns),
+        node_count=sds(nb, npb),
+        node_max=sds(nb, npb),
+        num_nodes=sds(nb),
+        mkba=sds(nb),
+        needs_restructure=sds(dtype=jnp.bool_),
+    )
+    compiled = flix_apply_pallas.lower(
+        state, sds(n), sds(n), sds(n),
+        max_results=ExecConfig().max_results, interpret=False, pipeline=True,
+    ).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 15.75 * 2**30
+    assert mem.temp_size_in_bytes < 7 * 2**30

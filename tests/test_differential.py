@@ -21,6 +21,9 @@ Three families of proofs:
      merge).
 """
 
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -29,6 +32,8 @@ from repro import core
 from repro.core.invariants import check_invariants
 from repro.core.state import EMPTY, MAX_VALID, NOT_FOUND
 from repro.kernels import ref
+from repro.core.query import locate_stored
+from repro.kernels.flix_apply import _surviving_min
 from repro.kernels.flix_delete import flix_delete_pallas
 from repro.kernels.flix_insert import flix_insert_pallas
 from repro.kernels.flix_query import flix_point_query_pallas
@@ -651,6 +656,159 @@ def test_fused_updated_buckets_counter(rng, batch):
     ops, _ = core.make_ops(tags, keys, vals)
     _, _, stats = core.apply_ops(st, ops, config=ExecConfig(impl="fused"))
     assert int(stats["updated_buckets"]) == want
+
+
+# ---------------------------------------------------------------------------
+# post-update fence rows: each bucket's surviving minimum, from the slots of
+# the batch's present deletes
+# ---------------------------------------------------------------------------
+
+
+def _stored_rows(st):
+    """Each bucket's stored keys, ascending (I1-I2)."""
+    k = np.asarray(st.keys).reshape(st.num_buckets, -1)
+    return [r[r != int(EMPTY)] for r in k]
+
+
+def _gapped_state(rng):
+    """A fresh build less every bucket's largest key, so that a SUCCESSOR
+    of ``mkba[b]`` finds nothing in bucket ``b`` and falls through to the
+    post-update minimum of the next bucket that holds a key."""
+    st, _, _ = _state_of_kind("fresh", rng)
+    tops = np.array([r[-1] for r in _stored_rows(st)], np.int32)
+    st, _ = core.delete(st, jnp.asarray(tops))
+    check_invariants(st)
+    return st, _stored_rows(st)
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+@pytest.mark.parametrize(
+    "case",
+    [
+        "bucket_min",
+        "first_k",
+        "whole_bucket",
+        "min_under_smaller_insert",
+        "absent",
+        "adjacent",
+        "min_valued_not_found",
+    ],
+)
+def test_fused_fence_rows_under_deletes(rng, case, pipeline):
+    """Deletes that change a bucket's minimum, then SUCCESSORs of fences,
+    each falling through its bucket to the next one: the fused
+    executor equals the reference (and the post-state keeps I1-I5).  An
+    emptied bucket must be skipped; a smaller key inserted beside the
+    deleted minimum must win; a stored key deletes whatever its value,
+    ``NOT_FOUND`` included."""
+    st, rows = _gapped_state(rng)
+    nb = st.num_buckets
+    mkba = np.asarray(st.mkba)
+    b = next(
+        c
+        for c in range(nb // 3, nb - 3)
+        if min(len(rows[c + i]) for i in range(3)) >= 3 and rows[c][0] > mkba[c - 1] + 1
+    )
+    ins = np.zeros(0, np.int32)
+    if case == "bucket_min":
+        dels = rows[b][:1]
+    elif case == "first_k":
+        dels = rows[b][:2]
+    elif case == "whole_bucket":
+        dels = rows[b]
+    elif case == "min_under_smaller_insert":
+        dels, ins = rows[b][:1], np.array([mkba[b - 1] + 1], np.int32)
+    elif case == "absent":
+        span = np.arange(mkba[b - 1] + 1, mkba[b + 2], dtype=np.int32)
+        absent = np.setdiff1d(span, np.concatenate(rows + [mkba]))
+        dels = rng.choice(absent, 8, replace=False)
+    elif case == "adjacent":
+        dels = np.concatenate([rows[b], rows[b + 1][:1], rows[b + 2][:2]])
+    else:  # min_valued_not_found
+        st = dataclasses.replace(st, vals=st.vals.at[b, 0, 0].set(NOT_FOUND))
+        dels = rows[b][:1]
+    # the fences around ``b`` and a spread of others
+    succ = mkba[np.union1d(np.arange(b - 2, b + 4), rng.choice(nb, 120))]
+    tags = np.concatenate([
+        np.full(dels.size, core.OP_DELETE), np.full(ins.size, core.OP_INSERT),
+        np.full(succ.size, core.OP_SUCCESSOR),
+    ]).astype(np.int32)
+    keys = np.concatenate([dels, ins, succ]).astype(np.int32)
+    vals = np.concatenate([np.zeros(dels.size), ins + 5_000_000, np.zeros(succ.size)])
+    assert tags.size <= 256
+    _assert_fused_matches_reference(
+        st, tags, keys, vals.astype(np.int32), pad_to=256, pipeline=pipeline
+    )
+
+
+@jax.jit
+def _surviving_min_of(st, sorted_dels):
+    hit, bkt, node, pos = locate_stored(st, sorted_dels)
+    nb = st.num_buckets
+    return _surviving_min(
+        st.keys.reshape(nb, -1), st.vals.reshape(nb, -1), hit, bkt,
+        node * st.node_size + pos,
+    )
+
+
+@pytest.mark.parametrize("kind", ["fresh", "deleted", "restructured", "expired"])
+def test_surviving_min_matches_numpy(rng, kind):
+    """The wrapper's surviving minimum (and its value, and the count of
+    buckets it recomputed) equals a brute-force NumPy minimum over every
+    stored slot, for delete sets of present keys, whole buckets and absent
+    keys."""
+    st, live, _ = _state_of_kind(kind, rng)
+    nb = st.num_buckets
+    keys2d = np.asarray(st.keys).reshape(nb, -1)
+    vals2d = np.asarray(st.vals).reshape(nb, -1)
+    rows = _stored_rows(st)
+    absent = np.setdiff1d(np.arange(0, 125000, 3, dtype=np.int32), live)
+    for _ in range(4):
+        whole = rng.choice(nb, 3, replace=False)
+        dels = np.unique(np.concatenate(
+            [rng.choice(live, rng.integers(0, 200))]
+            + [rows[c] for c in whole]
+            + [rng.choice(absent, 40)]
+        )).astype(np.int32)
+        batch = np.full(512, int(EMPTY), np.int32)
+        batch[: dels.size] = dels
+        got_min, got_val, fixups = _surviving_min_of(st, jnp.asarray(batch))
+        gone = np.isin(keys2d, dels) & (keys2d != int(EMPTY))
+        masked = np.where(gone, int(EMPTY), keys2d)
+        want = masked.min(axis=1)
+        np.testing.assert_array_equal(np.asarray(got_min), want)
+        held = want != int(EMPTY)
+        want_val = vals2d[np.arange(nb), masked.argmin(axis=1)]
+        np.testing.assert_array_equal(np.asarray(got_val)[held], want_val[held])
+        assert int(fixups) == int(gone.any(axis=1).sum())
+
+
+@pytest.mark.parametrize("batch", ["no_deletes", "few_buckets", "every_bucket"])
+def test_fused_delete_min_fixups_counter(rng, batch):
+    """``stats["delete_min_fixups"]`` counts the buckets whose post-update
+    minimum the wrapper recomputed: those holding a DELETE of a stored key
+    under the pre-batch fences — none without deletes, exactly the few
+    buckets of a minority batch's present deletes, all of them when every
+    bucket loses its minimum."""
+    st, live, _ = _state_of_kind("fresh", rng)
+    if batch == "every_bucket":
+        keys = np.asarray(st.keys)[:, 0, 0].astype(np.int32)
+        tags = np.full(keys.size, core.OP_DELETE, np.int32)
+        vals = np.zeros(keys.size, np.int32)
+    else:
+        tags, keys, vals = _minority_batch(rng, live)
+        if batch == "no_deletes":
+            keep = tags != core.OP_DELETE
+            tags, keys, vals = tags[keep], keys[keep], vals[keep]
+    is_del = tags == core.OP_DELETE
+    want = _updated_buckets(st, np.where(is_del, tags, core.OP_POINT), keys).size
+    if batch != "few_buckets":
+        assert want == (0 if batch == "no_deletes" else st.num_buckets)
+    else:
+        assert 0 < want < st.num_buckets // 10
+    ops, _ = core.make_ops(tags, keys, vals)
+    _, _, stats = core.apply_ops(st, ops, config=ExecConfig(impl="fused"))
+    assert int(stats["delete_min_fixups"]) == want
 
 
 # ---------------------------------------------------------------------------
