@@ -4,14 +4,15 @@ The paper's execution model is one sorted batch of mixed operations per
 step.  This suite sweeps the update ratio (0% = read-only … 100% = pure
 updates) on a fixed-size batch and times
 
-  * ``apply_ops(impl="reference")`` — the unified jnp engine: one global
-    sort, one bucket routing, but still four device passes over the state
-    (insert merge, delete, point, successor),
-  * ``apply_ops(impl="fused")`` — the compute-to-bucket Pallas kernel
-    (``kernels/flix_apply.py``): one VMEM-resident pass per bucket executes
-    the whole update-then-read sequence.  Compiled on TPU; in *interpret
-    mode* on this CPU container, where the recorded "speedup" is the honest
-    interpret-vs-jnp ratio (< 1) — the number to watch on real hardware.
+  * ``apply_ops(config=ExecConfig(impl="reference"))`` — the unified jnp
+    engine: one global sort, one bucket routing, but still four device
+    passes over the state (insert merge, delete, point, successor),
+  * ``apply_ops(config=ExecConfig(impl="fused"))`` — the compute-to-bucket
+    Pallas kernel (``kernels/flix_apply.py``): one VMEM-resident pass per
+    bucket executes the whole update-then-read sequence.  Compiled on TPU;
+    in *interpret mode* on CPU hosts, where the recorded "speedup"
+    is the honest interpret-vs-jnp ratio (< 1) — the number to watch on real
+    hardware.
     Measured at the read-heavy (0%) and update-heavy (100%) sweep ends so
     the interpret-mode cost stays bounded.
   * ``sequential`` — the pre-engine serving path: sort + route the inserts,

@@ -14,13 +14,13 @@ suite measures what that costs inside the batch engine.  The grid:
 
 Timed forms:
 
-  * ``apply_ops(impl="reference")`` — the jnp engine (its range phase is
-    the dense two-pass oracle: rank fences + exclusive-scan offsets + one
-    gather).
-  * ``apply_ops(impl="fused")`` — the compute-to-bucket Pallas kernel with
-    the in-VMEM range phase, at one sweep point (interpret mode on CPU
-    hosts: the recorded "speedup" < 1 is the honest interpret-vs-jnp
-    ratio — the number to watch on real hardware).
+  * ``apply_ops(config=ExecConfig(impl="reference"))`` — the jnp engine (its
+    range phase is the dense two-pass oracle: rank fences + exclusive-scan
+    offsets + one gather).
+  * ``apply_ops(config=ExecConfig(impl="fused"))`` — the compute-to-bucket
+    Pallas kernel with the in-VMEM range phase, at one sweep point
+    (interpret mode on CPU hosts: the recorded "speedup" < 1 is the honest
+    interpret-vs-jnp ratio — the number to watch on real hardware).
   * ``flix_range_pallas`` — the standalone two-pass count/scatter kernel on
     a pure range batch, same caveat.
 

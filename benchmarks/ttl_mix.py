@@ -17,12 +17,12 @@ inside the engine.  The grid:
 
 Timed forms:
 
-  * ``apply_ops(impl="reference", now=...)`` — the jnp engine running the
-    expiry pre-pass + two-plane TTL execution.
-  * ``apply_ops(impl="fused", now=...)`` — the compute-to-bucket Pallas
-    kernel under the same TTL planes, at one sweep point (interpret mode
-    on CPU hosts: the recorded "speedup" < 1 is the honest
-    interpret-vs-jnp ratio — the number to watch on real hardware).
+  * ``apply_ops(config=ExecConfig(impl="reference"), now=...)`` — the jnp
+    engine running the expiry pre-pass + two-plane TTL execution.
+  * ``apply_ops(config=ExecConfig(impl="fused"), now=...)`` — the
+    compute-to-bucket Pallas kernel under the same TTL planes, at one sweep
+    point (interpret mode on CPU hosts: the recorded "speedup" < 1 is the
+    honest interpret-vs-jnp ratio — the number to watch on real hardware).
   * ``expire_state`` alone — the pre-pass's marginal cost per skew level.
 
 ``benchmarks.run`` lifts the ``ttl_mix_fused_*`` / ``ttl_mix_ref_*``

@@ -5,8 +5,9 @@ Commit protocol, per engine batch (WAL-ahead):
   1. frame + append the sorted ``OpBatch`` (with its ``max_results``) to
      the write-ahead log and fsync — the batch is durable *before* the
      engine runs it;
-  2. execute it (``apply_ops`` / ``shard_apply_ops`` behind an engine
-     adapter, restructure-and-retry included);
+  2. execute it (``apply_ops_safe`` / ``shard_apply_ops_safe`` /
+     ``TieredFliX.apply`` behind an engine adapter, restructure-and-retry
+     included);
   3. fold the batch's update keys into the dirty-bucket set (fences are
      fixed between restructures, so host-side ``searchsorted`` routing is
      exact); a restructure bumps the *fence epoch* and dirties everything;
@@ -56,16 +57,9 @@ from repro.checkpoint.serialize import (
     state_from_pairs,
 )
 from repro.checkpoint.wal import WriteAheadLog, decode_ops, encode_ops
-from repro.core.config import _UNSET, ExecConfig, resolve_config
+from repro.core.config import ExecConfig
 from repro.core.expiry import NO_EXPIRY
-from repro.core.ops import (
-    OP_DELETE,
-    OP_EXPIRE,
-    OP_INSERT,
-    OpBatch,
-    apply_ops,
-)
-from repro.core.restructure import restructure_grow
+from repro.core.ops import OP_DELETE, OP_EXPIRE, OP_INSERT, OpBatch, apply_ops_safe
 from repro.core.state import EMPTY
 
 SNAP_FORMAT = "flix-durable-v1"
@@ -122,11 +116,10 @@ class EngineBase:
 class LocalEngine(EngineBase):
     """Single-device executor behind the durability layer.
 
-    ``config`` carries the execution strategy (kernel pipeline, tiles, …)
-    threaded to every inner ``apply_ops``; ``impl`` remains as a direct
-    ctor knob and is folded into it.  The per-batch ``max_results`` is NOT
-    part of this config — it is logged per WAL record so replay re-runs
-    each batch under its own budget.
+    ``config`` carries the execution strategy (executor, kernel pipeline,
+    tiles, …) threaded to every ``apply_ops_safe``.  The per-batch
+    ``max_results`` is NOT part of this config — it is logged per WAL
+    record so replay re-runs each batch under its own budget.
     """
 
     kind = "local"
@@ -134,16 +127,12 @@ class LocalEngine(EngineBase):
     def __init__(
         self,
         *,
-        impl: str = "auto",
         config: ExecConfig | None = None,
         node_size: int = 32,
         nodes_per_bucket: int = 16,
         fill: float = 0.5,
     ):
-        self.config = config if config is not None else ExecConfig(impl=impl)
-        if config is not None and impl != "auto":
-            self.config = self.config.replace(impl=impl)
-        self.impl = self.config.impl
+        self.config = config or ExecConfig()
         self.node_size = node_size
         self.nodes_per_bucket = nodes_per_bucket
         self.fill = fill
@@ -163,21 +152,12 @@ class LocalEngine(EngineBase):
         return handle
 
     def apply(self, handle, ops: OpBatch, *, max_results: int, now=None):
-        """``apply_ops`` with the restructure-and-retry loop surfaced: the
-        durability layer must KNOW when the fence epoch changed, so it
-        drives the retry itself instead of calling ``apply_ops_safe``."""
-        cfg = self.config.replace(max_results=max_results, donate=False)
-        new, results, stats = apply_ops(handle, ops, config=cfg, now=now)
-        restructured = False
-        if bool(new.needs_restructure) and not bool(handle.needs_restructure):
-            n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-            grown = restructure_grow(handle, extra_keys=max(n_ins, 1))
-            new, results, stats = apply_ops(grown, ops, config=cfg, now=now)
-            assert not bool(new.needs_restructure), "post-restructure overflow"
-            restructured = True
-        stats = dict(stats)
-        stats["restructure_retries"] = int(restructured)
-        return new, results, stats, restructured
+        """``apply_ops_safe``.  A restructure replay moves the fences, and
+        the durability layer learns of that fence-epoch bump from
+        ``stats["restructure_retries"]``."""
+        cfg = self.config.replace(max_results=max_results)
+        new, results, stats = apply_ops_safe(handle, ops, config=cfg, now=now)
+        return new, results, stats, bool(stats["restructure_retries"])
 
 
 class ShardEngine(EngineBase):
@@ -185,8 +165,9 @@ class ShardEngine(EngineBase):
 
     The handle is a ``ShardedFliX``; rebuilds go through ``shard_build``
     (so recovery re-partitions fences from the recovered contents — the
-    durable analogue of ``shard_restructure``), and the retry loop mirrors
-    ``shard_apply_ops_safe`` while reporting the epoch bump.
+    durable analogue of ``shard_restructure``), and batches run through
+    ``shard_apply_ops_safe``, whose ``restructure_retries`` reports the
+    epoch bump.
     """
 
     kind = "sharded"
@@ -195,24 +176,13 @@ class ShardEngine(EngineBase):
         self,
         mesh,
         *,
-        routing: str = "replicated",
-        impl: str = "auto",
         config: ExecConfig | None = None,
         node_size: int = 32,
         nodes_per_bucket: int = 16,
         fill: float = 0.5,
     ):
         self.mesh = mesh
-        self.config = (
-            config if config is not None else ExecConfig(impl=impl, routing=routing)
-        )
-        if config is not None:
-            if impl != "auto":
-                self.config = self.config.replace(impl=impl)
-            if routing != "replicated":
-                self.config = self.config.replace(routing=routing)
-        self.routing = self.config.routing
-        self.impl = self.config.impl
+        self.config = config or ExecConfig()
         self.node_size = node_size
         self.nodes_per_bucket = nodes_per_bucket
         self.fill = fill
@@ -239,24 +209,15 @@ class ShardEngine(EngineBase):
         return handle.state
 
     def apply(self, handle, ops: OpBatch, *, max_results: int, now=None):
-        from repro.core.distributed import shard_apply_ops, shard_restructure
+        """``shard_apply_ops_safe``: same fence-epoch signal as
+        :meth:`LocalEngine.apply`."""
+        from repro.core.distributed import shard_apply_ops_safe
 
-        cfg = self.config.replace(max_results=max_results, donate=False)
-        new, results, stats = shard_apply_ops(handle, ops, self.mesh, config=cfg, now=now)
-        restructured = False
-        if bool(new.state.needs_restructure) and not bool(
-            handle.state.needs_restructure
-        ):
-            n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-            grown = shard_restructure(handle, self.mesh, extra_keys=max(n_ins, 1))
-            new, results, stats = shard_apply_ops(
-                grown, ops, self.mesh, config=cfg, now=now
-            )
-            assert not bool(new.state.needs_restructure), "post-restructure overflow"
-            restructured = True
-        stats = dict(stats)
-        stats["restructure_retries"] = int(restructured)
-        return new, results, stats, restructured
+        cfg = self.config.replace(max_results=max_results)
+        new, results, stats = shard_apply_ops_safe(
+            handle, ops, self.mesh, config=cfg, now=now
+        )
+        return new, results, stats, bool(stats["restructure_retries"])
 
 
 class TieredEngine(EngineBase):
@@ -279,17 +240,13 @@ class TieredEngine(EngineBase):
         self,
         *,
         budget_bytes: int | None = None,
-        impl: str = "auto",
         config: ExecConfig | None = None,
         node_size: int = 32,
         nodes_per_bucket: int = 16,
         fill: float = 0.5,
     ):
         self.budget_bytes = budget_bytes
-        self.config = config if config is not None else ExecConfig(impl=impl)
-        if config is not None and impl != "auto":
-            self.config = self.config.replace(impl=impl)
-        self.impl = self.config.impl
+        self.config = config or ExecConfig()
         self.node_size = node_size
         self.nodes_per_bucket = nodes_per_bucket
         self.fill = fill
@@ -691,12 +648,10 @@ class DurableFliX:
         config: ExecConfig | None = None,
         meta=None,
         now: int | None = None,
-        max_results=_UNSET,
     ):
         """Durably execute one sorted batch; returns ``(results, stats)``.
 
-        Execution strategy rides on ``config=ExecConfig(...)`` (the bare
-        ``max_results`` keyword is a deprecated warn-once shim).  Only its
+        Execution strategy rides on ``config=ExecConfig(...)``.  Only its
         ``max_results`` is durable — it is logged per WAL record so replay
         re-runs each batch under its own budget; the rest of the strategy
         (impl, pipeline, tiles) belongs to the live engine and may differ
@@ -725,8 +680,7 @@ class DurableFliX:
         snapshot refused) because live and durable state have diverged —
         reopening from disk is the only consistent continuation.
         """
-        cfg = resolve_config("DurableFliX.apply", config, max_results=max_results)
-        mr = cfg.max_results
+        mr = (config or ExecConfig()).max_results
         self._check_poisoned()
         tag, key, val, exp = ops.to_host()
         if exp is None and now is not None:

@@ -19,12 +19,7 @@ from repro.core.batch import (
     sort_batch,
 )
 from repro.core.build import build, build_from_sorted, plan_geometry
-from repro.core.config import (
-    ExecConfig,
-    TileTable,
-    reset_deprecation_warnings,
-    resolve_config,
-)
+from repro.core.config import ExecConfig, TileTable
 from repro.core.query import (
     dense_range_scan,
     point_query,

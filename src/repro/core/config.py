@@ -16,10 +16,9 @@ object is :class:`ExecConfig`:
     hints (``has_updates``/``has_ranges``) are therefore **not** config —
     they stay per-call keywords.
 
-The legacy per-entry-point keywords still work this PR as thin deprecation
-shims: passing any of them builds an ``ExecConfig`` and warns once per
-entry point (``DeprecationWarning``).  They are removed next PR;
-``tools/check_exec_config.py`` gates the repo's own callers off them now.
+Every entry point takes ``config: ExecConfig | None = None`` and nothing
+else for strategy (``None`` means ``ExecConfig()``); the per-entry-point
+keywords the config replaced are gone.
 
 :class:`TileTable` carries the autotuner's chosen (block_q, block_b) tile
 per (build_size, batch_size) bucket.  It is plain data — hashable tuples
@@ -30,13 +29,8 @@ in, JSON out — so it round-trips through the bench artifact
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 DEFAULT_MAX_RESULTS = 128  # per-batch RANGE output budget (static)
-
-# sentinel distinguishing "caller did not pass this keyword" from any real
-# value (None is a real value for block_q/block_b/capacity)
-_UNSET = object()
 
 
 def _pow2_bucket(n: int) -> int:
@@ -152,41 +146,3 @@ class ExecConfig:
                 bq = bq if bq is not None else hit[0]
                 bb = bb if bb is not None else hit[1]
         return bq, bb
-
-
-# --- legacy-keyword shims ---------------------------------------------------
-
-# entry points that already warned this process (warn once per entry point)
-_warned: set[str] = set()
-
-
-def reset_deprecation_warnings() -> None:
-    """Re-arm the warn-once latches (tests asserting the warning use this)."""
-    _warned.clear()
-
-
-def resolve_config(entry: str, config: ExecConfig | None, /, **legacy) -> ExecConfig:
-    """Build the effective ExecConfig for an entry point.
-
-    ``legacy`` maps deprecated keyword names to their passed values, with
-    :data:`_UNSET` marking "not passed".  Passing any deprecated keyword
-    warns once per ``entry`` and is rejected when ``config=`` is also
-    given (the two would silently fight otherwise).
-    """
-    passed = {k: v for k, v in legacy.items() if v is not _UNSET}
-    if not passed:
-        return config if config is not None else ExecConfig()
-    if config is not None:
-        raise TypeError(
-            f"{entry}: pass config=ExecConfig(...) OR the deprecated keywords "
-            f"{sorted(passed)}, not both"
-        )
-    if entry not in _warned:
-        _warned.add(entry)
-        warnings.warn(
-            f"{entry}: keyword(s) {sorted(passed)} are deprecated — pass "
-            f"config=ExecConfig(...) instead (shims drop next release)",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-    return ExecConfig(**passed)

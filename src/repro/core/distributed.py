@@ -53,7 +53,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.batch import bucket_slices, gather_sublists
 from repro.core.build import build_from_sorted
-from repro.core.config import _UNSET, ExecConfig, resolve_config
+from repro.core.config import ExecConfig
 from repro.core.expiry import NO_EXPIRY
 from repro.core.ops import (
     OP_DELETE,
@@ -69,6 +69,7 @@ from repro.core.ops import (
     resolve_impl,
 )
 from repro.core.query import _suffix_min_with_index, flat_rank, range_offsets
+from repro.core.restructure import replay_on_overflow
 from repro.core.state import (
     EMPTY,
     KEY_DTYPE,
@@ -91,6 +92,11 @@ class ShardedFliX(NamedTuple):
     lower_fence: jax.Array    # [n_shards] fence below each shard's range
     part_fences: jax.Array    # [n_shards] upper fence per shard (replicated)
     axis: str
+
+    @property
+    def needs_restructure(self) -> jax.Array:
+        """The overflow flag, as on ``FliXState`` (``replay_on_overflow``)."""
+        return self.state.needs_restructure
 
 
 def plan_shard_budget(total_budget: int | None, n_shards: int) -> int | None:
@@ -1044,20 +1050,15 @@ def shard_apply_ops(
     has_updates: bool | None = None,
     has_ranges: bool | None = None,
     now=None,
-    routing=_UNSET,
-    impl=_UNSET,
-    max_results=_UNSET,
-    donate=_UNSET,
-    capacity=_UNSET,
 ):
     """Execute one mixed sorted batch across the mesh.
 
     Execution strategy comes in as one ``config=ExecConfig(...)``
     (``routing`` / ``impl`` / ``max_results`` / ``donate`` / ``capacity``
     plus the fused-kernel pipeline and tile knobs threaded to the per-shard
-    ``apply_ops``); the trailing keywords are deprecated warn-once shims.
-    Per-call facts (``has_updates`` / ``has_ranges`` hints, the TTL clock
-    ``now``) stay keywords — they describe the batch, not the strategy.
+    ``apply_ops``; ``None`` means ``ExecConfig()``).  Per-call facts
+    (``has_updates`` / ``has_ranges`` hints, the TTL clock ``now``) stay
+    keywords — they describe the batch, not the strategy.
 
     Returns ``(idx', results, stats)`` with the single-device ``apply_ops``
     contract (DESIGN.md §11):
@@ -1080,15 +1081,7 @@ def shard_apply_ops(
     hosts use :func:`shard_apply_ops_safe`, whose retry path regrows via
     :func:`shard_restructure`.
     """
-    cfg = resolve_config(
-        "shard_apply_ops",
-        config,
-        routing=routing,
-        impl=impl,
-        max_results=max_results,
-        donate=donate,
-        capacity=capacity,
-    )
+    cfg = config or ExecConfig()
     fn, args, kwargs = shard_executor(
         idx, ops, mesh, cfg=cfg, has_updates=has_updates, has_ranges=has_ranges, now=now
     )
@@ -1178,21 +1171,17 @@ def shard_apply_ops_safe(
     has_updates: bool | None = None,
     has_ranges: bool | None = None,
     now=None,
-    routing=_UNSET,
-    impl=_UNSET,
-    max_results=_UNSET,
-    capacity=_UNSET,
 ):
     """Host-level driver: apply, restructure-and-retry on bucket overflow.
 
-    Mirrors ``apply_ops_safe`` one level up: the retry replays the *whole*
+    Mirrors ``apply_ops_safe`` one level up, through the same
+    ``core.restructure.replay_on_overflow``: the retry replays the *whole*
     batch on a rebalanced (``shard_restructure``-grown) pre-batch index,
     which is safe because :func:`shard_apply_ops` never mutates its input
     (and is also why this driver never donates).  ``has_updates`` /
     ``has_ranges`` let drivers that already know the batch composition
     host-side skip the device syncs (``serve/kv_index.py`` does).
-    Execution strategy comes in as one ``config=ExecConfig(...)``; the
-    trailing keywords are deprecated warn-once shims.
+    Execution strategy comes in as one ``config=ExecConfig(...)``.
 
     Under ``routing="a2a"``, per-pair overflow
     (``stats["a2a_overflow"] > 0``) is ALSO retried here — the documented
@@ -1202,7 +1191,8 @@ def shard_apply_ops_safe(
     ``capacity`` unset, the starting point is the skew-derived
     :func:`default_a2a_capacity` rather than the worst-case chunk: ~n_shards
     times less a2a traffic on typical batches, with at most a couple of
-    doubling replays on pathological skew.
+    doubling replays on pathological skew.  A restructure replay starts
+    from the capacity the first attempt reached.
 
     The returned ``stats`` surfaces the whole driver run (host ints, so
     the gateway and bench artifact can report them without device syncs):
@@ -1214,53 +1204,22 @@ def shard_apply_ops_safe(
       success — this counter is how the retries remain visible).
     """
     with span("shard_apply_ops_safe"):
-        cfg = resolve_config(
-            "shard_apply_ops_safe",
-            config,
-            routing=routing,
-            impl=impl,
-            max_results=max_results,
-            capacity=capacity,
-        )
+        cfg = config or ExecConfig()
+        n_shards = int(mesh.shape[idx.axis])
+        chunk = ops.size // n_shards
         cap = cfg.capacity
         if cfg.routing == "a2a" and cap is None:
-            cap = default_a2a_capacity(
-                ops.size // int(mesh.shape[idx.axis]), int(mesh.shape[idx.axis])
-            )
+            cap = default_a2a_capacity(chunk, n_shards)
         # replays need the pre-batch buffers: never donate
         run_cfg = cfg.replace(donate=False, capacity=cap)
         a2a_retries = 0
         a2a_dropped = 0
-        while True:
-            new_idx, results, stats = shard_apply_ops(
-                idx,
-                ops,
-                mesh,
-                config=run_cfg,
-                has_updates=has_updates,
-                has_ranges=has_ranges,
-                now=now,
-            )
-            if cfg.routing != "a2a":
-                break
-            chunk = ops.size // int(mesh.shape[idx.axis])
-            overflow = int(stats["a2a_overflow"])
-            if overflow == 0 or run_cfg.capacity >= chunk:
-                break
-            a2a_retries += 1
-            a2a_dropped += overflow
-            run_cfg = run_cfg.replace(capacity=min(chunk, run_cfg.capacity * 2))
-        # waits for the executor: the device's tail of the batch ends here
-        with span("sync.needs_restructure"):
-            overflowed = bool(new_idx.state.needs_restructure) and not bool(
-                idx.state.needs_restructure
-            )
-        if overflowed:
-            with span("restructure"):
-                n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-                grown = shard_restructure(idx, mesh, extra_keys=max(n_ins, 1))
+
+        def run(handle):
+            nonlocal run_cfg, a2a_retries, a2a_dropped
+            while True:
                 new_idx, results, stats = shard_apply_ops(
-                    grown,
+                    handle,
                     ops,
                     mesh,
                     config=run_cfg,
@@ -1268,11 +1227,21 @@ def shard_apply_ops_safe(
                     has_ranges=has_ranges,
                     now=now,
                 )
-                assert not bool(new_idx.state.needs_restructure), (
-                    "post-restructure overflow"
-                )
-        stats = dict(stats)
-        stats["restructure_retries"] = int(overflowed)
+                if cfg.routing != "a2a":
+                    return new_idx, results, stats
+                overflow = int(stats["a2a_overflow"])
+                if overflow == 0 or run_cfg.capacity >= chunk:
+                    return new_idx, results, stats
+                a2a_retries += 1
+                a2a_dropped += overflow
+                run_cfg = run_cfg.replace(capacity=min(chunk, run_cfg.capacity * 2))
+
+        new_idx, results, stats = replay_on_overflow(
+            run,
+            lambda h, extra_keys: shard_restructure(h, mesh, extra_keys=extra_keys),
+            idx,
+            ops,
+        )
         stats["a2a_retries"] = a2a_retries
         stats["a2a_overflow_dropped"] = a2a_dropped
         return new_idx, results, stats

@@ -181,14 +181,21 @@ def insert_safe(state: FliXState, sorted_keys, sorted_vals):
     This is the paper's contract — restructuring is the capacity-management
     mechanism (§3.5); overflow pressure triggers it.  Host-driven because the
     new geometry changes static shapes (like a GPU-side realloc + rebuild).
+    The retry is ``core.restructure.replay_on_overflow``, the engines' one
+    driver, which sizes the regrowth by the batch's non-EMPTY keys; its
+    ``restructure_retries`` joins the returned stats.
     """
-    from repro.core.restructure import restructure_grow
+    from repro.core.ops import OP_INSERT, OP_NOP, OpBatch
+    from repro.core.restructure import replay_on_overflow, restructure_grow
 
-    new_state, stats = insert(state, sorted_keys, sorted_vals)
-    if bool(new_state.needs_restructure):
-        n_incoming = int(jnp.sum(sorted_keys != EMPTY))
-        grown = restructure_grow(state, extra_keys=n_incoming)
-        new_state, stats = insert(grown, sorted_keys, sorted_vals)
-        # Geometry from restructure_grow always fits the merged content.
-        assert not bool(new_state.needs_restructure), "post-restructure overflow"
+    keys = jnp.asarray(sorted_keys)
+    ops = OpBatch(
+        tag=jnp.where(keys != EMPTY, OP_INSERT, OP_NOP), key=keys, val=sorted_vals
+    )
+
+    def run(s):
+        new_state, stats = insert(s, sorted_keys, sorted_vals)
+        return new_state, None, stats
+
+    new_state, _, stats = replay_on_overflow(run, restructure_grow, state, ops)
     return new_state, stats

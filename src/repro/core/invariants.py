@@ -140,7 +140,8 @@ def check_range_results(ops, results, *, max_results: int) -> None:
     op's ``[lo, hi)``, segments are packed consecutively from offset 0 in
     batch order, and slots beyond the emitted total hold EMPTY / NOT_FOUND.
     Differential tests pin the *values*; this checker is the cheap
-    post-apply sanity used by ``apply_ops_safe(validate_ranges=True)``.
+    post-apply sanity used by
+    ``apply_ops_safe(config=ExecConfig(validate_ranges=True))``.
     """
     from repro.core.ops import OP_RANGE
 

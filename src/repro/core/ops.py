@@ -58,10 +58,8 @@ import jax.numpy as jnp
 
 from repro.core.batch import bucket_slices
 from repro.core.config import (
-    _UNSET,
     DEFAULT_MAX_RESULTS as DEFAULT_MAX_RESULTS,  # canonical home: core.config
     ExecConfig as ExecConfig,
-    resolve_config,
 )
 from repro.core.state import EMPTY, KEY_DTYPE, NOT_FOUND, VAL_DTYPE, FliXState
 from repro.core.trace import span
@@ -587,11 +585,6 @@ def apply_ops(
     config: ExecConfig | None = None,
     has_updates: bool | None = None,
     now=None,
-    impl=_UNSET,
-    donate=_UNSET,
-    block_q=_UNSET,
-    block_b=_UNSET,
-    max_results=_UNSET,
 ):
     """Execute one mixed sorted batch.  Returns ``(state', results, stats)``.
 
@@ -612,10 +605,8 @@ def apply_ops(
 
     ``config`` is the single execution-strategy surface
     (:class:`repro.core.config.ExecConfig`, DESIGN.md §16) — executor
-    choice, pipelining, donation, tile sizes, the RANGE budget.  The bare
-    keywords below (``impl``, ``donate``, ``block_q``, ``block_b``,
-    ``max_results``) are deprecation shims that build one and warn once;
-    they drop next release.  ``has_updates`` and ``now`` are *per-call*
+    choice, pipelining, donation, tile sizes, the RANGE budget; ``None``
+    means ``ExecConfig()``.  ``has_updates`` and ``now`` are *per-call*
     facts about the batch, not strategy, so they stay keywords.
 
     ``config.impl`` selects the executor:
@@ -652,15 +643,7 @@ def apply_ops(
     the overflowing buckets are untrustworthy — same contract as ``insert``;
     hosts use :func:`apply_ops_safe`.
     """
-    cfg = resolve_config(
-        "apply_ops",
-        config,
-        impl=impl,
-        donate=donate,
-        block_q=block_q,
-        block_b=block_b,
-        max_results=max_results,
-    )
+    cfg = config or ExecConfig()
     impl_r = resolve_impl(cfg.impl, ops, has_updates)
     # TTL activation is structural (does an expiry column exist on the state
     # or the batch?), so it is host-decidable even inside shard_map traces.
@@ -676,15 +659,12 @@ def apply_ops_safe(
     config: ExecConfig | None = None,
     has_updates: bool | None = None,
     now=None,
-    impl=_UNSET,
-    max_results=_UNSET,
-    validate_ranges=_UNSET,
-    validate=_UNSET,
 ):
     """Host-level driver: apply, restructure-and-retry on overflow.
 
     Mirrors ``insert_safe`` — restructuring is host-driven because the new
-    geometry changes static shapes.  The retry replays the *whole* batch on
+    geometry changes static shapes.  The retry
+    (``core.restructure.replay_on_overflow``) replays the *whole* batch on
     the regrown pre-batch state, which is safe because ``apply_ops`` never
     mutates its input (which is also why this driver never donates).
 
@@ -701,41 +681,20 @@ def apply_ops_safe(
     whole driver run, not just the final attempt — callers that account
     for retry cost (the serving gateway does) read it after the fact.
     """
-    from repro.core.restructure import restructure_grow
+    from repro.core.restructure import replay_on_overflow, restructure_grow
 
     with span("apply_ops_safe"):
-        cfg = resolve_config(
-            "apply_ops_safe",
-            config,
-            impl=impl,
-            max_results=max_results,
-            validate_ranges=validate_ranges,
-            validate=validate,
-        )
+        cfg = config or ExecConfig()
         # a retry replays the batch on the pre-batch state — never donate here
         run_cfg = cfg.replace(donate=False, validate=False, validate_ranges=False)
-        restructure_retries = 0
-        new_state, results, stats = apply_ops(
-            state, ops, config=run_cfg, has_updates=has_updates, now=now
+        new_state, results, stats = replay_on_overflow(
+            lambda s: apply_ops(
+                s, ops, config=run_cfg, has_updates=has_updates, now=now
+            ),
+            restructure_grow,
+            state,
+            ops,
         )
-        # waits for the executor: the device's tail of the batch ends here
-        with span("sync.needs_restructure"):
-            overflowed = bool(new_state.needs_restructure) and not bool(
-                state.needs_restructure
-            )
-        if overflowed:
-            with span("restructure"):
-                n_ins = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
-                grown = restructure_grow(state, extra_keys=max(n_ins, 1))
-                new_state, results, stats = apply_ops(
-                    grown, ops, config=run_cfg, has_updates=has_updates, now=now
-                )
-                assert not bool(new_state.needs_restructure), (
-                    "post-restructure overflow"
-                )
-            restructure_retries = 1
-        stats = dict(stats)
-        stats["restructure_retries"] = restructure_retries
         if cfg.validate_ranges:
             from repro.core.invariants import check_range_results
 

@@ -38,16 +38,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.build import plan_geometry
-from repro.core.config import _UNSET, ExecConfig, resolve_config
+from repro.core.config import ExecConfig
 from repro.core.expiry import NO_EXPIRY
-from repro.core.ops import (
-    OP_EXPIRE,
-    OP_INSERT,
-    OpBatch,
-    apply_ops,
-    touched_buckets,
+from repro.core.ops import OpBatch, apply_ops, touched_buckets
+from repro.core.restructure import (
+    replay_on_overflow,
+    restructure_grow,
+    restructure_shrink,
 )
-from repro.core.restructure import restructure_grow, restructure_shrink
 from repro.core.state import EMPTY, MAX_VALID, FliXState
 
 
@@ -409,23 +407,19 @@ class TieredFliX:
         config: "ExecConfig | None" = None,
         now: int | None = None,
         commit: bool = True,
-        max_results=_UNSET,
-        impl=_UNSET,
     ):
         """Prefetch → promote → run the unchanged executors → demote.
 
         Returns ``(results, stats, restructured)``; mutates ``self``.
         Execution strategy comes in as one ``config=ExecConfig(...)``
-        forwarded to the inner ``apply_ops`` (``max_results`` / ``impl``
-        are deprecated warn-once shims).  ``commit=False`` runs a read-only
-        batch: promotion/demotion still happen (residency is physical
-        placement, not logical content) but the post-apply packed bytes are
-        discarded — required for expiring reads that must not physically
-        reclaim rows.
+        forwarded to the inner ``apply_ops``.  A committed batch runs under
+        ``core.restructure.replay_on_overflow``, as every engine does.
+        ``commit=False`` runs a read-only batch: promotion/demotion still
+        happen (residency is physical placement, not logical content) but
+        the post-apply packed bytes are discarded — required for expiring
+        reads that must not physically reclaim rows.
         """
-        cfg = resolve_config(
-            "TieredFliX.apply", config, max_results=max_results, impl=impl
-        )
+        cfg = config or ExecConfig()
         # this engine replays batches on overflow and keeps the packed bytes
         # as its own working set: never donate
         cfg = cfg.replace(donate=False)
@@ -458,36 +452,40 @@ class TieredFliX:
             packed = self._gather(w_ids)
         self.promoted_total += promoted
 
-        new_packed, results, stats = apply_ops(packed, ops, config=cfg, now=now)
-        stats = dict(stats)
-        restructured = False
-        reclaimed = 0
+        before = 0
 
-        overflow = bool(new_packed.needs_restructure) and not self.needs_restructure
-        if overflow and commit:
-            # bucket overflow: the overflowed result is untrustworthy (same
-            # contract as apply_ops_safe) — regrow the PRE-batch state from a
-            # full materialization and replay.  This is the one tiered
-            # operation that transiently needs the whole index on device
-            # (same cost class as the paper's restructure relaunch).
+        def grow(handle, *, extra_keys):
+            # bucket overflow: regrow the PRE-batch state from a full
+            # materialization.  This is the one tiered operation that
+            # transiently needs the whole index on device (same cost class
+            # as the paper's restructure relaunch).
+            nonlocal before
             self.resident_ids = w_ids
-            self._packed = packed
+            self._packed = handle
             full = self.materialize()
             before = full.memory_bytes()
-            n_ins = int(((tag == OP_INSERT) | (tag == OP_EXPIRE)).sum())
-            grown = restructure_grow(full, extra_keys=max(n_ins, 1))
-            new_full, results, stats = apply_ops(grown, ops, config=cfg, now=now)
-            assert not bool(new_full.needs_restructure), "post-restructure overflow"
-            stats = dict(stats)
-            self._install_full(new_full)
-            reclaimed = max(0, before - new_full.memory_bytes())
+            return restructure_grow(full, extra_keys=extra_keys)
+
+        def run(state):
+            return apply_ops(state, ops, config=cfg, now=now)
+
+        if commit:
+            new, results, stats = replay_on_overflow(run, grow, packed, ops)
+        else:
+            new, results, stats = run(packed)
+            stats = dict(stats, restructure_retries=0)
+        restructured = stats["restructure_retries"] == 1
+        reclaimed = 0
+
+        if restructured:
+            self._install_full(new)
+            reclaimed = max(0, before - new.memory_bytes())
             self.reclaimed_total += reclaimed
-            restructured = True
         elif commit:
-            self._packed = new_packed
+            self._packed = new
             self.resident_ids = w_ids
-            self.needs_restructure = bool(new_packed.needs_restructure)
-            if self.h_exps is None and new_packed.exps is not None:
+            self.needs_restructure = bool(new.needs_restructure)
+            if self.h_exps is None and new.exps is not None:
                 # TTL plane materialized mid-stream (first batch with exps)
                 self.h_exps = np.full(self.h_keys.shape, int(NO_EXPIRY), np.int32)
             self._refresh_meta(w_ids)
@@ -497,7 +495,6 @@ class TieredFliX:
             self.resident_ids = w_ids
 
         demoted = self._evict_to_budget()
-        stats["restructure_retries"] = int(restructured)
         stats["promoted"] = promoted
         stats["demoted"] = demoted
         stats["resident_bytes"] = self.memory_bytes_resident()

@@ -6,6 +6,10 @@ key distribution — bounding both query latency (chain length → 1) and memory
 (node recovery).  Entirely device-resident: one global sort + the standard
 build; the host only chooses the new static geometry (the analogue of the
 paper's kernel relaunch with a new bucket count).
+
+:func:`replay_on_overflow` is the one host driver around it: every engine
+(single-device, sharded, tiered, durable) runs a batch, and on bucket
+overflow regrows the pre-batch handle and replays the batch through it.
 """
 
 from __future__ import annotations
@@ -18,7 +22,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.build import build_from_sorted, plan_geometry
+from repro.core.ops import OP_EXPIRE, OP_INSERT
 from repro.core.state import EMPTY, FliXState
+from repro.core.trace import span
 
 
 @partial(
@@ -156,3 +162,39 @@ def restructure_grow(
         node_size=state.node_size,
         fill=fill,
     )
+
+
+def replay_on_overflow(run, grow, handle, ops):
+    """Run one batch; on bucket overflow, regrow and replay it once.
+
+    ``run(h)`` executes ``ops`` against the handle ``h`` and returns
+    ``(new, results, stats)``; it must not mutate ``h`` (the replay needs
+    the pre-batch contents).  ``grow(h, extra_keys=n)`` returns a handle
+    with the same contents, restructured to absorb ``n`` more keys.  Any
+    handle works that answers ``needs_restructure``: a ``FliXState`` or a
+    ``ShardedFliX``.
+
+    The overflowed attempt's result is untrustworthy (``insert``'s
+    contract), so the whole batch is replayed on the grown pre-batch
+    handle, sized for the batch's INSERT and EXPIRE rows; one replay
+    always fits (``restructure_grow``'s geometry guarantee).  A handle
+    that was already flagged before the batch is not retried.
+
+    Returns ``(new, results, stats)`` from the attempt that counts, with
+    ``stats["restructure_retries"]`` (host int, 0 or 1) set: callers that
+    must know the geometry changed (the durable layer's fence epoch, the
+    tiered engine's full install) read it there.
+    """
+    new, results, stats = run(handle)
+    # waits for the executor: the device's tail of the batch ends here
+    with span("sync.needs_restructure"):
+        overflowed = bool(new.needs_restructure) and not bool(handle.needs_restructure)
+    if overflowed:
+        with span("restructure"):
+            n_inserting = int(jnp.sum((ops.tag == OP_INSERT) | (ops.tag == OP_EXPIRE)))
+            grown = grow(handle, extra_keys=max(n_inserting, 1))
+            new, results, stats = run(grown)
+            assert not bool(new.needs_restructure), "post-restructure overflow"
+    stats = dict(stats)
+    stats["restructure_retries"] = int(overflowed)
+    return new, results, stats

@@ -60,7 +60,7 @@ from repro.core import (
     make_ops,
     unsort,
 )
-from repro.core.config import _UNSET, ExecConfig, resolve_config
+from repro.core.config import ExecConfig
 
 PAGE_BITS = 12  # up to 4096 pages (≈ pages × page_size tokens) per sequence
 
@@ -109,8 +109,7 @@ class KVPageIndex:
     ``apply_ops`` executor (``"auto"`` = the fused compute-to-bucket kernel
     on TPU, the jnp reference engine elsewhere), whose ``routing`` picks
     the distributed batch mode when sharded, and whose pipeline/tile knobs
-    thread to the fused kernel.  The bare ``impl`` / ``routing`` keywords
-    are deprecated warn-once shims for it.
+    thread to the fused kernel; ``None`` means ``ExecConfig()``.
 
     ``shards`` > 0 range-partitions the index over that many local devices
     and serves every step through ``shard_apply_ops`` (replicated routing
@@ -156,14 +155,12 @@ class KVPageIndex:
         crash_hook=None,
         snapshot_window: int = 0,
         device_budget: int | None = None,
-        impl=_UNSET,
-        routing=_UNSET,
     ):
         # seed with one sentinel key (outside the (seq,page) space) so the
         # structure is never empty
         from repro.core import MAX_VALID
 
-        self.config = resolve_config("KVPageIndex", config, impl=impl, routing=routing)
+        self.config = config or ExecConfig()
         self.impl = self.config.impl
         self.routing = self.config.routing
         self._durable = None

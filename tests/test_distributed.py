@@ -17,7 +17,7 @@ def test_shard_apply_ops_end_to_end():
         """
         import numpy as np, jax, jax.numpy as jnp
         from repro import core
-        from repro.core import distributed as dist
+        from repro.core import ExecConfig, distributed as dist
 
         mesh = dist.make_shard_mesh(8)
         rng = np.random.default_rng(11)
@@ -47,7 +47,7 @@ def test_shard_apply_ops_end_to_end():
         EMPTY = np.iinfo(np.int32).max
 
         for routing in ("replicated", "a2a"):
-            _, res, stats = dist.shard_apply_ops(idx, ops, mesh, routing=routing)
+            _, res, stats = dist.shard_apply_ops(idx, ops, mesh, config=ExecConfig(routing=routing))
             assert int(stats["inserted"]) == extra.shape[0]
             assert int(stats["deleted"]) == dels.shape[0]
             value = np.asarray(core.unsort(res["value"], perm[:bk.shape[0]]))
@@ -75,7 +75,7 @@ def test_shard_apply_ops_a2a_overflow_surfaced():
         """
         import numpy as np, jax, jax.numpy as jnp
         from repro import core
-        from repro.core import distributed as dist
+        from repro.core import ExecConfig, distributed as dist
 
         mesh = dist.make_shard_mesh(8)
         rng = np.random.default_rng(13)
@@ -85,11 +85,11 @@ def test_shard_apply_ops_a2a_overflow_surfaced():
         hi = int(np.asarray(idx.part_fences)[0])  # everything -> shard 0
         q = rng.integers(0, hi, 2048).astype(np.int32)
         ops, perm = core.make_ops(np.full(2048, core.OP_POINT, np.int32), q)
-        _, _, stats = dist.shard_apply_ops(idx, ops, mesh, routing="a2a", capacity=64)
+        _, _, stats = dist.shard_apply_ops(idx, ops, mesh, config=ExecConfig(routing="a2a", capacity=64))
         assert int(stats["a2a_overflow"]) == 2048 - 8 * 64, int(stats["a2a_overflow"])
-        _, res, stats = dist.shard_apply_ops(idx, ops, mesh, routing="a2a", capacity=256)
+        _, res, stats = dist.shard_apply_ops(idx, ops, mesh, config=ExecConfig(routing="a2a", capacity=256))
         assert int(stats["a2a_overflow"]) == 0
-        _, want, _ = dist.shard_apply_ops(idx, ops, mesh, routing="replicated")
+        _, want, _ = dist.shard_apply_ops(idx, ops, mesh, config=ExecConfig(routing="replicated"))
         assert (np.asarray(res["value"]) == np.asarray(want["value"])).all()
         print("A2A_OVERFLOW_OK")
         """

@@ -1,42 +1,11 @@
-"""ExecConfig surface: shims warn once, config+legacy rejected, StepResult
-is attribute-only (DESIGN.md §16).
-
-The six entry points — ``apply_ops``, ``apply_ops_safe``,
-``shard_apply_ops(_safe)``, ``TieredFliX.apply``, ``KVPageIndex`` — share
-one resolution path (``core.config.resolve_config``), so the contract is
-proven against the path plus one end-to-end entry point per flavor;
-``tools/check_exec_config.py`` separately gates the repo's own callers off
-the deprecated keywords.
-"""
-
-import warnings
+"""ExecConfig surface: frozen and validated, StepResult is attribute-only,
+TileTable lookups (DESIGN.md §16)."""
 
 import numpy as np
 import pytest
 
-from repro import core
-from repro.core.config import (
-    ExecConfig,
-    TileTable,
-    reset_deprecation_warnings,
-    resolve_config,
-)
+from repro.core.config import ExecConfig, TileTable
 from repro.serve.kv_index import KVPageIndex, StepResult
-
-
-@pytest.fixture(autouse=True)
-def _rearm():
-    reset_deprecation_warnings()
-    yield
-    reset_deprecation_warnings()
-
-
-def _small_state_and_ops(rng):
-    keys = rng.choice(5000, size=400, replace=False).astype(np.int32)
-    st = core.build(keys, keys, node_size=8, nodes_per_bucket=8)
-    q = np.sort(rng.choice(keys, 64)).astype(np.int32)
-    ops, _ = core.make_ops(np.full(64, core.OP_POINT, np.int32), q, pad_to=64)
-    return st, ops
 
 
 def test_frozen_hashable_validated():
@@ -50,54 +19,6 @@ def test_frozen_hashable_validated():
     # replace returns a new validated instance
     assert cfg.replace(impl="reference").impl == "reference"
     assert cfg.impl == "fused"
-
-
-def test_legacy_keyword_warns_once_per_entry(rng):
-    st, ops = _small_state_and_ops(rng)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        core.apply_ops(st, ops, impl="reference")
-        core.apply_ops(st, ops, impl="reference")
-        core.apply_ops_safe(st, ops, impl="reference")
-    deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    # once per entry point, not per call
-    assert len(deps) == 2
-    assert "apply_ops" in str(deps[0].message)
-    assert "config=ExecConfig" in str(deps[0].message)
-    # re-arming the latch brings the warning back (what this suite relies on)
-    reset_deprecation_warnings()
-    with warnings.catch_warnings(record=True) as w2:
-        warnings.simplefilter("always")
-        core.apply_ops(st, ops, impl="reference")
-    assert len(w2) == 1
-
-
-def test_config_plus_legacy_rejected(rng):
-    st, ops = _small_state_and_ops(rng)
-    with pytest.raises(TypeError, match="not both"):
-        core.apply_ops(st, ops, config=ExecConfig(), impl="reference")
-    with pytest.raises(TypeError, match="not both"):
-        KVPageIndex(config=ExecConfig(), impl="reference")
-
-
-def test_legacy_and_config_paths_agree(rng):
-    st, ops = _small_state_and_ops(rng)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        _, res_legacy, _ = core.apply_ops(st, ops, impl="reference", max_results=32)
-    _, res_cfg, _ = core.apply_ops(
-        st, ops, config=ExecConfig(impl="reference", max_results=32)
-    )
-    for k in res_legacy:
-        np.testing.assert_array_equal(
-            np.asarray(res_legacy[k]), np.asarray(res_cfg[k]), err_msg=k
-        )
-
-
-def test_resolve_config_passthrough_and_default():
-    cfg = ExecConfig(impl="fused")
-    assert resolve_config("x", cfg) is cfg
-    assert resolve_config("x", None) == ExecConfig()
 
 
 def test_kv_page_index_accepts_config(rng):

@@ -1,6 +1,6 @@
 """Restructure paths that the rest of the suite never exercises.
 
-Two cold paths from ``core.restructure`` / ``core.ops``:
+Three cold paths from ``core.restructure`` / ``core.ops``:
 
   1. ``restructure_grow``'s pathological-skew *widening* branch: when a
      single bucket may have to absorb the whole incoming batch
@@ -9,6 +9,8 @@ Two cold paths from ``core.restructure`` / ``core.ops``:
   2. ``apply_ops_safe``'s restructure-and-replay round trip: a mixed batch
      that overflows mid-mix is replayed in full on the regrown pre-batch
      state, and every op class of the batch must come back correct.
+  3. ``replay_on_overflow``, the one overflow → restructure → replay
+     driver, reached through every engine that calls it.
 """
 
 import math
@@ -164,3 +166,153 @@ def test_apply_ops_safe_replay_reference_fused_identical():
     np.testing.assert_array_equal(
         np.asarray(r_ref["succ_key"]), np.asarray(r_f["succ_key"])
     )
+
+
+# ---------------------------------------------------------------------------
+# the one driver, through each of its callers
+# ---------------------------------------------------------------------------
+
+
+def _contents(state):
+    """Stored ``{key: value}`` of a FliXState (any placement)."""
+    k = np.asarray(state.keys).reshape(-1)
+    v = np.asarray(state.vals).reshape(-1)
+    live = k != int(EMPTY)
+    return dict(zip(k[live].tolist(), v[live].tolist()))
+
+
+def _shard_index(st, mesh):
+    """The tiny store's contents, range-partitioned over ``mesh``."""
+    from repro.core.distributed import shard_build
+
+    got = _contents(st)
+    keys = np.array(sorted(got), np.int32)
+    vals = np.array([got[k] for k in keys.tolist()], np.int32)
+    return shard_build(
+        jnp.asarray(keys), jnp.asarray(vals), mesh, node_size=4, nodes_per_bucket=2
+    )
+
+
+def _run_local(st, batches, tmp_path):
+    out = []
+    for ops in batches:
+        st, res, stats = core.apply_ops_safe(st, ops)
+        out.append((res, stats))
+    return out, _contents(st), None
+
+
+def _run_sharded(routing):
+    def run(st, batches, tmp_path):
+        from repro.core.distributed import make_shard_mesh, shard_apply_ops_safe
+
+        mesh = make_shard_mesh(1)
+        idx = _shard_index(st, mesh)
+        cfg = ExecConfig(routing=routing)
+        out = []
+        for ops in batches:
+            idx, res, stats = shard_apply_ops_safe(idx, ops, mesh, config=cfg)
+            out.append((res, stats))
+        return out, _contents(idx.state), None
+
+    return run
+
+
+def _run_tiered(st, batches, tmp_path):
+    from repro.core.residency import TieredFliX
+
+    tiered = TieredFliX.from_state(st, budget_bytes=max(1, st.memory_bytes() // 4))
+    out = [tiered.apply(ops)[:2] for ops in batches]
+    return out, _contents(tiered.materialize()), None
+
+
+def _run_durable(make_engine):
+    def run(st, batches, tmp_path):
+        from repro.checkpoint import DurableFliX
+
+        engine = make_engine()
+        handle = _shard_index(st, engine.mesh) if engine.kind == "sharded" else st
+        dur = DurableFliX.create(tmp_path / "idx", handle, engine=engine)
+        epoch0 = dur.epoch
+        out = [dur.apply(ops) for ops in batches]
+        contents = _contents(engine.flix(dur.handle))
+        dur.close()
+        return out, contents, dur.epoch - epoch0
+
+    return run
+
+
+def _local_engine():
+    from repro.checkpoint import LocalEngine
+
+    return LocalEngine(node_size=4, nodes_per_bucket=2)
+
+
+def _a2a_shard_engine():
+    from repro.checkpoint import ShardEngine
+    from repro.core.distributed import make_shard_mesh
+
+    return ShardEngine(
+        make_shard_mesh(1),
+        config=ExecConfig(routing="a2a"),
+        node_size=4,
+        nodes_per_bucket=2,
+    )
+
+
+@pytest.mark.parametrize(
+    "run",
+    [
+        pytest.param(_run_local, id="apply_ops_safe"),
+        pytest.param(_run_sharded("replicated"), id="shard_safe_replicated"),
+        pytest.param(_run_sharded("a2a"), id="shard_safe_a2a"),
+        pytest.param(_run_tiered, id="tiered_under_budget"),
+        pytest.param(_run_durable(_local_engine), id="durable_local"),
+        pytest.param(_run_durable(_a2a_shard_engine), id="durable_shard_a2a"),
+    ],
+)
+def test_overflow_replays_once(run, tmp_path):
+    """A batch whose inserts overflow a bucket is replayed exactly once on
+    the regrown pre-batch handle, through every caller of
+    ``replay_on_overflow``; results and stored contents match a dict model
+    over both batches, and a durable index bumps its fence epoch."""
+    st, keys = _tiny_state()
+    model = {int(k): int(k) for k in keys}
+    flood = np.arange(101, 148, dtype=np.int32)  # 47 keys inside one gap
+    dels = keys[::4].astype(np.int32)
+    points = keys[1::4].astype(np.int32)
+    tags1 = np.concatenate([
+        np.full(len(flood), core.OP_INSERT),
+        np.full(len(dels), core.OP_DELETE),
+        np.full(len(points), core.OP_POINT),
+    ]).astype(np.int32)
+    keys1 = np.concatenate([flood, dels, points]).astype(np.int32)
+    vals1 = np.concatenate([flood * 3, np.zeros(len(dels) + len(points), np.int32)])
+    ops1, perm1 = core.make_ops(tags1, keys1, vals1, pad_to=128)
+    for k in flood:
+        model[int(k)] = int(k) * 3
+    for k in dels:
+        del model[int(k)]
+    # the second batch reads every key either batch mentioned
+    reads = np.unique(np.concatenate([keys, flood])).astype(np.int32)
+    ops2, perm2 = core.make_ops(
+        np.full(len(reads), core.OP_POINT, np.int32), reads, pad_to=128
+    )
+
+    (
+        ((res1, stats1), (res2, stats2)),
+        contents,
+        epoch_bumps,
+    ) = run(st, [ops1, ops2], tmp_path)
+
+    assert int(stats1["restructure_retries"]) == 1
+    assert int(stats2["restructure_retries"]) == 0
+    assert int(stats1["inserted"]) == len(flood)
+    assert int(stats1["deleted"]) == len(dels)
+    got1 = np.asarray(core.unsort(res1["value"], perm1))[: len(keys1)]
+    np.testing.assert_array_equal(got1[tags1 == core.OP_POINT], points)
+    got2 = np.asarray(core.unsort(res2["value"], perm2))[: len(reads)]
+    want2 = [model.get(int(k), int(NOT_FOUND)) for k in reads]
+    np.testing.assert_array_equal(got2, want2)
+    assert contents == model
+    if epoch_bumps is not None:
+        assert epoch_bumps == 1
